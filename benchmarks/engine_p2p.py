@@ -233,8 +233,8 @@ def main(argv=None) -> None:
     # drift lands on both sides of every ratio. A flaky rep (native crash,
     # spawn failure) is LOGGED and skipped — at 20 fresh process pairs per
     # session, aborting on one discards a multi-minute run; medians come
-    # from the completed reps (chip_session's incremental-persistence
-    # philosophy). Zero completed reps for an engine is still fatal.
+    # from the completed reps. Zero completed reps for an engine is still
+    # fatal.
     raw = {eng: [] for eng in args.engines}
     failures = {eng: 0 for eng in args.engines}
     for rep in range(max(args.reps, 1)):

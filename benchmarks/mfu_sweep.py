@@ -4,8 +4,8 @@ local accelerator. This is the tool that sized `tpu_headline`'s TPU config
 changes to re-pick the headline shape.
 
 Usage: python -m benchmarks.mfu_sweep [config indices...]
-Prints one JSON line per config: params, step time, tokens/s, TFLOP/s, MFU
-(against the device's peak bf16 FLOP/s; null off-TPU or unknown kind).
+Needs a TPU. Prints one JSON line per config: device, params, step time,
+tokens/s, TFLOP/s, MFU (against the device's peak bf16 FLOP/s).
 """
 
 from __future__ import annotations
@@ -38,6 +38,9 @@ FUSED_XENT = {5: 8192}
 
 
 def main(argv=None) -> None:
+    from benchmarks import claim_device
+
+    dev = claim_device()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -50,8 +53,7 @@ def main(argv=None) -> None:
 
     args = argv if argv is not None else sys.argv[1:]
     which = [int(x) for x in args] or list(range(len(CONFIGS)))
-    dev = jax.devices()[0]
-    peak = _peak_for(dev.device_kind) if dev.platform == "tpu" else None
+    peak = _peak_for(dev["device_kind"])
 
     for ci in which:
         d, n_layers, ff, heads, batch, seq, remat, *rest = CONFIGS[ci]
@@ -72,19 +74,20 @@ def main(argv=None) -> None:
                                    (tokens, labels, jax.random.PRNGKey(1)),
                                    warmup=1, iters=8)
         except Exception as e:  # noqa: BLE001 — a config OOMing is a result
-            print(json.dumps({"cfg": ci, "error": str(e)[:200]}), flush=True)
+            print(json.dumps({**dev, "cfg": ci, "error": str(e)[:200]}),
+                  flush=True)
             continue
         fpt = transformer_flops_per_token(n_params, cfg["vocab"], d, n_layers, seq)
         fps = fpt * batch * seq
         print(json.dumps({
-            "cfg": ci, "d": d, "L": n_layers, "ff": ff, "b": batch, "s": seq,
+            **dev, "cfg": ci, "d": d, "L": n_layers, "ff": ff, "b": batch, "s": seq,
             **({"remat_policy": policy} if policy else {}),
             **({} if remat else {"remat": False}),
             "params_M": round(n_params / 1e6, 1),
             "step_s": round(dt, 4),
             "tok_s": round(batch * seq / dt, 1),
             "tflops": round(fps / dt / 1e12, 1),
-            "mfu": round(fps / dt / peak, 4) if peak else None,
+            "mfu": round(fps / dt / peak, 4),
         }), flush=True)
 
 

@@ -24,9 +24,9 @@ from benchmarks.busbw_sweep import make_table_emitter, parse_size, sweep_sizes
 
 def _worker(rank, world, port, q, args):
     try:
-        from benchmarks import reassert_jax_platform
+        from benchmarks import claim_device
 
-        reassert_jax_platform("cpu")  # loopback ranks cannot share one TPU
+        claim_device("cpu")  # loopback ranks cannot share one TPU
         os.environ["TPUNET_NSTREAMS"] = str(args.nstreams)
         if args.no_ffi:
             os.environ["TPUNET_FFI_COLLECTIVES"] = "0"

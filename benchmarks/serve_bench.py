@@ -14,8 +14,8 @@ lockstep tail waste the server recovers. Lengths are drawn
 deterministically (seeded) spanning short/long mix.
 
 Prints ONE JSON line:
-  {"platform", "slots", "requests", "serve_tok_s", "lockstep_tok_s",
-   "vs_lockstep", ...}
+  {"platform", "device_kind", "device_count", "slots", "requests",
+   "serve_tok_s", "lockstep_tok_s", "vs_lockstep", ...}
 """
 
 from __future__ import annotations
@@ -34,7 +34,8 @@ def _iqr4(xs):
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--platform", default="cpu", choices=["cpu", "tpu"])
+    ap.add_argument("--platform", default="tpu", choices=["cpu", "tpu"],
+                    help="needs a TPU; cpu is for testing the tool")
     ap.add_argument("--d", type=int, default=64)
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--heads", type=int, default=4)
@@ -81,10 +82,10 @@ def main(argv=None) -> None:
                          "IQR - single-shot walls on this box swing +-20%")
     args = ap.parse_args(argv)
 
-    import jax
+    from benchmarks import claim_device
 
-    if args.platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    dev = claim_device(args.platform)
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -183,7 +184,7 @@ def main(argv=None) -> None:
     lockstep_s = float(np.median(lockstep_walls))
 
     print(json.dumps({
-        "platform": jax.devices()[0].platform,
+        **dev,
         "slots": args.slots, "requests": args.requests,
         "prompt": args.prompt, "new_min": args.new_min,
         "new_max": args.new_max, "steps_per_call": args.steps_per_call,

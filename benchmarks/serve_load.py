@@ -196,7 +196,8 @@ def run_load(router, *, duration_s: float, rate: float, vocab: int,
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--platform", default="cpu", choices=["cpu", "tpu"])
+    ap.add_argument("--platform", default="tpu", choices=["cpu", "tpu"],
+                    help="needs a TPU; cpu is for testing the tool")
     ap.add_argument("--d", type=int, default=32)
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--heads", type=int, default=4)
@@ -223,9 +224,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     buckets = tuple(int(b) for b in args.buckets.split(","))
 
-    from benchmarks import reassert_jax_platform
+    from benchmarks import claim_device
 
-    reassert_jax_platform(args.platform)
+    dev = claim_device(args.platform)
     import threading
 
     import jax
@@ -284,7 +285,7 @@ def main(argv=None) -> None:
         th.join(timeout=60)
         router.close()
     print(json.dumps({
-        "platform": jax.devices()[0].platform, "slots": args.slots,
+        **dev, "slots": args.slots,
         "kv_codec": args.kv_codec, "rate": args.rate,
         "buckets": list(buckets), "session_prob": args.session_prob,
         **out}))

@@ -84,9 +84,9 @@ def run_benchmark(args, emit=print):
 
 def _mp_worker(rank, world, port, q, argv):
     try:
-        from benchmarks import reassert_jax_platform
+        from benchmarks import claim_device
 
-        reassert_jax_platform("cpu")  # loopback ranks cannot share one TPU
+        claim_device("cpu")  # loopback ranks cannot share one TPU
         args = _parse(argv)
         from tpunet import distributed
 
@@ -118,10 +118,10 @@ def _parse(argv):
 
 def main(argv=None):
     args = _parse(argv)
-    if args.world == 1:
-        from benchmarks import reassert_jax_platform
+    if args.world == 1:  # the world>1 parent never runs JAX
+        from benchmarks import place_compile_cache
 
-        reassert_jax_platform()  # the world>1 parent never runs JAX
+        place_compile_cache()
     if args.world > 1:
         from benchmarks import check_rank_results, spawn_ranks
 

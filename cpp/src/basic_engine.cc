@@ -190,6 +190,9 @@ struct Comm {
   std::vector<uint8_t> stream_dead GUARDED_BY(fo_mu);
   std::vector<uint8_t> stream_retired GUARDED_BY(fo_mu);
   size_t dead_count GUARDED_BY(fo_mu) = 0;
+  // Sender: the reverse ctrl direction is gone (peer closed it or died), so
+  // no NACK can arrive any more and a failing stream must poison, not wait.
+  bool nack_reader_gone GUARDED_BY(fo_mu) = false;
   std::vector<std::deque<ChunkRec>> recs GUARDED_BY(fo_mu);  // per-stream, seq-ordered
   std::vector<uint64_t> next_seq GUARDED_BY(fo_mu);  // chunks ever assigned per stream
   std::vector<uint64_t> done_seq GUARDED_BY(fo_mu);  // receiver: chunks fully read
@@ -491,7 +494,7 @@ Status RecvChunkWire(int fd, uint8_t* data, size_t len, bool crc, bool spin,
 bool SenderStreamFailed(Comm* c, StreamWorker* w) {
   {
     MutexLock lk(c->fo_mu);
-    if (c->Aborted() || c->nstreams == 1) return false;
+    if (c->Aborted() || c->nstreams == 1 || c->nack_reader_gone) return false;
     if (!c->stream_dead[w->idx]) {
       if (c->dead_count + 1 >= c->nstreams) return false;  // last stream: poison
       c->stream_dead[w->idx] = 1;
@@ -1191,6 +1194,25 @@ bool HandleNack(Comm* c, size_t k, uint64_t completed) {
   return true;
 }
 
+// The reverse ctrl direction ended (the peer closed it, or died): no NACK
+// will ever arrive. A stream that already failed over and awaits one holds
+// records nothing else will account, so its request would never settle and
+// wait() would sit on it for good (a SIGKILLed peer whose other stream's
+// write still landed in the kernel buffer — tests/test_fault_paths.py under
+// load): poison now. Streams that fail later see the flag and poison
+// themselves (SenderStreamFailed). A clean close finds nothing orphaned.
+void NackReaderGone(Comm* c, const char* why) {
+  bool orphaned = false;
+  {
+    MutexLock lk(c->fo_mu);
+    c->nack_reader_gone = true;
+    for (size_t i = 0; i < c->nstreams; ++i) {
+      orphaned |= c->stream_dead[i] && !c->stream_retired[i];
+    }
+  }
+  if (orphaned) PoisonAndDrainQueue(c, why);
+}
+
 // Parked on the receiver→sender direction of the ctrl connection (silent in
 // normal operation). Poll-based so spin mode's nonblocking ctrl fd does not
 // busy-burn a core here.
@@ -1200,14 +1222,18 @@ void NackReaderLoop(Comm* c) {
   while (true) {
     struct pollfd pfd = {c->ctrl_fd, POLLIN, 0};
     int pr = ::poll(&pfd, 1, 100);
-    if (pr < 0 && errno != EINTR) return;
     if (c->Aborted()) return;
+    if (pr < 0 && errno != EINTR) {
+      return NackReaderGone(c, "reverse ctrl poll failed while awaiting NACK");
+    }
     if (pr <= 0) continue;
     ssize_t n = ::recv(c->ctrl_fd, buf + got, sizeof(buf) - got, MSG_DONTWAIT);
-    if (n == 0) return;  // peer closed ctrl; scheduler/poison paths own it
+    if (n == 0) {
+      return NackReaderGone(c, "peer closed ctrl while a stream awaited its NACK");
+    }
     if (n < 0) {
       if (errno == EINTR || errno == EAGAIN || errno == EWOULDBLOCK) continue;
-      return;
+      return NackReaderGone(c, "reverse ctrl read failed while awaiting NACK");
     }
     got += static_cast<size_t>(n);
     if (got < sizeof(buf)) continue;
@@ -1602,7 +1628,11 @@ class BasicEngine : public EngineBase, public BundleAdopter {
     if (watchdog_ms_ == 0) return;
     std::weak_ptr<Comm> wc = c;
     state->on_stall = [wc] {
-      if (auto p = wc.lock()) p->AbortStreams();
+      // Full poison, not just AbortStreams: records orphaned mid-failover
+      // must settle too, or the verdict never surfaces through test().
+      if (auto p = wc.lock()) {
+        PoisonAndDrainQueue(p.get(), "progress watchdog verdict");
+      }
     };
   }
 

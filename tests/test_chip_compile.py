@@ -1,0 +1,173 @@
+"""Compiles for a described TPU v5e, without the chip: the kernels and the
+whole programs of the main path at the widths chip_smoke.py runs them at
+(d2048 L12 ff8192 h16, vocab 32000, bf16).
+
+These are compiles, not runs: the TPU compiler is installed here and
+compiles for a topology that is described and not attached. They catch what
+interpret mode cannot: a slice the tiling refuses, a kernel that wants more
+VMEM than a core has, a step that does not fit HBM, a program that holds no
+kernel at all. One file, one process: only one process may load libtpu, and
+it keeps it until it exits.
+"""
+
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from chip_smoke import FULL, KERNEL
+
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+MODEL = FULL.model  # vocab 32000, d2048, L12, h16, ff8192
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    """The program asks `jax.default_backend()` which path to lower, and
+    here that is the CPU. Steer it the way the chip would: Pallas kernels
+    compiled by Mosaic, DCN collectives over io_callback. Through
+    sys.modules, because `tpunet.ops` re-exports a function named
+    flash_attention over the submodule of that name."""
+    import tpunet.interop  # noqa: F401
+    import tpunet.ops  # noqa: F401
+
+    monkeypatch.setattr(sys.modules["tpunet.ops.flash_attention"],
+                        "_auto_interpret", lambda: False)
+    monkeypatch.setattr(sys.modules["tpunet.interop"],
+                        "_ffi_available", lambda: False)
+
+
+def _on(sharding, tree):
+    """Shapes of `tree`, placed on the described chip."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _flash_loss(causal=True, window=None):
+    from tpunet.ops import flash_attention
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal, interpret=False, window=window)
+        return jnp.sum(o.astype(jnp.float32))
+
+    return loss
+
+
+def _qkv(one_chip, batch, seq, kv_heads):
+    def arr(heads):
+        return jax.ShapeDtypeStruct((batch, seq, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    return arr(16), arr(kv_heads), arr(kv_heads)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("kv_heads,window", [(16, None), (4, None),
+                                             (16, 256), (4, 256)],
+                         ids=["mha", "gqa4", "window256", "gqa4_window256"])
+def test_flash_b8_s2048(one_chip, direction, kv_heads, window):
+    fn = _flash_loss(window=window)
+    if direction == "bwd":
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*_qkv(one_chip, 8, 2048, kv_heads)).compile().as_text()
+    assert text.count(KERNEL) == (1 if direction == "fwd" else 3)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_b1_s8192(one_chip, direction):
+    fn = _flash_loss()
+    if direction == "bwd":
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    jax.jit(fn).lower(*_qkv(one_chip, 1, 8192, 16)).compile()
+
+
+def test_flash_s32768_is_refused_for_vmem(one_chip):
+    """Each program stages the whole K and V of its head in VMEM, so the
+    sequence a kernel can take is bounded by the core's 16 MiB. This pins
+    the limit until a later PR streams K/V through the grid."""
+    with pytest.raises(Exception, match=r"(?is)vmem.*limit"):
+        jax.jit(_flash_loss()).lower(*_qkv(one_chip, 1, 32768, 16)).compile()
+
+
+def _train_program(one_chip, **step_kw):
+    import optax
+
+    from tpunet.models import Transformer
+    from tpunet.train import create_train_state, make_train_step
+
+    model = Transformer(compute_dtype=jnp.bfloat16, attn_impl="flash",
+                        remat=True, **MODEL)
+    tx = optax.adamw(3e-4)
+    tokens = jax.ShapeDtypeStruct((FULL.batch, FULL.seq), jnp.int32,
+                                  sharding=one_chip)
+    state = jax.eval_shape(
+        lambda t: create_train_state(model, jax.random.PRNGKey(0), t, tx)[0],
+        tokens)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    step = make_train_step(model, tx, **step_kw)
+    return step.lower(_on(one_chip, state), tokens, tokens,
+                      _on(one_chip, key)).compile()
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def test_train_step_has_its_kernels_and_fits(one_chip, as_on_chip):
+    compiled = _train_program(one_chip)
+    # 48: per layer the forward, its remat recompute, dQ, dK/dV
+    assert compiled.as_text().count(KERNEL) == FULL.train_kernels
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_cross_host_train_step_bucketed(one_chip, as_on_chip):
+    from conftest import free_port
+
+    from tpunet import distributed
+
+    distributed.finalize()
+    distributed.initialize(f"127.0.0.1:{free_port()}", 0, 1)
+    try:
+        compiled = _train_program(one_chip, cross_host=True,
+                                  bucket_bytes=64 << 20)
+    finally:
+        distributed.finalize()
+    assert compiled.as_text().count(KERNEL) == FULL.train_kernels
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("kv_heads,window", [(None, None), (4, 256)],
+                         ids=["mha", "gqa4_window256"])
+def test_generate_b8_p512_n256(one_chip, as_on_chip, kv_heads, window):
+    from tpunet.models import Transformer, generate
+
+    model = Transformer(compute_dtype=jnp.bfloat16, attn_impl="flash",
+                        n_kv_heads=kv_heads, attn_window=window, **MODEL)
+    prompt = jax.ShapeDtypeStruct((FULL.dec_batch, FULL.prompt), jnp.int32,
+                                  sharding=one_chip)
+    params = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t)["params"], prompt)
+    compiled = jax.jit(lambda p, t: generate(model, p, t, FULL.new)).lower(
+        _on(one_chip, params), prompt).compile()
+    assert compiled.as_text().count(KERNEL) == FULL.decode_kernels  # the prefill

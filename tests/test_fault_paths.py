@@ -50,8 +50,9 @@ def _victim(rank: int, world: int, port: int, q) -> None:
 
     comm = Communicator(f"127.0.0.1:{port}", rank, world)
     comm.barrier()
-    q.put((rank, "ready"))
     arr = np.ones((64 << 20) // 4, np.float32)  # 64 MiB: long enough to die in
+    comm.all_reduce(arr)
+    q.put((rank, "in the loop"))  # see _kill_mid_collective
     while True:  # loop until killed
         comm.all_reduce(arr)
 
@@ -156,24 +157,52 @@ def test_peer_death_before_channel_wiring_errors_cleanly():
             p.join(timeout=10)
 
 
-def test_peer_death_mid_allreduce_errors_cleanly():
+def _kill_mid_collective(survivor, victim, wait_s: float = 240):
+    """Start rank 0 `survivor` and rank 1 `victim`, SIGKILL the victim while
+    both are looping over collectives, and return (the survivor's verdict,
+    the survivor's process).
+
+    No sleep decides when to kill. The victim reports "in the loop" after
+    its first post-warm-up collective has completed — a collective, so the
+    survivor completed it too — and from then on both sit in back-to-back
+    collectives, so whenever the kill lands a collective is in flight or
+    the next one will wait on a dead peer. The victim reports on a queue of
+    its OWN (see _prewiring_victim: a SIGKILL inside a shared queue's write
+    lock wedges every other writer).
+
+    Under six xdist workers these tests used to lose the survivor's verdict
+    for good. That was the engine, not the test: a sender whose one stream
+    failed over waited for a NACK its dead peer could no longer send
+    (basic_engine.cc NackReaderGone, docs/DESIGN.md "single-stream
+    failover" point 5)."""
     import multiprocessing as mp
 
     ctx = mp.get_context("spawn")
-    q = ctx.Queue()
+    q, vq = ctx.Queue(), ctx.Queue()
     port = free_port()
-    surv = ctx.Process(target=_survivor, args=(0, 2, port, q))
-    vict = ctx.Process(target=_victim, args=(1, 2, port, q))
-    surv.start()
-    vict.start()
-    ready = {q.get(timeout=120)[0], q.get(timeout=120)[0]}
-    assert ready == {0, 1}
-    time.sleep(0.3)  # let an allreduce get going
-    vict.kill()  # SIGKILL: no goodbye, sockets RST on close
-    rank, status = q.get(timeout=120)
-    surv.join(timeout=30)
-    vict.join(timeout=30)
-    assert rank == 0 and status.startswith("OK error"), status
+    surv = ctx.Process(target=survivor, args=(0, 2, port, q))
+    vict = ctx.Process(target=victim, args=(1, 2, port, vq))
+    try:
+        surv.start()
+        vict.start()
+        assert q.get(timeout=wait_s) == (0, "ready")
+        assert vq.get(timeout=wait_s) == (1, "in the loop")
+        vict.kill()  # SIGKILL: no goodbye, sockets RST on close
+        rank, status = q.get(timeout=wait_s)
+        assert rank == 0
+        surv.join(timeout=60)
+        vict.join(timeout=30)
+        return status, surv
+    finally:
+        for p in (surv, vict):
+            if p.pid is not None and p.is_alive():
+                p.kill()
+                p.join(timeout=10)
+
+
+def test_peer_death_mid_allreduce_errors_cleanly():
+    status, _ = _kill_mid_collective(_survivor, _victim)
+    assert status.startswith("OK error"), status
 
 
 def _jax_survivor(rank: int, world: int, port: int, q) -> None:
@@ -219,34 +248,18 @@ def _jax_victim(rank: int, world: int, port: int, q) -> None:
     distributed.initialize(f"127.0.0.1:{port}", rank, world)
     fn = jax.jit(dcn_psum)
     x = jnp.ones((16 << 20) // 4, jnp.float32)
+    np.asarray(fn(x))  # warm compile + one good sync
     np.asarray(fn(x))
-    q.put((rank, "ready"))
+    q.put((rank, "in the loop"))  # see _kill_mid_collective
     while True:
         np.asarray(fn(x))
 
 
 def test_peer_death_surfaces_as_jax_exception():
-    # The io_callback seam must turn the transport error into a Python
-    # exception out of the jitted program — not a wedge.
-    import multiprocessing as mp
-
-    ctx = mp.get_context("spawn")
-    q = ctx.Queue()
-    port = free_port()
-    surv = ctx.Process(target=_jax_survivor, args=(0, 2, port, q))
-    vict = ctx.Process(target=_jax_victim, args=(1, 2, port, q))
-    surv.start()
-    vict.start()
-    ready = set()
-    for _ in range(2):
-        ready.add(q.get(timeout=240)[0])
-    assert ready == {0, 1}
-    time.sleep(0.3)
-    vict.kill()
-    rank, status = q.get(timeout=240)
-    surv.join(timeout=30)
-    vict.join(timeout=30)
-    assert rank == 0 and status.startswith("OK raised"), status
+    # The bridge into the jitted program must turn the transport error into
+    # a Python exception out of it — not a wedge.
+    status, _ = _kill_mid_collective(_jax_survivor, _jax_victim)
+    assert status.startswith("OK raised"), status
 
 
 def _async_survivor(rank: int, world: int, port: int, q) -> None:
@@ -281,30 +294,16 @@ def _async_victim(rank: int, world: int, port: int, q) -> None:
 
     comm = Communicator(f"127.0.0.1:{port}", rank, world)
     comm.barrier()
-    q.put((rank, "ready"))
     arr = np.ones((32 << 20) // 4, np.float32)
+    comm.all_reduce(arr)
+    q.put((rank, "in the loop"))  # see _kill_mid_collective
     while True:
         comm.all_reduce(arr)
 
 
 def test_peer_death_with_unwaited_async_tickets_exits_cleanly():
-    import multiprocessing as mp
-
-    ctx = mp.get_context("spawn")
-    q = ctx.Queue()
-    port = free_port()
-    surv = ctx.Process(target=_async_survivor, args=(0, 2, port, q))
-    vict = ctx.Process(target=_async_victim, args=(1, 2, port, q))
-    surv.start()
-    vict.start()
-    ready = {q.get(timeout=120)[0], q.get(timeout=120)[0]}
-    assert ready == {0, 1}
-    time.sleep(0.5)
-    vict.kill()
-    rank, status = q.get(timeout=120)
-    assert rank == 0 and status == "OK errored", status
-    surv.join(timeout=60)
-    vict.join(timeout=30)
+    status, surv = _kill_mid_collective(_async_survivor, _async_victim)
+    assert status == "OK errored", status
     # The regression: survivor used to die with SIGSEGV (-11) at exit.
     assert surv.exitcode == 0, f"survivor exitcode {surv.exitcode}"
 

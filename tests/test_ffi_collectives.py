@@ -70,11 +70,8 @@ def test_ffi_dtypes_and_zero_size_world1():
             y = jax.jit(dcn_psum)(x)
             np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
         # f64/i64 need x64 mode or they silently downcast to f32/i32 and
-        # dtype codes 1/4 would never be exercised. jax.enable_x64 moved out
-        # of the top-level namespace on the 0.4.x line.
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        # dtype codes 1/4 would never be exercised.
+        with jax.enable_x64():
             for dt in (jnp.float64, jnp.int64):
                 x = jnp.arange(7).astype(dt)
                 assert x.dtype == dt
@@ -234,14 +231,14 @@ def test_ffi_error_is_classified_as_comm_failure():
     # elastic recovery's is_comm_failure string-match keeps working when
     # the failure surfaces as XlaRuntimeError from the custom call.
     from tpunet import distributed
-    from tpunet.interop import _ffi_available, _jax_ffi_mod
+    from tpunet.interop import _ffi_available
     from tpunet.train.elastic import is_comm_failure
 
     distributed.finalize()
     distributed.initialize(f"127.0.0.1:{free_port()}", 0, 1)
     try:
         assert _ffi_available()
-        bad = _jax_ffi_mod().ffi_call(
+        bad = jax.ffi.ffi_call(
             "tpunet_all_reduce",
             jax.ShapeDtypeStruct((4,), jnp.float32), has_side_effect=True)
         with pytest.raises(Exception) as ei:

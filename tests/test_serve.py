@@ -144,7 +144,8 @@ def test_serve_bench_cli(capsys):
     # 7 interleaved passes would add CI time with no assertion power.
     from benchmarks.serve_bench import main as bench_main
 
-    bench_main(["--requests", "4", "--slots", "2", "--prompt", "8",
+    bench_main(["--platform", "cpu",
+                "--requests", "4", "--slots", "2", "--prompt", "8",
                 "--new-min", "2", "--new-max", "6", "--steps-per-call", "4",
                 "--d", "32", "--layers", "1", "--heads", "2", "--ff", "64",
                 "--vocab", "64", "--reps", "1"])
@@ -152,6 +153,7 @@ def test_serve_bench_cli(capsys):
 
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["serve_tok_s"] > 0 and out["lockstep_tok_s"] > 0
+    assert (out["platform"], out["device_count"]) == ("cpu", 8)
     assert out["serve_micro_steps"] > 0
     assert out["sched_win"] > 0
 

@@ -1,22 +1,26 @@
 """Loader + raw ctypes signatures for libtpunet.so (the C ABI, c_api.h).
 
-Builds the native library on demand (``make -C cpp``) with a file lock so
-concurrent test processes don't race the build. The reference shipped its
-native core the same way conceptually: cargo staticlib + make shared object
-(reference: cc/Makefile:9-16).
+Builds the native library on demand (``make -C cpp -j build/libtpunet.so``)
+under a file lock so concurrent test processes don't race the build. The
+reference shipped its native core the same way conceptually: cargo staticlib
++ make shared object (reference: cc/Makefile:9-16).
 """
 
 from __future__ import annotations
 
 import ctypes
 import fcntl
+import hashlib
 import os
 import subprocess
+import sys
 from pathlib import Path
 
 _REPO_ROOT = Path(__file__).resolve().parent.parent
 _CPP_DIR = _REPO_ROOT / "cpp"
 _LIB_PATH = _CPP_DIR / "build" / "libtpunet.so"
+# Digest of the sources the library was built from, written beside it.
+_STAMP_PATH = _LIB_PATH.with_name("libtpunet.so.sources")
 
 TPUNET_OK = 0
 TPUNET_ERR_NULL = -1
@@ -50,38 +54,49 @@ class NetProperties(ctypes.Structure):
     ]
 
 
-def _sources_mtime() -> float:
-    newest = 0.0
-    for sub in ("src", "include/tpunet", "tests"):
-        d = _CPP_DIR / sub
-        if d.is_dir():
-            for f in d.rglob("*"):
-                if f.suffix in (".cc", ".h"):
-                    newest = max(newest, f.stat().st_mtime)
-    mk = _CPP_DIR / "Makefile"
-    if mk.exists():
-        newest = max(newest, mk.stat().st_mtime)
-    return newest
+def _sources_digest() -> str:
+    """SHA-256 over everything the library target compiles: the Makefile,
+    cpp/src and the public headers. Content, not file times: a copied or
+    freshly checked-out tree keeps its bytes but not its mtimes."""
+    files = [_CPP_DIR / "Makefile"]
+    for sub in ("src", "include/tpunet"):
+        files += sorted(f for f in (_CPP_DIR / sub).rglob("*")
+                        if f.suffix in (".cc", ".h"))
+    h = hashlib.sha256()
+    for f in files:
+        h.update(f.relative_to(_CPP_DIR).as_posix().encode() + b"\0")
+        h.update(f.read_bytes())
+    return h.hexdigest()
 
 
 def build_native(force: bool = False) -> Path:
-    """Build libtpunet.so if missing or stale. Safe across processes."""
+    """Build cpp/build/libtpunet.so if it is missing or was built from other
+    sources than the ones on disk; `force` rebuilds regardless. Only the
+    library target, in parallel, every object recompiled (`make -B`): the
+    objects a copied build directory holds cannot be trusted by file time
+    either. Safe across processes."""
     lock_path = _CPP_DIR / ".build.lock"
     with open(lock_path, "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)
         try:
-            stale = (
-                force
-                or not _LIB_PATH.exists()
-                or _LIB_PATH.stat().st_mtime < _sources_mtime()
+            digest = _sources_digest()
+            fresh = (
+                not force
+                and _LIB_PATH.exists()
+                and _STAMP_PATH.exists()
+                and _STAMP_PATH.read_text().strip() == digest
             )
-            if stale:
+            if not fresh:
+                _STAMP_PATH.unlink(missing_ok=True)
                 subprocess.run(
-                    ["make", "-C", str(_CPP_DIR), "all"],
+                    ["make", "-C", str(_CPP_DIR), "-B",
+                     f"-j{os.cpu_count() or 1}", f"PYTHON={sys.executable}",
+                     "build/libtpunet.so"],
                     check=True,
                     capture_output=True,
                     text=True,
                 )
+                _STAMP_PATH.write_text(digest + "\n")
         except subprocess.CalledProcessError as e:  # surface compiler output
             raise RuntimeError(
                 f"native build failed:\n{e.stdout}\n{e.stderr}"
@@ -94,19 +109,25 @@ def build_native(force: bool = False) -> Path:
 _lib: ctypes.CDLL | None = None
 
 
-def load() -> ctypes.CDLL:
-    """Load (building if needed) and memoize the native library."""
+def load(lib_file: Path | None = None) -> ctypes.CDLL:
+    """Load (building if needed) and memoize the native library. An explicit
+    `lib_file` on the first call wins over TPUNET_LIBRARY_PATH and a bundled
+    copy — how chip_smoke.py pins the process to the file it just built."""
     global _lib
     if _lib is not None:
+        if lib_file is not None and Path(_lib._name) != Path(lib_file):
+            raise RuntimeError(
+                f"libtpunet already loaded from {_lib._name}, not {lib_file}")
         return _lib
-    path = os.environ.get("TPUNET_LIBRARY_PATH", "")
-    bundled = Path(__file__).resolve().parent / "lib" / "libtpunet.so"
-    if path:
-        lib_file = Path(path)
-    elif bundled.exists():  # installed wheel: .so shipped as package data
-        lib_file = bundled
-    else:  # source checkout: build on demand
-        lib_file = build_native()
+    if lib_file is None:
+        path = os.environ.get("TPUNET_LIBRARY_PATH", "")
+        bundled = Path(__file__).resolve().parent / "lib" / "libtpunet.so"
+        if path:
+            lib_file = Path(path)
+        elif bundled.exists():  # installed wheel: .so shipped as package data
+            lib_file = bundled
+        else:  # source checkout: build on demand
+            lib_file = build_native()
     lib = ctypes.CDLL(str(lib_file))
 
     u = ctypes.c_uintptr if hasattr(ctypes, "c_uintptr") else ctypes.c_size_t

@@ -87,20 +87,6 @@ _FFI_TARGETS = {
 }
 
 
-def _jax_ffi_mod():
-    """The FFI registration/call module: ``jax.ffi`` on current jax,
-    ``jax.extend.ffi`` on the 0.4.x line (same curried ffi_call API). The
-    custom-call lane must not depend on which spelling this environment
-    ships — falling back to io_callback over a NAME move would silently
-    cost the 3-copy bridge."""
-    mod = getattr(jax, "ffi", None)
-    if mod is not None and hasattr(mod, "register_ffi_target"):
-        return mod
-    from jax.extend import ffi as extend_ffi
-
-    return extend_ffi
-
-
 def _ffi_available() -> bool:
     """True when the zero-copy XLA custom-call path can serve this trace:
     CPU backend, handler symbols present in libtpunet.so (omitted when the
@@ -128,10 +114,9 @@ def _ffi_available() -> bool:
         from tpunet import _native
 
         lib = _native.load()
-        ffi = _jax_ffi_mod()
         for target, symbol in _FFI_TARGETS.items():
-            ffi.register_ffi_target(
-                target, ffi.pycapsule(getattr(lib, symbol)),
+            jax.ffi.register_ffi_target(
+                target, jax.ffi.pycapsule(getattr(lib, symbol)),
                 platform="cpu")
         _ffi_state["registered"] = True
     return True
@@ -144,7 +129,7 @@ def _ffi_call(target: str, spec, x, after=(), **attrs):
     them. (stablehlo.optimization_barrier is NOT enough — the pipeline
     expands it away and did reorder data-independent collectives in
     rank-asymmetric traces.)"""
-    return _jax_ffi_mod().ffi_call(target, spec, has_side_effect=True)(
+    return jax.ffi.ffi_call(target, spec, has_side_effect=True)(
         x, *after, **attrs)
 
 
@@ -425,8 +410,11 @@ def hierarchical_psum(x, axis_name: str | None = None):
     """Two-tier psum: `lax.psum` over the in-pod mesh axis (ICI, XLA
     collectives), then a DCN all-reduce across processes. This is the shape
     a v5e-32 (4 hosts x 8 chips) gradient sync takes: ICI does the heavy
-    intra-pod reduction at interconnect speed, DCN carries one
-    already-reduced copy per host.
+    intra-pod reduction at interconnect speed, DCN carries the
+    already-reduced copy. Under `shard_map` the DCN all-reduce runs once on
+    EVERY device of the process, each with the same reduced block (four
+    all-reduces a call on a four-chip host, chip_smoke.py --four-chips): one
+    copy per host is what the layout wants and not yet what it does.
 
     Requires `tpunet.distributed.initialize()` BEFORE the first trace: the
     world-size decision is baked into the jitted executable, so a lazy

@@ -190,14 +190,14 @@ class BatchServer:
         # refill resets the row).
         max_len_cap = max_len
 
-        # Both jits CLOSE OVER params: the server's weights are fixed at
-        # construction, and passing the 10s-of-leaves param tree through
-        # every call costs a flatten + cache lookup per dispatch — real
-        # money when the step itself is ~1 ms.
-        params_c = params
-
-        @partial(jax.jit, donate_argnums=(0, 1, 2))
-        def decode_step(cache, toks, key):
+        # params are ARGUMENTS of every jitted program (bound below with
+        # functools.partial, so callers keep the short signatures). A jit
+        # that closes over them lowers each leaf as a literal constant of
+        # the program: at the d2048 L12 model that is 2.9 GB of constants
+        # compiled into every prefill shape and the decode step, each with
+        # its own copy on the device.
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def decode_step(params_c, cache, toks, key):
             key, sub = jax.random.split(key)
 
             def body(carry, k):
@@ -214,8 +214,8 @@ class BatchServer:
             # (slots, window) readback + the carried device state.
             return cache, toks, toks_out.swapaxes(0, 1), key
 
-        @partial(jax.jit, donate_argnums=(0, 1), static_argnames=("chunk",))
-        def prefill_slots(cache, toks, prompts, rows, key, chunk):
+        @partial(jax.jit, donate_argnums=(1, 2), static_argnames=("chunk",))
+        def prefill_slots(params_c, cache, toks, prompts, rows, key, chunk):
             # Row surgery, n rows at once: gather the claimed slots out of
             # every cache leaf, reset their indexes (the rows may hold
             # dead sequences' frontiers), prefill the (n, p) prompts
@@ -269,8 +269,6 @@ class BatchServer:
             greedy = temperature == 0.0
             t_ring = _spec_ring_ok(model, gamma)
             d_ring = _spec_ring_ok(draft_model, gamma)
-            draft_params_c = draft_params
-            rows_i = jnp.arange(slots)
             spec_cap = max_len_cap + gamma + 1
 
             def probs_of(logits):
@@ -278,11 +276,7 @@ class BatchServer:
                     filtered_logits(logits, temperature, top_k, top_p),
                     axis=-1)
 
-            round_core = _make_spec_round_core(
-                self._dm, self._dm_draft, params_c, draft_params_c, gamma,
-                greedy, probs_of, t_ring, d_ring)
-
-            def spec_round(carry, key):
+            def spec_round(round_core, carry, key):
                 # One speculative round over every slot (live or garbage):
                 # draft gamma, verify in ONE target forward, commit each
                 # row's own accepted prefix + fix/bonus token. The
@@ -307,24 +301,28 @@ class BatchServer:
                 new_idx = jnp.minimum(idx0 + counts, spec_cap)
                 t_cache = _set_cache_index(t_cache, new_idx)
                 d_cache = _set_cache_index(d_cache, new_idx)
-                tok_next = w[rows_i, n_eff]
+                tok_next = w[jnp.arange(slots), n_eff]
                 return (t_cache, d_cache, tok_next), (w, counts)
 
-            @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-            def spec_decode_step(t_cache, d_cache, toks, key):
+            @partial(jax.jit, donate_argnums=(2, 3, 4, 5))
+            def spec_decode_step(params_c, draft_params_c, t_cache, d_cache,
+                                 toks, key):
                 key, sub = jax.random.split(key)
+                round_core = _make_spec_round_core(
+                    self._dm, self._dm_draft, params_c, draft_params_c,
+                    gamma, greedy, probs_of, t_ring, d_ring)
                 (t_cache, d_cache, toks), (w, counts) = jax.lax.scan(
-                    spec_round, (t_cache, d_cache, toks),
+                    partial(spec_round, round_core), (t_cache, d_cache, toks),
                     jax.random.split(sub, steps_per_call))
                 # (slots, rounds, gamma+1) committed blocks + per-round
                 # per-row commit counts.
                 return (t_cache, d_cache, toks, w.swapaxes(0, 1),
                         counts.swapaxes(0, 1), key)
 
-            @partial(jax.jit, donate_argnums=(0, 1, 2),
+            @partial(jax.jit, donate_argnums=(2, 3, 4),
                      static_argnames=("chunk",))
-            def spec_prefill_slots(t_cache, d_cache, toks, prompts, rows,
-                                   key, chunk):
+            def spec_prefill_slots(params_c, draft_params_c, t_cache, d_cache,
+                                   toks, prompts, rows, key, chunk):
                 # Same row surgery as the plain path, on BOTH caches: the
                 # draft must hold the prompt K/V before it can propose.
                 key, sub = jax.random.split(key)
@@ -344,12 +342,14 @@ class BatchServer:
                 toks = toks.at[rows].set(tok)
                 return t_cache, d_cache, toks, tok, key
 
-            self._spec_decode_step = spec_decode_step
-            self._spec_prefill_slots = spec_prefill_slots
+            self._spec_decode_step = partial(spec_decode_step, params,
+                                             draft_params)
+            self._spec_prefill_slots = partial(spec_prefill_slots, params,
+                                               draft_params)
             self.stats["spec_rounds"] = 0
             self.stats["spec_committed"] = 0
-        self._decode_step = decode_step
-        self._prefill_slots = prefill_slots
+        self._decode_step = partial(decode_step, params)
+        self._prefill_slots = partial(prefill_slots, params)
 
     def submit(self, prompt, max_new_tokens: int) -> int:
         """Enqueue one request; returns its id. Slot assignment happens at

@@ -1,10 +1,9 @@
 """Small shared helpers for shard_map-based collectives code.
 
-jax >= 0.9 tracks varying-manual-axes (vma) in avals inside shard_map:
-fresh literals (zeros/full) are "unvarying" and cannot meet device-varying
-values in a scan carry without an explicit cast. `full_varying_like` builds
-a filled array that carries the vma of a reference value, portably across
-jax versions (pcast / pvary / no-op).
+jax tracks varying-manual-axes (vma) in avals inside shard_map: fresh
+literals (zeros/full) are "unvarying" and cannot meet device-varying values
+in a scan carry without an explicit cast. `full_varying` builds a filled
+array that carries the vma of a reference value.
 """
 
 from __future__ import annotations
@@ -12,45 +11,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.4.35 re-exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(*args, **kwargs):
-    """shard_map with a portability shim: jax versions WITHOUT the
-    pcast/pvary varying-cast ops cannot express "this literal is varying",
-    so their replication checker flags cond/scan branches that mix fresh
-    literals with device-varying carries (the exact pattern the ring /
-    zigzag attention scans use). On those versions the static check is
-    disabled (check_rep=False — purely a compile-time lint, no codegen
-    change); versions that HAVE the cast ops keep the check and the
-    explicitly-cast literals from full_varying()."""
-    if (getattr(jax.lax, "pcast", None) is None
-            and getattr(jax.lax, "pvary", None) is None):
-        kwargs.setdefault("check_rep", False)
-    return _shard_map(*args, **kwargs)
+shard_map = jax.shard_map
 
 
 def vma_of(x) -> tuple:
-    try:
-        return tuple(jax.typeof(x).vma)
-    except AttributeError:  # older jax: no vma tracking
-        return ()
+    return tuple(jax.typeof(x).vma)
 
 
 def full_varying(shape, fill, dtype, vma: tuple):
     x = jnp.full(shape, fill, dtype)
     if not vma:
         return x
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, vma, to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, vma)
-    # Neither cast op exists (0.4.x line): vma may be reported on avals but
-    # there is no explicit cast — fresh literals already meet varying values
-    # without one on these versions.
-    return x
+    return jax.lax.pcast(x, vma, to="varying")

@@ -48,14 +48,14 @@ class PrefillEngine:
         self._cache = init_cache(self._dm, 1, max_len)
         self._chunk = prefill_chunk
         self.stats = {"prefills": 0}
-        params_c = params
 
-        @partial(jax.jit, donate_argnums=(0,), static_argnames=("chunk",))
-        def prefill_one(cache, prompt, chunk):
+        # params are an argument, not a closure: see BatchServer.
+        @partial(jax.jit, donate_argnums=(1,), static_argnames=("chunk",))
+        def prefill_one(params_c, cache, prompt, chunk):
             cache = _set_cache_index(cache, 0)
             return _prefill(self._dm, params_c, cache, prompt, chunk)
 
-        self._prefill_one = prefill_one
+        self._prefill_one = partial(prefill_one, params)
 
     def kv_leaf_shapes(self, plen: int) -> list[tuple]:
         """Per-leaf KV block shapes for a prompt of length `plen` — must
