@@ -331,6 +331,27 @@ int32_t tpunet_c_trace_flush(void);
  * tracing even when TPUNET_TRACE_DIR was unset at load. NULL or "" flushes
  * and disables. */
 int32_t tpunet_c_trace_set_dir(const char* dir);
+/* Record one PROGRAM span (tpunet.telemetry.span): a host-side span of the
+ * Python layer — the DCN bridge's callback, fit()'s loop — into the native
+ * tracer's buffer, so it lands in the same tpunet-trace-rank<R>.json as the
+ * request and collective phase spans. `start_us`/`dur_us` are on
+ * CLOCK_MONOTONIC in microseconds (Python: time.monotonic_ns() // 1000).
+ * Args written: seq (the root span's per-process counter, shared by its
+ * children), nbytes, and — when given — parent (the enclosing span's name),
+ * kind, step (step < 0 = none); never comm_id/coll_seq, the collective
+ * phases' join key. `name`, `parent`, `kind`: [A-Za-z0-9_.:-]{1,64} (parent
+ * and kind may be NULL or ""). Returns 1 when recorded, 0 when tracing is
+ * off (the caller stops calling), or TPUNET_ERR_INVALID. */
+int32_t tpunet_c_trace_span(const char* name, uint64_t start_us, uint64_t dur_us,
+                            uint64_t seq, uint64_t nbytes, const char* parent,
+                            const char* kind, int64_t step);
+/* Count one host callback of the DCN bridge (tpunet/interop.py's
+ * io_callback path) and its operand bytes into
+ * tpunet_bridge_{calls,bytes}_total{kind=...}: 0 = all_reduce,
+ * 1 = all_reduce_start, 2 = all_reduce_finish, 3 = all_gather,
+ * 4 = reduce_scatter, 5 = all_to_all, 6 = broadcast,
+ * 7 = neighbor_exchange. */
+int32_t tpunet_c_bridge_call(int32_t kind, uint64_t nbytes);
 /* Bound port of the on-demand /metrics listener, or 0 when no listener is
  * up. TPUNET_METRICS_PORT unset/empty = no listener; an explicit 0 binds an
  * EPHEMERAL port (multi-tier loopback: several processes on one box each

@@ -82,6 +82,12 @@ constexpr int kSwapPhaseCount = 4;
 // publish, commit, abort, retry, mismatch.
 constexpr int kSwapKindCount = 5;
 
+// DCN-bridge callback kinds (tpunet_bridge_{calls,bytes}_total{kind=...}):
+// all_reduce, all_reduce_start, all_reduce_finish, all_gather,
+// reduce_scatter, all_to_all, broadcast, neighbor_exchange — the collectives
+// tpunet/interop.py stages through a host callback (docs/DESIGN.md §3).
+constexpr int kBridgeKindCount = 8;
+
 // QoS traffic-class slots (latency, bulk, control — TrafficClass in qos.h;
 // kept as a bare count here so telemetry.h need not include qos.h).
 constexpr int kQosClassCount = 3;
@@ -188,6 +194,11 @@ struct MetricsSnapshot {
   StageHist swap_us[kSwapPhaseCount];
   uint64_t swap_events[kSwapKindCount] = {0};
   uint64_t weight_version = 0;
+  // DCN-bridge accounting (docs/DESIGN.md §3 "JAX seam"): host callbacks
+  // the program's io_callback path ran, and the operand bytes that crossed
+  // it, by collective kind. The FFI path never feeds these.
+  uint64_t bridge_calls[kBridgeKindCount] = {0};
+  uint64_t bridge_bytes[kBridgeKindCount] = {0};
   // Zero-copy data-path counters (docs/DESIGN.md "Data path"): wire syscalls
   // indexed by utils.h IoOp (send, recv, sendmsg, recvmsg) and bytes
   // produced by the reduction kernels. syscalls/MiB is derived from these in
@@ -277,6 +288,18 @@ class Telemetry {
   // tracing_enabled() to skip building the phase string).
   void OnCollPhase(uint64_t comm_id, uint64_t coll_seq, const char* phase,
                    uint64_t start_us, uint64_t dur_us, uint64_t nbytes);
+  // Program span (tpunet.telemetry.span through tpunet_c_trace_span): a
+  // host-side span of the Python layer (the DCN bridge's callback, fit()'s
+  // loop) buffered into the SAME trace file, stamped by the caller with
+  // MonotonicUs()'s clock. Tagged {seq, parent}, never {comm_id, coll_seq}:
+  // those stay the collective phases' join key. `parent`/`kind` may be
+  // empty, `step` < 0 means none. Returns false when tracing is off.
+  bool OnProgramSpan(const char* name, uint64_t start_us, uint64_t dur_us,
+                     uint64_t seq, uint64_t nbytes, const char* parent,
+                     const char* kind, int64_t step);
+  // One host callback of the DCN bridge (tpunet_c_bridge_call): `kind`
+  // indexes kBridgeKindCount, `nbytes` is the operand's size.
+  void OnBridgeCall(int kind, uint64_t nbytes);
   // Failure-containment hooks (cold paths). `action` indexes FaultAction.
   void OnFaultInjected(int action);
   void OnStreamFailover();

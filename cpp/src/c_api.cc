@@ -590,6 +590,48 @@ int32_t tpunet_c_trace_set_dir(const char* dir) {
   return TPUNET_OK;
 }
 
+namespace {
+// Names that go into the trace file verbatim: a closed alphabet, so no
+// caller can break the file's JSON.
+bool SpanNameOk(const char* s, bool may_be_empty) {
+  if (!s || !*s) return may_be_empty;
+  size_t n = 0;
+  for (; s[n]; ++n) {
+    const char c = s[n];
+    const bool ok = (c >= 'a' && c <= 'z') || (c >= 'A' && c <= 'Z') ||
+                    (c >= '0' && c <= '9') || c == '_' || c == '.' ||
+                    c == ':' || c == '-';
+    if (!ok || n >= 64) return false;
+  }
+  return true;
+}
+}  // namespace
+
+int32_t tpunet_c_trace_span(const char* name, uint64_t start_us, uint64_t dur_us,
+                            uint64_t seq, uint64_t nbytes, const char* parent,
+                            const char* kind, int64_t step) {
+  if (!SpanNameOk(name, false) || !SpanNameOk(parent, true) ||
+      !SpanNameOk(kind, true)) {
+    return Fail(TPUNET_ERR_INVALID,
+                "span name/parent/kind must be 1-64 chars of [A-Za-z0-9_.:-]");
+  }
+  return tpunet::Telemetry::Get().OnProgramSpan(name, start_us, dur_us, seq,
+                                                nbytes, parent, kind, step)
+             ? 1
+             : 0;
+}
+
+int32_t tpunet_c_bridge_call(int32_t kind, uint64_t nbytes) {
+  if (kind < 0 || kind >= tpunet::kBridgeKindCount) {
+    return Fail(TPUNET_ERR_INVALID,
+                "kind must be 0..7 (all_reduce, all_reduce_start, "
+                "all_reduce_finish, all_gather, reduce_scatter, all_to_all, "
+                "broadcast, neighbor_exchange)");
+  }
+  tpunet::Telemetry::Get().OnBridgeCall(kind, nbytes);
+  return TPUNET_OK;
+}
+
 int32_t tpunet_c_metrics_port(void) {
   return tpunet::Telemetry::Get().MetricsPort();
 }

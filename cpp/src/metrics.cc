@@ -9,6 +9,7 @@
 #include <stddef.h>
 #include <string.h>
 #include <sys/socket.h>
+#include <sys/syscall.h>
 #include <unistd.h>
 
 #include <algorithm>
@@ -102,15 +103,16 @@ struct TcpInfoCompat {
 };
 
 struct Span {
-  enum class Kind : uint8_t { kReq, kColl, kInstant };
+  enum class Kind : uint8_t { kReq, kColl, kInstant, kProg };
   Kind kind = Kind::kReq;
   bool is_send = false;
-  uint64_t comm = 0;    // kReq: comm id | kColl: comm_id | kInstant: stream idx
-  uint64_t req = 0;     // kReq: request id | kColl: coll_seq | kInstant: srtt
-  uint64_t nbytes = 0;  // kReq/kColl: bytes | kInstant: median srtt
+  uint64_t comm = 0;    // kReq: comm id | kColl: comm_id | kInstant: stream idx | kProg: thread id
+  uint64_t req = 0;     // kReq: request id | kColl: coll_seq | kInstant: srtt | kProg: seq
+  uint64_t nbytes = 0;  // kReq/kColl/kProg: bytes | kInstant: median srtt
   uint64_t start_us = 0;
   uint64_t dur_us = 0;
-  std::string name;     // kColl: phase | kInstant: event name
+  std::string name;     // kColl: phase | kInstant: event name | kProg: span name
+  std::string extra;    // kProg: further args, ready-made JSON members
 };
 
 // Request ids are engine-local (each instance counts from 1), so open spans
@@ -234,6 +236,11 @@ struct Telemetry::Impl {
   StageHistAtomic swap_phase[kSwapPhaseCount];
   std::atomic<uint64_t> swap_events[kSwapKindCount] = {};
   std::atomic<uint64_t> weight_version{0};
+
+  // DCN-bridge accounting: host callbacks of the io_callback path and the
+  // operand bytes they staged, by collective kind.
+  std::atomic<uint64_t> bridge_calls[kBridgeKindCount] = {};
+  std::atomic<uint64_t> bridge_bytes[kBridgeKindCount] = {};
 
   // TCP introspection (always on unless TPUNET_TCPINFO_INTERVAL_MS=0).
   uint64_t tcp_interval_us =
@@ -772,6 +779,41 @@ void Telemetry::OnCollPhase(uint64_t comm_id, uint64_t coll_seq, const char* pha
   if (flush) FlushTrace();
 }
 
+bool Telemetry::OnProgramSpan(const char* name, uint64_t start_us, uint64_t dur_us,
+                              uint64_t seq, uint64_t nbytes, const char* parent,
+                              const char* kind, int64_t step) {
+  if (!tracing_enabled()) return false;
+  Impl* im = impl_.get();
+  Span s;
+  s.kind = Span::Kind::kProg;
+  // One Perfetto track per calling thread (the io_callback body runs on a
+  // runtime thread, fit() on the caller's); folded so merge_traces()'s
+  // rank * 1e6 + tid stays inside the rank's range.
+  s.comm = static_cast<uint64_t>(::syscall(SYS_gettid)) % 1000000;
+  s.req = seq;
+  s.nbytes = nbytes;
+  s.start_us = start_us;
+  s.dur_us = dur_us;
+  s.name = name;
+  if (parent && *parent) s.extra += std::string(",\"parent\":\"") + parent + "\"";
+  if (kind && *kind) s.extra += std::string(",\"kind\":\"") + kind + "\"";
+  if (step >= 0) s.extra += ",\"step\":" + std::to_string(step);
+  bool flush = false;
+  {
+    MutexLock lk(im->span_mu);
+    im->done_spans.push_back(std::move(s));
+    flush = im->done_spans.size() >= 4096;
+  }
+  if (flush) FlushTrace();
+  return true;
+}
+
+void Telemetry::OnBridgeCall(int kind, uint64_t nbytes) {
+  if (kind < 0 || kind >= kBridgeKindCount) return;
+  impl_->bridge_calls[kind].fetch_add(1, std::memory_order_relaxed);
+  impl_->bridge_bytes[kind].fetch_add(nbytes, std::memory_order_relaxed);
+}
+
 void Telemetry::OnFaultInjected(int action) {
   if (action < 0 || action >= kFaultActionSlots) return;
   impl_->faults_injected[action].fetch_add(1, std::memory_order_relaxed);
@@ -899,6 +941,8 @@ void Telemetry::Reset() {
   for (auto& h : im->swap_phase) h.Reset();
   for (auto& c : im->swap_events) c.store(0, std::memory_order_relaxed);
   im->weight_version.store(0, std::memory_order_relaxed);
+  for (auto& c : im->bridge_calls) c.store(0, std::memory_order_relaxed);
+  for (auto& c : im->bridge_bytes) c.store(0, std::memory_order_relaxed);
   {
     MutexLock lk(im->win_mu);
     im->win_init = false;
@@ -1037,6 +1081,10 @@ MetricsSnapshot Telemetry::Snapshot() const {
   }
   for (int k = 0; k < kSwapKindCount; ++k) {
     s.swap_events[k] = im->swap_events[k].load(std::memory_order_relaxed);
+  }
+  for (int k = 0; k < kBridgeKindCount; ++k) {
+    s.bridge_calls[k] = im->bridge_calls[k].load(std::memory_order_relaxed);
+    s.bridge_bytes[k] = im->bridge_bytes[k].load(std::memory_order_relaxed);
   }
   s.weight_version = im->weight_version.load(std::memory_order_relaxed);
   for (int t = 0; t < kServeTierCount; ++t) {
@@ -1420,6 +1468,25 @@ std::string Telemetry::PrometheusText() const {
          "serving tier reports; the swap lane's per-rank flip gate).");
   emit("tpunet_weight_version{rank=\"%lld\"} %llu\n", (long long)rank,
        (unsigned long long)s.weight_version);
+  static const char* kBridgeKinds[kBridgeKindCount] = {
+      "all_reduce",     "all_reduce_start", "all_reduce_finish", "all_gather",
+      "reduce_scatter", "all_to_all",       "broadcast",         "neighbor_exchange"};
+  family("tpunet_bridge_calls_total", "counter",
+         "Host callbacks the DCN bridge (tpunet/interop.py io_callback "
+         "path) ran, by collective kind; the FFI path never counts here.");
+  for (int k = 0; k < kBridgeKindCount; ++k) {
+    emit("tpunet_bridge_calls_total{rank=\"%lld\",kind=\"%s\"} %llu\n",
+         (long long)rank, kBridgeKinds[k],
+         (unsigned long long)s.bridge_calls[k]);
+  }
+  family("tpunet_bridge_bytes_total", "counter",
+         "Operand bytes staged through the DCN bridge's host callbacks, by "
+         "collective kind.");
+  for (int k = 0; k < kBridgeKindCount; ++k) {
+    emit("tpunet_bridge_bytes_total{rank=\"%lld\",kind=\"%s\"} %llu\n",
+         (long long)rank, kBridgeKinds[k],
+         (unsigned long long)s.bridge_bytes[k]);
+  }
   family("tpunet_hold_on_request", "gauge",
          "Requests posted but not yet test()ed done (in flight).");
   emit("tpunet_hold_on_request{rank=\"%lld\"} %llu\n", (long long)rank,
@@ -1619,6 +1686,20 @@ bool Telemetry::FlushTrace() {
                 (unsigned long long)s.start_us, (unsigned long long)s.dur_us,
                 (unsigned long long)s.comm, (unsigned long long)s.req,
                 (unsigned long long)s.nbytes, (unsigned long long)HostId());
+        break;
+      case Span::Kind::kProg:
+        // Program span: a host span of the Python layer. Joined to its
+        // parent by (parent, seq); deliberately WITHOUT comm_id/coll_seq,
+        // which mark collective phases for merge_traces() and the ring
+        // readers.
+        fprintf(f,
+                ",\n{\"name\":\"%s\",\"ph\":\"X\",\"pid\":%lld,\"tid\":%llu,"
+                "\"ts\":%llu,\"dur\":%llu,\"args\":{\"seq\":%llu,"
+                "\"nbytes\":%llu%s}}",
+                s.name.c_str(), (long long)im->rank, (unsigned long long)s.comm,
+                (unsigned long long)s.start_us, (unsigned long long)s.dur_us,
+                (unsigned long long)s.req, (unsigned long long)s.nbytes,
+                s.extra.c_str());
         break;
       case Span::Kind::kInstant:
         fprintf(f,

@@ -60,13 +60,15 @@ the ticket API on its own program segments.
 from __future__ import annotations
 
 from functools import partial
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import io_callback
 
-from tpunet import distributed
+from tpunet import distributed, telemetry
+from tpunet.collectives import _c_contig
 
 
 def _comm():
@@ -137,6 +139,39 @@ def _callback_result_spec(x: jax.Array | jnp.ndarray):
     return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x))
 
 
+def _host_operand(_comm, x):
+    return _c_contig(np.asarray(x))
+
+
+def _bridge(kind: str, collective, *, stage_in=_host_operand, stage_out=None):
+    """The host callback of one io_callback collective: the program's whole
+    share of the bridge, counted and cut into spans where the time can go
+    (docs/DESIGN.md 6c; `kind` is a tpunet_bridge_*_total label).
+
+    stage_in(comm, operand): what the program does before the native call,
+        by default the operand as a C-contiguous ndarray;
+    collective(comm, staged): the native call (its result buffer is a lazy
+        np.empty inside Communicator, whose pages the native write faults
+        in), for `_start` the submission, for `_finish` the wait;
+    stage_out(comm, result): what is left between the native call's return
+        and the callback's.
+    Operands after the first are `after=` dependencies and are ignored."""
+
+    def cb(x, *_deps):
+        nbytes = int(x.nbytes)
+        with telemetry.span("dcn.bridge", kind=kind, nbytes=nbytes):
+            telemetry.bridge_call(kind, nbytes)
+            with telemetry.span("dcn.bridge.stage_in", nbytes=nbytes):
+                comm = _comm()
+                staged = stage_in(comm, x)
+            with telemetry.span("dcn.bridge.collective", nbytes=nbytes):
+                out = collective(comm, staged)
+            with telemetry.span("dcn.bridge.stage_out"):
+                return out if stage_out is None else stage_out(comm, out)
+
+    return cb
+
+
 # -- all-reduce -------------------------------------------------------------
 
 
@@ -153,11 +188,11 @@ def dcn_all_reduce(x, op: str = "sum", *, after=()):
     — training all-reduces are ordered by gradient data flow already; the
     kwarg exists for inference/serving traces."""
     if after:
-        return _dcn_all_reduce_after(x, op, tuple(after))
+        return _dcn_all_reduce_impl(x, op, tuple(after))
     return _dcn_all_reduce_diff(x, op)
 
 
-def _dcn_all_reduce_after(x, op: str, after):
+def _dcn_all_reduce_impl(x, op: str, after=()):
     if _ffi_available():
         from tpunet.collectives import _OPS, _dtype_code
 
@@ -165,25 +200,7 @@ def _dcn_all_reduce_after(x, op: str, after):
             "tpunet_all_reduce", _callback_result_spec(x), x, after,
             dtype=np.int64(_dtype_code(np.dtype(jnp.result_type(x)))),
             op=np.int64(_OPS[op]))
-
-    def cb(a):
-        return _comm().all_reduce(np.asarray(a), op)
-
-    return io_callback(cb, _callback_result_spec(x), x, ordered=True)
-
-
-def _dcn_all_reduce_impl(x, op: str):
-    if _ffi_available():
-        from tpunet.collectives import _OPS, _dtype_code
-
-        return _ffi_call(
-            "tpunet_all_reduce", _callback_result_spec(x), x,
-            dtype=np.int64(_dtype_code(np.dtype(jnp.result_type(x)))),
-            op=np.int64(_OPS[op]))
-
-    def cb(a):
-        return _comm().all_reduce(np.asarray(a), op)
-
+    cb = _bridge("all_reduce", lambda c, a: c.all_reduce(a, op))
     return io_callback(cb, _callback_result_spec(x), x, ordered=True)
 
 
@@ -290,10 +307,8 @@ def dcn_all_reduce_start(x, op: str = "sum", *, after=()):
     legal `after=` operand for a later FFI call, pinning the reverse
     direction."""
 
-    def cb(a, *_deps):
-        c = _comm()
-        return np.uint32(_register_pending(c, c.iall_reduce(np.asarray(a), op)))
-
+    cb = _bridge("all_reduce_start", lambda c, a: c.iall_reduce(a, op),
+                 stage_out=lambda c, res: np.uint32(_register_pending(c, res)))
     return io_callback(cb, jax.ShapeDtypeStruct((), jnp.uint32), x,
                        *tuple(after), ordered=True)
 
@@ -304,9 +319,8 @@ def dcn_all_reduce_finish(ticket, like, *, after=()):
     `after=` pins this completion behind earlier FFI `dcn_*` results, same
     contract as `dcn_all_reduce_start`."""
 
-    def cb(t, *_deps):
-        return _pop_pending(_comm(), int(t)).wait()
-
+    cb = _bridge("all_reduce_finish", lambda c, res: res.wait(),
+                 stage_in=lambda c, t: _pop_pending(c, int(t)))
     return io_callback(cb, _callback_result_spec(like), ticket,
                        *tuple(after), ordered=True)
 
@@ -324,9 +338,7 @@ def dcn_all_gather(x, *, after=()):
     if _ffi_available():
         return _ffi_call("tpunet_all_gather", spec, x, after)
 
-    def cb(a):
-        return _comm().all_gather(np.asarray(a))
-
+    cb = _bridge("all_gather", lambda c, a: c.all_gather(a))
     return io_callback(cb, spec, x, ordered=True)
 
 
@@ -347,9 +359,7 @@ def dcn_reduce_scatter(x, op: str = "sum", *, after=()):
             dtype=np.int64(_dtype_code(np.dtype(jnp.result_type(x)))),
             op=np.int64(_OPS[op]))
 
-    def cb(a):
-        return _comm().reduce_scatter(np.asarray(a), op)
-
+    cb = _bridge("reduce_scatter", lambda c, a: c.reduce_scatter(a, op))
     return io_callback(cb, spec, x, ordered=True)
 
 
@@ -366,9 +376,7 @@ def dcn_all_to_all(x, *, after=()):
         return _ffi_call("tpunet_all_to_all", _callback_result_spec(x), x,
                          after)
 
-    def cb(a):
-        return _comm().all_to_all(np.asarray(a))
-
+    cb = _bridge("all_to_all", lambda c, a: c.all_to_all(a))
     return io_callback(cb, _callback_result_spec(x), x, ordered=True)
 
 
@@ -377,9 +385,7 @@ def dcn_broadcast(x, root: int = 0, *, after=()):
         return _ffi_call("tpunet_broadcast", _callback_result_spec(x), x,
                          after, root=np.int64(root))
 
-    def cb(a):
-        return _comm().broadcast(np.asarray(a), root)
-
+    cb = _bridge("broadcast", lambda c, a: c.broadcast(a, root))
     return io_callback(cb, _callback_result_spec(x), x, ordered=True)
 
 
@@ -392,9 +398,7 @@ def dcn_neighbor_exchange(x, *, after=()):
         return _ffi_call("tpunet_neighbor_exchange",
                          _callback_result_spec(x), x, after)
 
-    def cb(a):
-        return _comm().neighbor_exchange(np.asarray(a))
-
+    cb = _bridge("neighbor_exchange", lambda c, a: c.neighbor_exchange(a))
     return io_callback(cb, _callback_result_spec(x), x, ordered=True)
 
 

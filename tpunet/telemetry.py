@@ -16,6 +16,10 @@ Chrome-trace spans for every request plus collective phase spans tagged
                          measurement windows
   flush_trace()       -> write buffered spans (file is valid JSON after)
   profile()           -> context manager that enables tracing at runtime
+  span()              -> context manager around a piece of the PROGRAM's own
+                         host work (the DCN bridge's callback, fit()'s loop):
+                         one span in the native trace file and one on the
+                         JAX profiler's timeline
   merge_traces()      -> join per-rank trace files into one Perfetto
                          timeline, aligned by collective tags
   scrape()            -> GET the native /metrics listener
@@ -29,6 +33,7 @@ Chrome-trace spans for every request plus collective phase spans tagged
   swap_observe()      -> record one weight-swap phase duration sample
   swap_event()        -> count one weight-swap event by kind
   weight_version()    -> set the serving checkpoint-version gauge
+  bridge_call()       -> count one DCN-bridge host callback and its bytes
   flightrec_dump()    -> write this rank's flight-recorder ring to disk
   flightrec_stats()   -> (events_recorded, ring_capacity) of the recorder
 
@@ -48,9 +53,13 @@ from __future__ import annotations
 import contextlib
 import ctypes
 import glob
+import itertools
 import json
 import os
 import re
+import sys
+import threading
+import time
 import urllib.request
 
 from tpunet import _native
@@ -333,15 +342,125 @@ def profile(trace_dir: str | None = None, merge: bool = False):
     lib = _native.load()
     trace_dir = trace_dir or os.environ.get("TPUNET_TRACE_DIR") or "/tmp/tpunet-traces"
     os.makedirs(trace_dir, exist_ok=True)
+    global _native_spans
     _native.check(lib.tpunet_c_trace_set_dir(trace_dir.encode()), "trace_set_dir")
+    _native_spans = True
     prof = _Profile(trace_dir)
     try:
         yield prof
     finally:
+        _native_spans = False
         _native.check(lib.tpunet_c_trace_flush(), "trace_flush")
         _native.check(lib.tpunet_c_trace_set_dir(b""), "trace_set_dir")
         if merge:
             prof.merged_path = merge_traces(trace_dir)
+
+
+# -- program spans ------------------------------------------------------------
+
+# Whether span() feeds the native tracer: TPUNET_TRACE_DIR at load (the
+# native layer reads it once, then), or an open profile(). A rank the native
+# gate (ranks 0-7) leaves out learns so from its first span's answer.
+_native_spans = bool(os.environ.get("TPUNET_TRACE_DIR")
+                     or os.environ.get("BAGUA_NET_JAEGER_ADDRESS"))
+_span_local = threading.local()  # .top: the innermost open span of the thread
+_span_seq = itertools.count(1)   # one number a ROOT span, per process
+
+
+class span:
+    """A span around a piece of the program's own host work.
+
+    ``with telemetry.span("dcn.bridge", kind="all_reduce", nbytes=n): ...``
+
+    Two sinks, one name (docs/DESIGN.md 6c "Trace span model"):
+
+    - the native tracer, when it is on (``profile()`` open or
+      TPUNET_TRACE_DIR set): the span lands in this rank's
+      ``tpunet-trace-rank<R>.json`` beside the request and collective phase
+      spans, on their clock (CLOCK_MONOTONIC), with args ``seq`` (a
+      per-process count of ROOT spans; children carry their root's),
+      ``parent`` (the enclosing span's name), ``nbytes`` and, when given,
+      ``kind`` and ``step`` (from ``step_num``). Other ``args`` reach only
+      the profiler. Never ``comm_id``/``coll_seq``: those mark collective
+      phases for merge_traces() and the ring's readers.
+    - the JAX profiler, always, but only in a process that has imported
+      jax already: ``jax.profiler.TraceAnnotation("tpunet:" + name,
+      **args)`` (``StepTraceAnnotation`` when ``step_num`` is given), so
+      under ``jax.profiler.trace`` the span shares the device operations'
+      timeline, from whatever thread ran it. A root span also carries
+      ``seq`` there: with both sinks on, the two copies of a root span are
+      the clock anchors between the two files.
+
+    With both off the cost is a thread-local read, a flag test and a no-op
+    TraceMe: no native call, nothing kept.
+    """
+
+    __slots__ = ("name", "args", "_outer", "_seq", "_ann", "_t0")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> "span":
+        outer = self._outer = getattr(_span_local, "top", None)
+        args = self.args
+        if outer is None:
+            self._seq = next(_span_seq)
+            args = dict(args, seq=self._seq)
+        else:
+            self._seq = outer._seq
+        _span_local.top = self
+        jax = sys.modules.get("jax")
+        self._ann = None
+        if jax is not None:
+            kind = (jax.profiler.StepTraceAnnotation if "step_num" in args
+                    else jax.profiler.TraceAnnotation)
+            self._ann = kind("tpunet:" + self.name, **args)
+        self._t0 = time.monotonic_ns() if _native_spans else 0
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        global _native_spans
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        t1 = time.monotonic_ns()
+        _span_local.top = self._outer
+        if not (self._t0 and _native_spans):
+            return
+        # Both ends floored to the tracer's microseconds, so that a child
+        # never sticks out of its parent by a rounding.
+        start_us = self._t0 // 1000
+        kind = self.args.get("kind")
+        rc = _native.load().tpunet_c_trace_span(
+            self.name.encode(), start_us, t1 // 1000 - start_us, self._seq,
+            int(self.args.get("nbytes", 0)),
+            self._outer.name.encode() if self._outer is not None else None,
+            str(kind).encode() if kind is not None else None,
+            int(self.args.get("step_num", -1)))
+        if rc == 0:  # the native tracer is off for this rank: stop asking
+            _native_spans = False
+        elif rc < 0 and exc_type is None:
+            _native.check(rc, "trace_span")
+
+
+_BRIDGE_KINDS = {"all_reduce": 0, "all_reduce_start": 1, "all_reduce_finish": 2,
+                 "all_gather": 3, "reduce_scatter": 4, "all_to_all": 5,
+                 "broadcast": 6, "neighbor_exchange": 7}
+
+
+def bridge_call(kind: str, nbytes: int) -> None:
+    """Count one host callback of the DCN bridge (tpunet/interop.py's
+    io_callback path) and its operand bytes into
+    ``tpunet_bridge_calls_total{kind=...}`` and
+    ``tpunet_bridge_bytes_total{kind=...}``."""
+    if kind not in _BRIDGE_KINDS:
+        raise ValueError(
+            f"kind must be one of {sorted(_BRIDGE_KINDS)}, got {kind!r}")
+    lib = _native.load()
+    _native.check(lib.tpunet_c_bridge_call(_BRIDGE_KINDS[kind], max(0, int(nbytes))),
+                  "bridge_call")
 
 
 def _coll_tags(events: list[dict]) -> dict[tuple, int]:

@@ -20,6 +20,7 @@ from typing import Any, Callable, Iterable
 
 import jax
 
+from tpunet import telemetry
 from tpunet.train.checkpoint import CheckpointManager
 from tpunet.train.trainer import TrainState
 
@@ -127,39 +128,53 @@ def fit(
 
             it = prefetch_to_device(it, size=prefetch,
                                     sharding=prefetch_sharding)
+        # Spans (docs/DESIGN.md 6c): one train.step an iteration, on the
+        # profiler's step view by step_num; inside it the places where the
+        # host can hold the device back. A stream that ends early leaves a
+        # last train.step holding only its train.feed.
         while done < steps:
-            try:
-                inputs, labels = next(it)
-            except StopIteration:
-                break  # finite dataset exhausted before the schedule
-            step_rng = jax.random.fold_in(rng, done)
-            state, loss = train_step(state, inputs, labels, step_rng)
-            done += 1
-            if log_every and done % log_every == 0:
-                dt = time.perf_counter() - t0
-                log({
-                    "step": done,
-                    "loss": float(loss),  # host transfer = the sync point
-                    "steps_per_s": (done - window_start) / dt if dt > 0 else 0.0,
-                })
-                t0 = time.perf_counter()
-                window_start = done
-            if (eval_fn is not None and eval_every
-                    and done % eval_every == 0 and done < steps):
-                log({"step": done, "eval": eval_fn(state)})
-                last_eval_step = done
-                # Eval wall time must not deflate the NEXT window's
-                # steps_per_s: restart the throughput window after it.
-                t0 = time.perf_counter()
-                window_start = done
-            if mgr is not None and checkpoint_every and done % checkpoint_every == 0:
-                mgr.save(done, state)
+            with telemetry.span("train.step", step_num=done):
+                try:
+                    with telemetry.span("train.feed"):
+                        inputs, labels = next(it)
+                except StopIteration:
+                    break  # finite dataset exhausted before the schedule
+                step_rng = jax.random.fold_in(rng, done)
+                with telemetry.span("train.step_fn"):
+                    state, loss = train_step(state, inputs, labels, step_rng)
+                done += 1
+                if log_every and done % log_every == 0:
+                    dt = time.perf_counter() - t0
+                    with telemetry.span("train.loss_fetch"):
+                        loss_host = float(loss)  # host transfer = the sync point
+                    log({
+                        "step": done,
+                        "loss": loss_host,
+                        "steps_per_s": (done - window_start) / dt if dt > 0 else 0.0,
+                    })
+                    t0 = time.perf_counter()
+                    window_start = done
+                if (eval_fn is not None and eval_every
+                        and done % eval_every == 0 and done < steps):
+                    with telemetry.span("train.eval"):
+                        evaluated = eval_fn(state)
+                    log({"step": done, "eval": evaluated})
+                    last_eval_step = done
+                    # Eval wall time must not deflate the NEXT window's
+                    # steps_per_s: restart the throughput window after it.
+                    t0 = time.perf_counter()
+                    window_start = done
+                if mgr is not None and checkpoint_every and done % checkpoint_every == 0:
+                    with telemetry.span("train.checkpoint"):
+                        mgr.save(done, state)
         if eval_fn is not None and done > start_step and done != last_eval_step:
             # Final evaluation on the finished state (also covers runs whose
             # stream ended early) — skipped for pure no-op re-invocations and
             # when the cadence already evaluated this exact step (a stream
             # exhausted right at an eval point must not eval twice).
-            log({"step": done, "eval": eval_fn(state)})
+            with telemetry.span("train.eval"):
+                evaluated = eval_fn(state)
+            log({"step": done, "eval": evaluated})
         if mgr is not None:
             if done == start_step and start_step < steps:
                 # The schedule wanted more steps but the stream yielded
@@ -179,7 +194,8 @@ def fit(
             # force=True bypasses the save-interval policy but still raises
             # StepAlreadyExistsError on a duplicate step.
             if mgr.latest_step() != done:
-                mgr.save(done, state, force=True)
+                with telemetry.span("train.checkpoint"):
+                    mgr.save(done, state, force=True)
             mgr.wait_until_finished()
     finally:
         if mgr is not None:
