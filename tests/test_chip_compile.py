@@ -157,6 +157,27 @@ def test_cross_host_train_step_bucketed(one_chip, as_on_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_cross_host_train_step_is_two_programs_without_a_host_transfer(
+        one_chip, as_on_chip):
+    """The flat cross-host step: the gradient leaves the chip between its two
+    programs, so neither holds a callback, a send or a recv, and the kernels
+    are all in the first."""
+    from conftest import free_port
+
+    from tpunet import distributed
+
+    distributed.finalize()
+    distributed.initialize(f"127.0.0.1:{free_port()}", 0, 1)
+    try:
+        text = _train_program(one_chip, cross_host=True).as_text()
+    finally:
+        distributed.finalize()
+    assert text.count(KERNEL) == FULL.train_kernels
+    assert "jit_grad_program" in text and "jit_apply_program" in text
+    for mark in ("is_host_transfer", "callback", "send-done", "recv-done"):
+        assert mark not in text, mark
+
+
 @pytest.mark.parametrize("kv_heads,window", [(None, None), (4, 256)],
                          ids=["mha", "gqa4_window256"])
 def test_generate_b8_p512_n256(one_chip, as_on_chip, kv_heads, window):

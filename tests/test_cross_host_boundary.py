@@ -1,0 +1,316 @@
+"""The flat cross-host train step as two device programs with the gradient's
+all-reduce between them on the host (make_train_step(cross_host=True)):
+bit-identical to the single program that held dcn_pmean(flat), which stays
+here as the oracle; what the ahead-of-time compile gives back; the bridge's
+counters and spans once a step."""
+
+from __future__ import annotations
+
+import glob
+import json
+import os
+
+import numpy as np
+import pytest
+
+# Module level so mp-spawn children (which re-import this module, but not
+# conftest.py) are held to the CPU too.
+os.environ["JAX_PLATFORMS"] = "cpu"
+import jax  # noqa: E402
+
+jax.config.update("jax_platforms", "cpu")
+
+from conftest import free_port, run_spawn_workers  # noqa: E402
+
+STEPS = 3
+BRIDGE_CHILDREN = ["dcn.bridge.stage_in", "dcn.bridge.collective", "dcn.bridge.stage_out"]
+
+
+def _in_jit_step(model, tx, donate: bool, grad_compression=None, accum_steps=None):
+    """The step as it was before the boundary: ONE program, the flat
+    gradient through dcn_pmean in its middle (an FFI custom call on the CPU,
+    an ordered io_callback with TPUNET_FFI_COLLECTIVES=0)."""
+    import jax.numpy as jnp
+    from jax.flatten_util import ravel_pytree
+
+    from tpunet.interop import dcn_pmean
+    from tpunet.train.trainer import TrainState, _apply_updates, _value_and_grads
+
+    def train_step(state, images, labels, dropout_rng):
+        loss, grads = _value_and_grads(model, state.params, images, labels,
+                                       dropout_rng, 0.01, None, accum_steps, 0.0)
+        leaves, treedef = jax.tree_util.tree_flatten(grads)
+        f0 = [leaf.dtype == jax.dtypes.float0 for leaf in leaves]
+        flat, unravel = ravel_pytree(
+            [leaf for leaf, skip in zip(leaves, f0) if not skip])
+        if grad_compression == "bf16":
+            reduced = dcn_pmean(flat.astype(jnp.bfloat16)).astype(flat.dtype)
+        else:
+            reduced = dcn_pmean(flat)
+        it = iter(unravel(reduced))
+        grads = jax.tree_util.tree_unflatten(
+            treedef, [leaf if skip else next(it) for leaf, skip in zip(leaves, f0)])
+        updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        params = _apply_updates(state.params, updates)
+        return TrainState(params, opt_state, state.step + 1), loss
+
+    return jax.jit(train_step, donate_argnums=(0,) if donate else ())
+
+
+def _tiny(rank: int, qlora: bool = False):
+    """(model, tx, a fresh state's maker, tokens, labels); the ranks differ
+    in their batch, so the mean over them is what couples them."""
+    import jax.numpy as jnp
+    import optax
+
+    from tpunet.models import Transformer, graft_base, lora_optimizer, quantize_params
+    from tpunet.train import TrainState
+
+    model = Transformer(vocab=32, d_model=16, n_layers=1, n_heads=2, d_ff=32,
+                        compute_dtype=jnp.float32)
+    toks = jax.random.randint(jax.random.PRNGKey(10 + rank), (4, 8), 0, 32)
+    labels = jnp.roll(toks, -1, axis=1)
+    params = model.init(jax.random.PRNGKey(0), toks)["params"]
+    tx = optax.adamw(1e-2)
+    if qlora:  # frozen int8 leaves: their gradients are float0
+        model = model.clone(weight_quant="int8", lora_rank=4)
+        params = graft_base(model.init(jax.random.PRNGKey(2), toks)["params"],
+                            quantize_params(params))
+        tx = lora_optimizer(optax.adam(5e-3), params)
+
+    def fresh():  # a donating step eats its state: one each
+        p = jax.tree.map(jnp.copy, params)
+        return TrainState(p, tx.init(p), jnp.zeros((), jnp.int32))
+
+    return model, tx, fresh, toks, labels
+
+
+def _run(step, state, toks, labels):
+    losses = []
+    for i in range(STEPS):
+        state, loss = step(state, toks, labels, jax.random.PRNGKey(i))
+        losses.append(np.asarray(loss))
+    return jax.tree.map(np.asarray, state), losses
+
+
+def _assert_bitwise(got, want) -> None:
+    (gs, gl), (ws, wl) = got, want
+    paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(ws)]
+    for path, a, b in zip(paths, jax.tree.leaves(gs), jax.tree.leaves(ws), strict=True):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), path
+    assert [x.tobytes() for x in gl] == [x.tobytes() for x in wl]
+
+
+CASES = {
+    "donate-ffi": dict(donate=True),
+    "keep-ffi": dict(donate=False),
+    "donate-callback": dict(donate=True, ffi="0"),
+    "keep-callback": dict(donate=False, ffi="0"),
+    "float0-leaves": dict(donate=False, qlora=True),
+    "bf16-ffi": dict(donate=True, grad_compression="bf16"),
+    "bf16-callback": dict(donate=False, grad_compression="bf16", ffi="0"),
+    "accum2": dict(donate=True, accum_steps=2),
+}
+
+
+def _parity_worker(rank: int, world: int, port: int, q, case: str) -> None:
+    try:
+        kw = dict(CASES[case])
+        os.environ["TPUNET_FFI_COLLECTIVES"] = kw.pop("ffi", "1")
+        qlora = kw.pop("qlora", False)
+        from tpunet import distributed, telemetry
+        from tpunet.train import make_train_step
+
+        distributed.initialize(f"127.0.0.1:{port}", rank, world)
+        model, tx, fresh, toks, labels = _tiny(rank, qlora)
+        want = _run(_in_jit_step(model, tx, **kw), fresh(), toks, labels)
+
+        def calls() -> float:
+            return sum(telemetry.metrics()["tpunet_bridge_calls_total"].values())
+
+        before = calls()
+        got = _run(make_train_step(model, tx, cross_host=True, **kw), fresh(),
+                   toks, labels)
+        # the boundary is taken whatever the oracle's bridge was
+        assert calls() - before == STEPS
+        _assert_bitwise(got, want)
+        if qlora:
+            assert any(leaf.dtype == np.int8 for leaf in jax.tree.leaves(got[0].params))
+        assert not np.array_equal(got[1][0], got[1][-1])  # it did train
+        distributed.finalize()
+        q.put((rank, "OK"))
+    except Exception as e:  # noqa: BLE001
+        import traceback
+
+        q.put((rank, f"FAIL: {type(e).__name__}: {e}\n{traceback.format_exc()[-800:]}"))
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_three_steps_bit_identical_to_the_in_jit_path(case):
+    run_spawn_workers(_parity_worker, 2, extra_args=(case,))
+
+
+# -- one rank, in this process ---------------------------------------------------
+
+@pytest.fixture()
+def world_of_one():
+    from tpunet import distributed
+
+    distributed.finalize()
+    distributed.initialize(f"127.0.0.1:{free_port()}", 0, 1)
+    yield
+    distributed.finalize()
+
+
+def _bridge_counts() -> dict:
+    from tpunet import telemetry
+
+    m = telemetry.metrics()
+    return {(fam.split("_")[2], telemetry.labels(key)["kind"]): v
+            for fam in ("tpunet_bridge_calls_total", "tpunet_bridge_bytes_total")
+            for key, v in m[fam].items() if v}
+
+
+def _n_grad(state) -> int:
+    return sum(x.size for x in jax.tree.leaves(state.params))
+
+
+def test_lowered_and_compiled_is_callable_and_holds_both_programs(world_of_one):
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    step = make_train_step(model, tx, cross_host=True)
+    key = jax.random.PRNGKey(0)
+    compiled = step.lower(fresh(), toks, labels, key).compile()
+    text = compiled.as_text()
+    assert "jit_grad_program" in text and "jit_apply_program" in text
+    # nothing of either program leaves the device in its middle
+    for mark in ("callback", "is_host_transfer", "custom_call_target=\"tpunet",
+                 "send-done", "recv-done"):
+        assert mark not in text, mark
+    _assert_bitwise(_run(compiled, fresh(), toks, labels),
+                    _run(step, fresh(), toks, labels))
+    # abstract arguments lower too (tests/test_chip_compile.py compiles so)
+    shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                          (fresh(), toks, labels, key))
+    assert "jit_apply_program" in step.lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("grad_compression,itemsize", [(None, 4), ("bf16", 2)],
+                         ids=["f32", "bf16"])
+def test_one_bridge_call_and_the_vectors_bytes_a_step(world_of_one, grad_compression,
+                                                      itemsize):
+    from tpunet import telemetry
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    step = make_train_step(model, tx, cross_host=True,
+                           grad_compression=grad_compression)
+    state = fresh()
+    n = _n_grad(state)
+    telemetry.reset()
+    _run(step, state, toks, labels)
+    assert _bridge_counts() == {("calls", "all_reduce"): STEPS,
+                                ("bytes", "all_reduce"): STEPS * itemsize * n}
+
+
+def test_bridge_spans_nest_once_a_step_under_fit(world_of_one, tmp_path):
+    from tpunet import telemetry
+    from tpunet.train import fit, make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    step = make_train_step(model, tx, cross_host=True)
+    state = fresh()
+    n = _n_grad(state)
+    with telemetry.profile(str(tmp_path)):
+        fit(state, step, [(toks, labels)] * STEPS, steps=STEPS, log_every=1,
+            log_fn=lambda m: None)
+    (path,) = glob.glob(os.path.join(str(tmp_path), "tpunet-trace-rank*.json"))
+    with open(path) as f:
+        events = [e for e in json.load(f) if e.get("ph") == "X"]
+    bridges = [e for e in events if e["name"] == "dcn.bridge"]
+    assert len(bridges) == STEPS
+    steps = {e["args"]["seq"]: e for e in events if e["name"] == "train.step"}
+    for b in bridges:
+        # the exchange runs on the thread that called the step, inside it
+        assert b["args"]["parent"] == "train.step_fn" and b["args"]["seq"] in steps
+        assert b["args"]["kind"] == "all_reduce" and b["args"]["nbytes"] == 4 * n
+        kids = sorted((e for e in events if e["args"].get("parent") == "dcn.bridge"
+                       and e["args"]["seq"] == b["args"]["seq"]), key=lambda e: e["ts"])
+        assert [k["name"] for k in kids] == BRIDGE_CHILDREN
+        assert all(b["ts"] <= k["ts"] and k["ts"] + k["dur"] <= b["ts"] + b["dur"]
+                   and k["tid"] == b["tid"] for k in kids)
+    assert len({b["args"]["seq"] for b in bridges}) == STEPS
+
+
+def test_the_communicator_is_resolved_at_every_call(world_of_one):
+    """Elastic recovery re-points the process-default communicator under
+    programs that are already compiled."""
+    from tpunet import distributed
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    compiled = make_train_step(model, tx, cross_host=True, donate=False).lower(
+        fresh(), toks, labels, jax.random.PRNGKey(0)).compile()
+    want = _run(compiled, fresh(), toks, labels)
+    first = distributed.global_communicator()
+    distributed.finalize()
+    distributed.initialize(f"127.0.0.1:{free_port()}", 0, 1)
+    assert distributed.global_communicator() is not first
+    _assert_bitwise(_run(compiled, fresh(), toks, labels), want)
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(cross_host=True, bucket_bytes=1 << 10)],
+                         ids=["one-host", "bucketed"])
+def test_the_other_steps_are_still_one_jitted_program(world_of_one, kw):
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    step = make_train_step(model, tx, **kw)
+    assert type(step) is type(jax.jit(lambda: 0))
+    assert "jit_train_step" in step.lower(fresh(), toks, labels,
+                                          jax.random.PRNGKey(0)).as_text()
+
+
+def test_the_result_buffer_is_kept_and_free_again_when_a_call_returns(world_of_one):
+    """The ring reduces into ONE buffer a step object, 64-byte aligned (the
+    CPU backend's device_put then aliases it). Calls that no data flow
+    chains (the same state twice) must not see each other's bytes: a call
+    returns only when its apply program has read the buffer."""
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    step = make_train_step(model, tx, cross_host=True, donate=False)
+    state, key = fresh(), jax.random.PRNGKey(0)
+    other = jax.random.randint(jax.random.PRNGKey(99), toks.shape, 0, 32)
+    first = jax.tree.map(np.asarray, step(state, toks, labels, key))
+    buf = step._out
+    assert buf.ctypes.data % 64 == 0 and buf.nbytes == 4 * _n_grad(state)
+    for _ in range(3):
+        step(state, other, labels, key)  # another gradient through the same buffer
+        again = jax.tree.map(np.asarray, step(state, toks, labels, key))
+        assert step._out is buf
+        for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(first), strict=True):
+            assert a.tobytes() == b.tobytes()
+
+
+def test_all_reduce_into_a_callers_buffer(world_of_one):
+    from tpunet import distributed
+    from tpunet.interop import host_buffer_like
+
+    comm = distributed.global_communicator()
+    x = np.arange(1000, dtype=np.float32)
+    out = host_buffer_like(x)
+    assert out.shape == x.shape and out.dtype == x.dtype and out.ctypes.data % 64 == 0
+    assert comm.all_reduce(x, "sum", out=out) is out
+    np.testing.assert_array_equal(out, x)
+    for bad in (np.empty(999, np.float32), np.empty(1000, np.float64),
+                np.empty(2000, np.float32)[::2], [0.0] * 1000):
+        with pytest.raises(ValueError, match="out must be"):
+            comm.all_reduce(x, "sum", out=bad)
+    frozen = np.empty(1000, np.float32)
+    frozen.flags.writeable = False
+    with pytest.raises(ValueError, match="out must be"):
+        comm.all_reduce(x, "sum", out=frozen)
+    with pytest.raises(ValueError, match="pass no out"):
+        comm.all_reduce(x.copy(), "sum", inplace=True, out=out)

@@ -174,10 +174,15 @@ class Communicator:
 
     # -- collectives -------------------------------------------------------
 
-    def all_reduce(self, arr: Any, op: str = "sum", inplace: bool = False) -> np.ndarray:
+    def all_reduce(self, arr: Any, op: str = "sum", inplace: bool = False,
+                   out: np.ndarray | None = None) -> np.ndarray:
         """AllReduce. inplace=True reduces into `arr` itself (must be a
         C-contiguous ndarray) — skips the send→recv staging copy, which
-        matters at 100MB+ gradient-bucket sizes."""
+        matters at 100MB+ gradient-bucket sizes. `out`: a writable
+        C-contiguous ndarray of arr's shape and dtype to reduce into and
+        return, for a caller that keeps its result buffer across calls (a
+        new np.empty of 553 MB costs its first-touch page faults on every
+        call: 0.6 s on the v5e's host, PERF.md section 7)."""
         caller_arr = arr
         arr = np.asarray(arr)
         if inplace and (arr is not caller_arr or not arr.flags.c_contiguous):
@@ -186,7 +191,18 @@ class Communicator:
                 "copy would leave the caller's buffer unchanged)"
             )
         arr = _c_contig(arr)
-        out = arr if inplace else np.empty_like(arr)
+        if inplace:
+            if out is not None:
+                raise ValueError("inplace=True reduces into arr: pass no out")
+            out = arr
+        elif out is None:
+            out = np.empty_like(arr)
+        elif (not isinstance(out, np.ndarray) or out.shape != arr.shape
+              or out.dtype != arr.dtype or not out.flags.c_contiguous
+              or not out.flags.writeable):
+            raise ValueError(
+                f"out must be a writable C-contiguous ndarray of shape "
+                f"{arr.shape} and dtype {arr.dtype}")
         _native.check(
             self._lib.tpunet_comm_all_reduce(
                 self._id,

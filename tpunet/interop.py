@@ -1,4 +1,15 @@
-"""JAX ↔ tpunet interop: cross-host collectives inside jitted programs.
+"""JAX ↔ tpunet interop: cross-host collectives inside jitted programs,
+and one between them.
+
+A collective that has a program boundary to stand at does not enter a
+program at all: `host_all_reduce` takes a device array to the host by the
+runtime's own array transfer, reduces it over the ring and puts the result
+back, from the calling thread. The trainer's flat gradient exchange
+(make_train_step(cross_host=True): between backward and the optimizer) is
+that case and uses nothing else of this module. The in-jit seam below is for
+collectives in the MIDDLE of a program — ZeRO's reduce-scatter and
+all-gather, the bucketed start/finish tickets, hierarchical_psum under
+shard_map, ring and zigzag attention — which cannot leave it.
 
 XLA has no NCCL-style net-plugin seam (SURVEY §7 hard-part #1), so the
 cross-host path enters jitted code two ways:
@@ -225,6 +236,41 @@ def dcn_psum(x):
 def dcn_pmean(x):
     w = distributed.world_size()
     return dcn_all_reduce(x, "sum") / jnp.asarray(w, dtype=jnp.result_type(x))
+
+
+# -- all-reduce at a program boundary ----------------------------------------
+
+
+def host_buffer_like(x) -> np.ndarray:
+    """An uninitialised host array of x's shape and dtype, 64-byte aligned:
+    the CPU backend's jax.device_put takes such an array as it is, without
+    a copy (an np.empty of this size sits 16 bytes past a page and is
+    copied). A result buffer for host_all_reduce's `out`."""
+    nbytes = int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+    raw = np.empty(nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    return raw[start:start + nbytes].view(x.dtype).reshape(x.shape)
+
+
+def host_all_reduce(x: jax.Array, op: str = "sum",
+                    out: np.ndarray | None = None) -> jax.Array:
+    """AllReduce the device array `x` across processes BETWEEN two device
+    programs, from the calling thread: to the host by the runtime's own
+    array transfer (np.asarray: a copy off an accelerator, a view of a CPU
+    array), one Communicator.all_reduce, and back by jax.device_put to where
+    `x` was. For a collective that has a program boundary to stand at (the
+    trainer's gradient exchange, between backward and the optimizer): no
+    host-transfer operation in either program, and both stay cacheable.
+    Same spans and counters as the in-jit bridge, whose helper this calls.
+
+    `out` (from host_buffer_like): the ring's result buffer, kept by the
+    caller across calls. jax.device_put returns before an accelerator has
+    the bytes, and on the CPU backend the array that comes back IS `out`: so
+    the caller may pass `out` again only once whatever consumed the last
+    result has finished."""
+    return _bridge(
+        "all_reduce", lambda c, a: c.all_reduce(a, op, out=out),
+        stage_out=lambda c, res: jax.device_put(res, x.sharding))(x)
 
 
 # -- nonblocking all-reduce (gradient-bucket overlap) -----------------------

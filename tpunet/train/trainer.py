@@ -2,15 +2,25 @@
 sync over the tpunet DCN transport.
 
 Design (TPU-first):
-  * One jitted function contains forward, backward, and update — XLA fuses
-    elementwise ops into the matmuls and inserts ICI collectives from the
-    array shardings (batch over `dp`, Megatron-split classifier over `mdl`).
+  * On one host, ONE jitted function contains forward, backward, and update
+    — XLA fuses elementwise ops into the matmuls and inserts ICI collectives
+    from the array shardings (batch over `dp`, Megatron-split classifier
+    over `mdl`).
   * Cross-host gradient sync flattens the whole gradient pytree into ONE
     contiguous vector before the DCN all-reduce (`ravel_pytree`), so the
     multi-stream transport stripes a single large message instead of
     dribbling per-layer buffers — the same bucketing insight behind the
     reference's fairness design (large chunked messages saturate parallel
     streams; reference SURVEY §2.2 step 5).
+  * That all-reduce sits between backward and the optimizer, which is a
+    program boundary: the flat cross-host step is TWO device programs (grad,
+    apply) with the ring between them on the host, the vector crossing by
+    the runtime's ordinary array transfers (`_BoundaryStep`). Inside one
+    program it would cross as an `io_callback` host transfer, which on the
+    v5e cost 2.7 s of a 3.15 s VGG16 step around a 0.29 s ring (PERF.md,
+    PR 24 and 25). Collectives in the MIDDLE of a program (ZeRO's
+    reduce-scatter and all-gather, the bucketed overlap, ring attention)
+    have no boundary to stand at and keep tpunet.interop's in-jit seam.
 """
 
 from __future__ import annotations
@@ -294,11 +304,33 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
                     fused_xent_block: int | None = None,
                     accum_steps: int | None = None,
                     z_loss: float = 0.0):
-    """Build the jitted train step.
+    """Build the train step: (state, inputs, labels, rng) -> (state, loss).
+
+    cross_host=False (one host): ONE jitted program, `state` donated when
+    `donate`. The return value is the `jax.jit` object itself.
 
     cross_host=True adds the DCN gradient all-reduce tier (requires
-    tpunet.distributed.initialize() BEFORE the first trace — the decision
-    is baked into the executable).
+    tpunet.distributed.initialize() BEFORE this call — the world size is
+    baked into the executables). The step is then two jitted programs and
+    the exchange between them (`_BoundaryStep`, called like the jitted step;
+    `.lower(*args).compile()` compiles both ahead of time, `.as_text()` of
+    the result is both programs' text):
+      1. grad: forward and backward, the gradient raveled into one flat
+         vector; returns (loss, flat). Nothing is donated: the apply
+         program still needs `state`.
+      2. on the host, tpunet.interop.host_all_reduce(flat): device to host,
+         ONE Communicator.all_reduce(sum) over the process-default
+         communicator as it is at that call (elastic recovery re-points it
+         under compiled programs) into a result buffer the step object
+         keeps across steps, and back to the device.
+      3. apply: the mean (the sum over `world`, on the device, in the
+         vector's dtype), unraveled, through `tx` into the parameters;
+         `state` donated when `donate`. The call returns when this program
+         has finished (the kept buffer is then free again).
+    The all-reduce sits at the boundary because it can: a collective between
+    backward and the optimizer needs nothing of either program while it
+    runs, and outside a program its bytes move by the runtime's plain array
+    transfers instead of an `io_callback`'s host-transfer operations.
 
     grad_compression="bf16" casts the flattened gradient vector to bfloat16
     before the cross-host all-reduce and back after — halving DCN bytes for
@@ -312,9 +344,10 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     the router can collapse onto one expert and capacity-drop most tokens.
 
     bucket_bytes (cross_host only): sync gradients in byte-bounded buckets
-    via NONBLOCKING all-reduces instead of one flat blocking vector, so DCN
-    transfer overlaps backward compute (see _bucketed_dcn_pmean). None keeps
-    the single-vector path.
+    via NONBLOCKING all-reduces INSIDE one jitted program instead of one
+    flat vector at the boundary, so DCN transfer overlaps backward compute
+    (see _bucketed_dcn_pmean; the in-jit io_callback seam). None keeps the
+    single-vector path.
     """
     if grad_compression not in (None, "bf16"):
         raise ValueError(f"unknown grad_compression {grad_compression!r}")
@@ -323,7 +356,6 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     if cross_host:
         # Import here so single-host training never touches the transport.
         from tpunet import distributed
-        from tpunet.interop import dcn_pmean
 
         world = distributed.world_size()  # raises early if initialize() was skipped
         # One cast path: when the wire already compresses to bf16, ship f32
@@ -333,37 +365,110 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
         if grad_compression == "bf16" and _wire_handles_bf16():
             grad_compression = None
 
-    def train_step(state: TrainState, images, labels, dropout_rng):
-        loss, grads = _value_and_grads(model, state.params, images, labels,
-                                       dropout_rng, moe_aux_weight,
-                                       fused_xent_block, accum_steps, z_loss)
+    def value_and_grads(state, images, labels, dropout_rng):
+        return _value_and_grads(model, state.params, images, labels,
+                                dropout_rng, moe_aux_weight,
+                                fused_xent_block, accum_steps, z_loss)
 
-        if cross_host:
-            if bucket_bytes is not None:
-                grads = _bucketed_dcn_pmean(grads, bucket_bytes, grad_compression, world)
-            else:
-                # ravel_pytree cannot flatten float0 leaves (QLoRA's frozen
-                # int8 base under allow_int): partition them out, reduce
-                # the inexact vector, reinsert the placeholders.
-                leaves, treedef = jax.tree_util.tree_flatten(grads)
-                f0 = [leaf.dtype == jax.dtypes.float0 for leaf in leaves]
-                flat, unravel = ravel_pytree(
-                    [leaf for leaf, skip in zip(leaves, f0) if not skip])
-                if grad_compression == "bf16":
-                    reduced = dcn_pmean(flat.astype(jnp.bfloat16)).astype(flat.dtype)
-                else:
-                    reduced = dcn_pmean(flat)
-                it = iter(unravel(reduced))
-                grads = jax.tree_util.tree_unflatten(
-                    treedef,
-                    [leaf if skip else next(it)
-                     for leaf, skip in zip(leaves, f0)])
-
+    def updated(state, grads):
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
         params = _apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss
+        return TrainState(params, opt_state, state.step + 1)
 
-    return jax.jit(train_step, donate_argnums=(0,) if donate else ())
+    donated = (0,) if donate else ()
+    if not cross_host or bucket_bytes is not None:
+        def train_step(state: TrainState, images, labels, dropout_rng):
+            loss, grads = value_and_grads(state, images, labels, dropout_rng)
+            if cross_host:
+                grads = _bucketed_dcn_pmean(grads, bucket_bytes, grad_compression, world)
+            return updated(state, grads), loss
+
+        return jax.jit(train_step, donate_argnums=donated)
+
+    def grad_program(state: TrainState, images, labels, dropout_rng):
+        loss, grads = value_and_grads(state, images, labels, dropout_rng)
+        # ravel_pytree cannot flatten float0 leaves (QLoRA's frozen int8
+        # base under allow_int): they carry no gradient and stay behind.
+        flat, _ = ravel_pytree([g for g in jax.tree.leaves(grads)
+                                if g.dtype != jax.dtypes.float0])
+        if grad_compression == "bf16":
+            flat = flat.astype(jnp.bfloat16)
+        return loss, flat
+
+    def apply_program(state: TrainState, reduced):
+        # A gradient has its parameter's shape and dtype, and an integer
+        # parameter's is float0: the tree the vector was raveled from is
+        # read off the parameters (whose own raveled values nothing uses).
+        leaves, treedef = jax.tree_util.tree_flatten(state.params)
+        f0 = [not jnp.issubdtype(p.dtype, jnp.inexact) for p in leaves]
+        like, unravel = ravel_pytree(
+            [p for p, skip in zip(leaves, f0) if not skip])
+        # the mean in the wire's dtype, as dcn_pmean forms it
+        mean = reduced / jnp.asarray(world, reduced.dtype)
+        it = iter(unravel(mean.astype(like.dtype)))
+        grads = jax.tree_util.tree_unflatten(
+            treedef, [_grad_zeros(p) if skip else next(it)
+                      for p, skip in zip(leaves, f0)])
+        return updated(state, grads)
+
+    # The reduced vector is not donated: no output has its shape, so XLA
+    # could not reuse it, and it is dropped when the call returns anyway.
+    return _BoundaryStep(jax.jit(grad_program),
+                         jax.jit(apply_program, donate_argnums=donated))
+
+
+class _BoundaryStep:
+    """The flat cross-host step: a grad program and an apply program with
+    the gradient's all-reduce between them, on the host. Called like the
+    jitted step it stands in for; `.lower(*args).compile()` compiles both
+    halves ahead of time and gives an object of the same kind whose
+    `.as_text()` is both programs' text.
+
+    The ring's result buffer lives here, across steps (`_out`, made at the
+    first call): a new one a step costs its page faults, 0.6 s for VGG16's
+    553 MB on the v5e's host, on the chip rank and on a CPU rank alike. It
+    is handed to jax.device_put, which returns before an accelerator has
+    the bytes and which on the CPU backend aliases it: so a call returns
+    only when its apply program has finished, and the next call's ring
+    finds the buffer free whatever state that call is given."""
+
+    def __init__(self, grad, apply):
+        self._grad, self._apply = grad, apply
+        self._out = None
+
+    def __call__(self, state, images, labels, dropout_rng):
+        from tpunet.interop import host_all_reduce, host_buffer_like
+
+        loss, flat = self._grad(state, images, labels, dropout_rng)
+        if self._out is None:
+            self._out = host_buffer_like(flat)
+        state = self._apply(state, host_all_reduce(flat, out=self._out))
+        jax.block_until_ready(state.step)
+        return state, loss
+
+    def lower(self, state, images, labels, dropout_rng):
+        return _LoweredBoundaryStep(
+            self._grad.lower(state, images, labels, dropout_rng),
+            self._apply, state)
+
+    def as_text(self) -> str:
+        return self._grad.as_text() + "\n" + self._apply.as_text()
+
+
+class _LoweredBoundaryStep:
+    """The apply program's operand is placed where the grad program leaves
+    its vector, which only the compiled grad program says: so the second
+    half is lowered here, in compile()."""
+
+    def __init__(self, grad_lowered, apply, state):
+        self._grad, self._apply, self._state = grad_lowered, apply, state
+
+    def compile(self) -> _BoundaryStep:
+        grad = self._grad.compile()
+        flat = self._grad.out_info[1]
+        reduced = jax.ShapeDtypeStruct(flat.shape, flat.dtype,
+                                       sharding=grad.output_shardings[1])
+        return _BoundaryStep(grad, self._apply.lower(self._state, reduced).compile())
 
 
 def _zero_shard_geometry(n: int, world: int) -> tuple[int, int]:
