@@ -267,6 +267,10 @@ struct ShmComm {
   // interleaved with them over ctrl (nack: ctrl-TCP mode). seg_name is
   // kept so a nack can unlink the segment the receiver never opened.
   bool await_ack = false;
+  // True while completed pre-verdict sends sit in the ring with their LEN
+  // frames still deferred: a plain close must let the verdict flush them
+  // first (see Shutdown), or data the caller saw complete is taken back.
+  std::atomic<bool> preack_unflushed{false};
   std::string seg_name;
   struct Deferred {
     uint64_t len = 0;         // message length (the deferred LEN frame)
@@ -300,6 +304,20 @@ struct ShmComm {
       return;
     }
     msgs.Close();
+    // A send that completed into the ring before the handshake verdict is
+    // the kernel-buffer analogue: closing a TCP socket still delivers what
+    // send() accepted, so a plain close waits (bounded by the handshake
+    // timeout, and not at all once aborted) for the scheduler to resolve the
+    // verdict and flush the deferred LEN frames.
+    if (is_send && preack_unflushed.load(std::memory_order_acquire)) {
+      const uint64_t deadline_us =
+          MonotonicUs() + 1000 * GetEnvU64("TPUNET_HANDSHAKE_TIMEOUT_MS", 10000);
+      while (preack_unflushed.load(std::memory_order_acquire) &&
+             !aborted.load(std::memory_order_acquire) &&
+             MonotonicUs() < deadline_us) {
+        std::this_thread::sleep_for(std::chrono::milliseconds(1));
+      }
+    }
     Abort();
     if (scheduler && scheduler->joinable()) scheduler->join();
     if (ctrl_fd >= 0) ::close(ctrl_fd);
@@ -559,6 +577,7 @@ Status SendPreAckMsg(ShmComm* c, const ShmMsg& m, bool* needs_verdict) {
   }
   d.ring_end = c->seg.hdr->head.load(std::memory_order_relaxed);
   c->deferred.push_back(d);
+  c->preack_unflushed.store(true, std::memory_order_release);
   return Status::Ok();
 }
 
@@ -669,6 +688,9 @@ void ShmSendLoop(ShmComm* c) {
       if (ps.ok() && r2) c->await_ack = false;
     }
   }
+  // Flushed by the verdict, or lost to a failure: either way a close has
+  // nothing left to wait for.
+  c->preack_unflushed.store(false, std::memory_order_release);
   if (!ps.ok()) {
     PoisonShm(c, ps.msg);
     return;

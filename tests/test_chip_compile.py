@@ -108,6 +108,33 @@ def test_flash_s32768_is_refused_for_vmem(one_chip):
         jax.jit(_flash_loss()).lower(*_qkv(one_chip, 1, 32768, 16)).compile()
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("seq", [16384, 32768])
+def test_eva_h32_w2048_c16(one_chip, direction, seq):
+    """EvaByte's attention at its published widths, half and the whole of
+    its published context: each program stages one window of K and V (or
+    the summaries), so the sequence does not bound it. The kernels carry
+    their own names into the HLO, which the benchmark's readers match."""
+    from tpunet.ops.eva_attention import eva_attention
+
+    def fn(q, k, v, phi, mu):
+        o = eva_attention(q, k, v, phi, mu, 2048, 16, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    if direction == "bwd":
+        fn = jax.grad(fn, argnums=(0, 1, 2, 3, 4))
+    qkv = jax.ShapeDtypeStruct((1, seq, 32, 128), jnp.bfloat16, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((32, 128), jnp.float32, sharding=one_chip)
+    text = jax.jit(fn).lower(qkv, qkv, qkv, vec, vec).compile().as_text()
+    names = ["eva_local_fwd", "eva_remote_fwd"]
+    if direction == "bwd":
+        names += ["eva_local_dq", "eva_remote_dq", "eva_local_dkv", "eva_remote_dkv"]
+    assert text.count(KERNEL) == len(names)
+    for name in names:
+        assert len([ln for ln in text.splitlines()
+                    if name in ln.split(" = ")[0] and KERNEL in ln]) == 1, name
+
+
 def _train_program(one_chip, **step_kw):
     import optax
 

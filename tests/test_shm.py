@@ -197,3 +197,82 @@ def test_shm_disabled_is_plain_tcp():
     (_, shm_rx, tcp_rx), (_, shm_tx, _) = _run_pair(env, env, sizes=SWEEP_SMALL)
     assert shm_rx == 0 and shm_tx == 0
     assert tcp_rx == TOTAL_SMALL
+
+
+# ---------------------------------------------------------------------------
+# A sender that is done and closes before the receiver has accepted.
+
+
+def _late_receiver(conn, env: dict, sizes: list) -> None:
+    os.environ.update(env)
+    import time
+
+    from tpunet.transport import Net
+
+    net = Net()
+    listen = net.listen(0)
+    conn.send(bytes(listen.handle))
+    assert conn.recv() == "SENT"  # every send has completed on the other side
+    time.sleep(0.3)  # ... and its close is under way
+    try:
+        rc = listen.accept()
+        for size in sizes:
+            buf = np.zeros(size, dtype=np.uint8)
+            got = rc.recv(buf, timeout=30)
+            exp = np.arange(size, dtype=np.uint64).astype(np.uint8)
+            assert got == size and np.array_equal(buf, exp), (size, got)
+        rc.close()
+        conn.send("OK")
+    except Exception as e:  # noqa: BLE001
+        conn.send(f"FAIL: {type(e).__name__}: {e}")
+    listen.close()
+    net.close()
+
+
+def _early_closer(conn, env: dict, sizes: list) -> None:
+    os.environ.update(env)
+    from tpunet.transport import Net
+
+    net = Net()
+    sc = net.connect(conn.recv())
+    for size in sizes:
+        data = np.arange(size, dtype=np.uint64).astype(np.uint8)
+        assert sc.send(data, timeout=30) == size
+    conn.send("SENT")
+    sc.close()
+    net.close()
+    conn.send("CLOSED")
+
+
+@pytest.mark.parametrize("recv_host", ["one", "other"])
+def test_shm_close_before_accept_still_delivers(recv_host):
+    """Sends that completed into the ring before the handshake verdict are
+    the kernel-buffer analogue: a sender that then closes, before the
+    receiver is even inside accept(), still delivers them: over the ring
+    when the receiver acks (same host), replayed over ctrl when it nacks
+    (the fake-host split). Closing used to drop the deferred LEN frames,
+    and a first pipeline stage that finished early took its microbatches
+    with it (tests/test_workloads.py, under load)."""
+    sizes = [8, 4096, 4096, 1 << 16]
+    env_s = {"TPUNET_SHM": "1", "TPUNET_HOST_ID": "one"}
+    env_r = {"TPUNET_SHM": "1", "TPUNET_HOST_ID": recv_host}
+    ctx = mp.get_context("spawn")
+    pr, cr = ctx.Pipe()
+    ps, cs = ctx.Pipe()
+    r = ctx.Process(target=_late_receiver, args=(cr, env_r, sizes))
+    s = ctx.Process(target=_early_closer, args=(cs, env_s, sizes))
+    r.start()
+    s.start()
+    try:
+        ps.send(pr.recv())
+        assert ps.poll(60) and ps.recv() == "SENT"
+        pr.send("SENT")
+        assert pr.poll(60)
+        recv_res = pr.recv()
+        assert ps.poll(60) and ps.recv() == "CLOSED"
+    finally:
+        for p in (r, s):
+            p.join(timeout=30)
+            if p.is_alive():
+                p.kill()
+    assert recv_res == "OK", recv_res

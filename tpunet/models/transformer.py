@@ -60,14 +60,24 @@ def rotary_embed(x, base: float = 10000.0, pos_offset: int = 0, positions=None):
 
 
 class RMSNorm(nn.Module):
+    """unit_offset: the learned scale is stored as its offset from 1 (zeros
+    at initialisation) and applied as (1 + scale). out_dtype: the output's
+    type; None = the input's. The model's norms give compute_dtype, which a
+    float32 residual stream (`residual_dtype`) makes another type than x's."""
+
     eps: float = 1e-6
+    unit_offset: bool = False
+    out_dtype: jnp.dtype | None = None
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        init = nn.initializers.zeros if self.unit_offset else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],))
+        if self.unit_offset:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (norm * scale).astype(x.dtype)
+        return (norm * scale).astype(self.out_dtype or x.dtype)
 
 
 class QuantDense(nn.Module):
@@ -172,7 +182,11 @@ class SelfAttention(nn.Module):
     PROCESSES over the tpunet DCN transport — requires
     tpunet.distributed.initialize(); dcn_zigzag additionally expects each
     process's shard to be its zigzag chunk pair, i.e. tokens fed through
-    to_zigzag, and is the balanced-causal variant of dcn_ring).
+    to_zigzag, and is the balanced-causal variant of dcn_ring), or "eva"
+    (tpunet.ops.eva_attention: exact causal attention inside each
+    `eva_window`, one learned summary per `eva_chunk` positions of every
+    earlier window, one softmax over both; the per-head vectors
+    `adaptive_phi` and `adaptive_mu_k` are this module's parameters).
 
     n_kv_heads < n_heads is grouped-query attention: k/v are projected to
     n_kv_heads — the kv projection params/FLOPs and (in decode) the KV
@@ -225,6 +239,9 @@ class SelfAttention(nn.Module):
     #   to the masked cache for narrower windows.
     lora_rank: int = 0
     lora_alpha: float | None = None
+    rope_theta: float = 10000.0
+    eva_window: int | None = None  # attn_impl="eva": positions a window
+    eva_chunk: int | None = None   #   and positions a summary
 
     @nn.compact
     def __call__(self, x):
@@ -233,6 +250,10 @@ class SelfAttention(nn.Module):
         kv = self.n_kv_heads or h
         if h % kv:
             raise ValueError(f"n_heads {h} not divisible by n_kv_heads {kv}")
+        if self.attn_impl == "eva" and (
+                kv != h or not self.eva_window or not self.eva_chunk):
+            raise ValueError("attn_impl='eva' needs eva_window, eva_chunk and "
+                             "n_kv_heads == n_heads")
         if (self.attn_impl == "flash" and not self.decode
                 and (self.flash_block_q, self.flash_block_k) != (128, 128)):
             # Explicit (non-default) tile sizes must actually be honored:
@@ -306,8 +327,8 @@ class SelfAttention(nn.Module):
                 idx = cidx.value
                 cap = ckey.value.shape[1]
                 step_pos = (idx[..., None] + jnp.arange(s)).astype(jnp.float32)
-                q = rotary_embed(q, positions=step_pos)
-                k = rotary_embed(k, positions=step_pos)
+                q = rotary_embed(q, self.rope_theta, positions=step_pos)
+                k = rotary_embed(k, self.rope_theta, positions=step_pos)
                 rows = jnp.arange(b)[:, None]
                 if ring:
                     # A full-width ring never overflows: writes land at pos
@@ -462,8 +483,8 @@ class SelfAttention(nn.Module):
                 jnp.arange(s, dtype=jnp.float32),
                 self.mesh.shape[self.sp_axis], axis=0,
             )
-        q = rotary_embed(q, pos_offset=pos_offset, positions=positions)
-        k = rotary_embed(k, pos_offset=pos_offset, positions=positions)
+        q = rotary_embed(q, self.rope_theta, pos_offset, positions)
+        k = rotary_embed(k, self.rope_theta, pos_offset, positions)
         if kv != h and self.attn_impl != "flash":
             # GQA broadcast AFTER rotary (rotary runs on the kv heads): the
             # projection savings are already banked; every impl below then
@@ -474,7 +495,16 @@ class SelfAttention(nn.Module):
             k = jnp.repeat(k, h // kv, axis=2)
             v = jnp.repeat(v, h // kv, axis=2)
 
-        if self.attn_impl == "zigzag":
+        if self.attn_impl == "eva":
+            from tpunet.ops.eva_attention import eva_attention
+
+            def vec(key, shape):  # the released model's initialisation
+                return jnp.clip(jax.random.normal(key, shape), -1.0, 1.0) / math.sqrt(dh)
+
+            o = eva_attention(q, k, v, self.param("adaptive_phi", vec, (h, dh)),
+                              self.param("adaptive_mu_k", vec, (h, dh)),
+                              self.eva_window, self.eva_chunk)
+        elif self.attn_impl == "zigzag":
             from tpunet.parallel.zigzag_attention import zigzag_self_attention
 
             o = zigzag_self_attention(
@@ -638,9 +668,16 @@ class Block(nn.Module):
     decode_ring_cache: bool = True
     lora_rank: int = 0
     lora_alpha: float | None = None
+    norm_eps: float = 1e-6
+    norm_unit_offset: bool = False
+    rope_theta: float = 10000.0
+    eva_window: int | None = None
+    eva_chunk: int | None = None
 
     @nn.compact
     def __call__(self, x):
+        norm = lambda name: RMSNorm(  # noqa: E731
+            self.norm_eps, self.norm_unit_offset, self.compute_dtype, name=name)
         x = x + SelfAttention(
             self.n_heads, self.head_dim, self.compute_dtype, self.attn_impl,
             self.mesh, self.dp_axis, self.sp_axis, self.tp_axis,
@@ -652,8 +689,9 @@ class Block(nn.Module):
             per_row_cache=self.per_row_cache,
             decode_ring_cache=self.decode_ring_cache,
             lora_rank=self.lora_rank,
-            lora_alpha=self.lora_alpha, name="attn",
-        )(RMSNorm(name="norm1")(x))
+            lora_alpha=self.lora_alpha, rope_theta=self.rope_theta,
+            eva_window=self.eva_window, eva_chunk=self.eva_chunk, name="attn",
+        )(norm("norm1")(x))
         if self.n_experts > 0:
             mlp = MoeMlp(self.n_experts, self.d_ff, self.capacity_factor,
                          self.compute_dtype, top_k=self.moe_top_k, name="moe")
@@ -662,7 +700,7 @@ class Block(nn.Module):
                       weight_quant=self.weight_quant,
                       lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
                       name="mlp")
-        return x + mlp(RMSNorm(name="norm2")(x))
+        return x + mlp(norm("norm2")(x))
 
 
 class Transformer(nn.Module):
@@ -713,6 +751,16 @@ class Transformer(nn.Module):
     #   load a base checkpoint, merge_lora to fold back); composes with
     #   weight_quant="int8" (QLoRA: frozen int8 base + fp adapters)
     lora_alpha: float | None = None
+    norm_eps: float = 1e-6         # RMSNorm's epsilon, every norm of the model
+    norm_unit_offset: bool = False  # norm scales stored as offsets from 1
+    rope_theta: float = 10000.0    # the rotary base
+    residual_dtype: jnp.dtype | None = None  # the residual stream's type;
+    #   None = compute_dtype. float32 keeps the skip additions exact while
+    #   every norm hands compute_dtype to the matmuls
+    n_pred_heads: int = 1          # > 1: the head gives the next n positions'
+    #   logits, (b, s, n, vocab); head j predicts the token at t + 1 + j
+    eva_window: int | None = None  # attn_impl="eva" (tpunet.ops.eva_attention)
+    eva_chunk: int | None = None
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, features_only: bool = False):
@@ -740,10 +788,14 @@ class Transformer(nn.Module):
                 "['kernel'], but the adapted tree nests it under 'base' "
                 "(and the lm_head adapters would be silently dropped) - "
                 "merge_lora first, or train without fused xent")
+        if self.n_pred_heads > 1 and (features_only or self.decode):
+            raise ValueError(
+                "n_pred_heads > 1 has no fused cross-entropy and no decode "
+                "path: both read one position's logits from the head")
         emb = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab, self.d_model)
         )
-        x = emb[tokens].astype(self.compute_dtype)
+        x = emb[tokens].astype(self.residual_dtype or self.compute_dtype)
         head_dim = self.d_model // self.n_heads
         # remat drops block activations in the forward pass and recomputes
         # them in the backward — the standard long-context memory lever
@@ -781,9 +833,13 @@ class Transformer(nn.Module):
                 per_row_cache=self.per_row_cache,
                 decode_ring_cache=self.decode_ring_cache,
                 lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
+                norm_eps=self.norm_eps, norm_unit_offset=self.norm_unit_offset,
+                rope_theta=self.rope_theta,
+                eva_window=self.eva_window, eva_chunk=self.eva_chunk,
                 name=f"block{i}",
             )(x)
-        x = RMSNorm(name="norm_f")(x)
+        x = RMSNorm(self.norm_eps, self.norm_unit_offset, self.compute_dtype,
+                    name="norm_f")(x)
         if features_only:
             if self.is_initializing():
                 # The lm_head param must still exist (fused-xent callers
@@ -792,10 +848,13 @@ class Transformer(nn.Module):
                 nn.Dense(self.vocab, use_bias=False, dtype=self.compute_dtype,
                          name="lm_head")(x[..., :1, :])
             return x.astype(self.compute_dtype)
-        logits = _dense(self.vocab, self.compute_dtype, "lm_head",
-                        self.weight_quant, self.lora_rank,
-                        self.lora_alpha)(x)
-        return logits.astype(jnp.float32)
+        logits = _dense(self.vocab * self.n_pred_heads, self.compute_dtype,
+                        "lm_head", self.weight_quant, self.lora_rank,
+                        self.lora_alpha)(x).astype(jnp.float32)
+        if self.n_pred_heads > 1:
+            logits = logits.reshape(*logits.shape[:-1], self.n_pred_heads,
+                                    self.vocab)
+        return logits
 
 
 def transformer_partition_rules(

@@ -25,6 +25,7 @@ Design (TPU-first):
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Any, NamedTuple
 
@@ -142,6 +143,16 @@ def _wire_handles_bf16() -> bool:
     return getattr(distributed.global_communicator(), "wire_dtype", "f32") == "bf16"
 
 
+def multi_head_targets(labels, n_heads: int):
+    """Targets of `n_heads` prediction heads from next-token labels (.., s):
+    head j at position t is asked for labels[t + j] (the token at t + 1 + j).
+    Returns (targets (.., s, n_heads), inside (s, n_heads)): inside is False
+    where t + j falls past the sequence, and the target there is a filler."""
+    s = labels.shape[-1]
+    at = jnp.arange(s)[:, None] + jnp.arange(n_heads)[None, :]
+    return jnp.take(labels, jnp.minimum(at, s - 1), axis=-1), at < s
+
+
 def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
                   fused_xent_block: int | None = None,
                   z_loss: float = 0.0):
@@ -170,6 +181,18 @@ def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
         )
 
     fused = fused_xent_block is not None
+    mean = jnp.mean
+    if getattr(model, "n_pred_heads", 1) > 1:
+        # Several prediction heads: logits (b, s, heads, vocab); the loss is
+        # the mean over the (position, head) pairs whose target lies inside
+        # the sequence.
+        if fused:
+            raise ValueError("fused_xent_block reads one position's logits "
+                             "from the head; the model has n_pred_heads > 1")
+        labels, inside = multi_head_targets(labels, model.n_pred_heads)
+        share = inside / (jnp.sum(inside) * math.prod(labels.shape[:-2]))
+        mean = lambda x: jnp.sum(x * share.astype(x.dtype))  # noqa: E731
+
     def loss_fn(p):
         out = model.apply(
             {"params": p}, images, train=True, rngs={"dropout": dropout_rng},
@@ -198,11 +221,10 @@ def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
             lse = jax.scipy.special.logsumexp(out, axis=-1)
             picked = jnp.take_along_axis(
                 out, labels[..., None], axis=-1)[..., 0]
-            loss = (lse - picked).mean() + z_loss * jnp.mean(
+            loss = mean(lse - picked) + z_loss * mean(
                 jnp.square(lse.astype(jnp.float32)))
         else:
-            loss = optax.softmax_cross_entropy_with_integer_labels(out, labels)
-            loss = loss.mean()
+            loss = mean(optax.softmax_cross_entropy_with_integer_labels(out, labels))
         if has_moe:
             # flax wraps sown values in tuples: sum leaves on matching paths
             # and average over MoE blocks.
