@@ -60,7 +60,8 @@ def _tree_sum(tree):
     return sum(jnp.sum(x.astype(jnp.float32)) for x in jax.tree.leaves(tree))
 
 
-def segments(cfg: dict, *, block_q: int = 128, block_k: int = 128):
+def segments(cfg: dict, *, block_q: int | None = None,
+             block_k: int | None = None):
     """Build {name: (chained_fn, carry0, flops_fwd, flops_fwdbwd)} for one
     layer's blocks plus the model-level head/optimizer segments.
 

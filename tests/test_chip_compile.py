@@ -72,12 +72,12 @@ def _flash_loss(causal=True, window=None):
     return loss
 
 
-def _qkv(one_chip, batch, seq, kv_heads):
-    def arr(heads):
-        return jax.ShapeDtypeStruct((batch, seq, heads, 128), jnp.bfloat16,
+def _qkv(one_chip, batch, seq, kv_heads, heads=16):
+    def arr(n):
+        return jax.ShapeDtypeStruct((batch, seq, n, 128), jnp.bfloat16,
                                     sharding=one_chip)
 
-    return arr(16), arr(kv_heads), arr(kv_heads)
+    return arr(heads), arr(kv_heads), arr(kv_heads)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -98,6 +98,21 @@ def test_flash_b1_s8192(one_chip, direction):
     if direction == "bwd":
         fn = jax.grad(fn, argnums=(0, 1, 2))
     jax.jit(fn).lower(*_qkv(one_chip, 1, 8192, 16)).compile()
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_mistral_b2_s8192_window4096(one_chip, direction):
+    """The benchmark's Mistral cell, blocks left to the plan (512 x 512
+    there): forward stays one kernel and backward two more, which the
+    cell's harness counts."""
+    from tpunet.ops.flash_attention import _plan
+
+    assert _plan(8192, 8192, jnp.bfloat16, True, 4096) == (512, 512, 108)
+    fn = _flash_loss(window=4096)
+    if direction == "bwd":
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*_qkv(one_chip, 2, 8192, 8, heads=32)).compile().as_text()
+    assert text.count(KERNEL) == (1 if direction == "fwd" else 3)
 
 
 def test_flash_s32768_is_refused_for_vmem(one_chip):

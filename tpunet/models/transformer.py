@@ -219,12 +219,13 @@ class SelfAttention(nn.Module):
     n_kv_heads: int | None = None
     decode: bool = False
     attn_window: int | None = None  # sliding-window causal (flash/reference)
-    # Flash kernel tile sizes (attn_impl="flash" only). 128 matches the MXU/
-    # lane width and is the measured round-3 default; expose them so an
-    # on-chip block sweep (benchmarks.mfu_attribution --sweep-blocks) can be
-    # applied to the model without editing kernel code.
-    flash_block_q: int = 128
-    flash_block_k: int = 128
+    # Flash kernel tile sizes (attn_impl="flash" only). None leaves the
+    # choice to tpunet.ops.flash_attention._plan, which makes it from the
+    # shapes of each call; an explicit value wins, so that an on-chip block
+    # sweep (benchmarks.mfu_attribution --sweep-blocks) can be applied to the
+    # model without editing kernel code.
+    flash_block_q: int | None = None
+    flash_block_k: int | None = None
     weight_quant: str | None = None
     prefill: bool = False  # decode=True only: first fill of an EMPTY cache
     #   runs block-causal attention through the configured kernel (flash on
@@ -255,8 +256,8 @@ class SelfAttention(nn.Module):
             raise ValueError("attn_impl='eva' needs eva_window, eva_chunk and "
                              "n_kv_heads == n_heads")
         if (self.attn_impl == "flash" and not self.decode
-                and (self.flash_block_q, self.flash_block_k) != (128, 128)):
-            # Explicit (non-default) tile sizes must actually be honored:
+                and (self.flash_block_q, self.flash_block_k) != (None, None)):
+            # Explicit tile sizes must actually be honored:
             # flash_attention silently falls back to the O(S^2) reference
             # einsum for untileable shapes, and compiled Mosaic silently
             # clamps non-lane-aligned block_q to 128 — either would make a
@@ -264,7 +265,9 @@ class SelfAttention(nn.Module):
             # decode=True is exempt: cached steps never reach the flash
             # kernel (dense-einsum branch below) and prefill prompts have
             # arbitrary lengths, where the reference fallback is the point.
-            bq, bk = self.flash_block_q, self.flash_block_k
+            # one given alone is also the other's value, as in the plan
+            bq = self.flash_block_q or self.flash_block_k
+            bk = self.flash_block_k or self.flash_block_q
             if s % bq or s % bk or bq % bk:
                 raise ValueError(
                     f"flash_block_q/k=({bq},{bk}) do not tile seq {s} under "
@@ -659,8 +662,8 @@ class Block(nn.Module):
     mlp_impl: str = "gelu"
     decode: bool = False
     attn_window: int | None = None
-    flash_block_q: int = 128
-    flash_block_k: int = 128
+    flash_block_q: int | None = None
+    flash_block_k: int | None = None
     moe_top_k: int = 1
     weight_quant: str | None = None
     prefill: bool = False
@@ -731,8 +734,8 @@ class Transformer(nn.Module):
     attn_window: int | None = None  # sliding-window causal attention (Mistral
     #   -style): each token sees the window most recent positions; flash
     #   kernels prune to O(S*window) FLOPs. reference/flash impls only.
-    flash_block_q: int = 128       # flash kernel tile sizes; sweep with
-    flash_block_k: int = 128       #   benchmarks.mfu_attribution --sweep-blocks
+    flash_block_q: int | None = None  # flash kernel tile sizes; None = chosen
+    flash_block_k: int | None = None  #   from the shapes (ops.flash_attention._plan)
     weight_quant: str | None = None  # "int8" = weight-only quantized matmuls
     #   (inference: pair with tpunet.models.quantize_params on a trained
     #   fp tree; halves the weight HBM traffic decode is bound by)

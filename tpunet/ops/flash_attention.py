@@ -11,8 +11,11 @@ Design notes (TPU-first):
     blockwise through VMEM with a `fori_loop`, carrying the online-softmax
     state (m, l, acc) functionally.
   * causal masking prunes the k-loop upper bound per q-block (no wasted
-    MXU work on fully-masked blocks); the diagonal block is masked
+    MXU work on fully-masked blocks); the visited blocks are masked
     elementwise.
+  * the (block_q, block_k) tile of one loop step is chosen from the call's
+    shapes by `_plan` (512 x 512 at long sequences: a visit's fixed cost,
+    not the MXU, bounds a 128 x 128 step); forward and backward both ask it.
   * backward pass: FlashAttention-2 style blockwise kernels. The forward
     additionally emits the per-row logsumexp; the backward recomputes
     P = exp(S - lse) within blocks (O(S) memory, no stored score matrix)
@@ -27,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +74,38 @@ def attention_reference(q, k, v, causal: bool = False,
     return o.astype(dt)
 
 
+# The loop bounds of one program: the causal diagonal and the sliding
+# window prune the tiles it visits to those that hold a visible (query, key)
+# pair; the elementwise mask trims the rest inside a tile. On Python ints
+# (the plan's count) and on traced ints (the kernels' bounds) alike.
+
+def _k_tiles(i, block_q, block_k, seq_k, causal, window, maximum=jnp.maximum):
+    """[lo, hi): the k-tiles q-block i visits (forward, dQ)."""
+    if not causal:
+        return 0, seq_k // block_k
+    # Last k-block that the final row of this q-block may attend to.
+    hi = pl.cdiv((i + 1) * block_q, block_k)
+    if window is None:
+        return 0, hi
+    # First k-block any row of this q-block still sees: the FIRST row's
+    # oldest key is i*bq - (window-1); the lower bound must cover it.
+    return maximum(i * block_q - (window - 1), 0) // block_k, hi
+
+
+def _q_tiles(j, block_q, block_k, seq_q, causal, window, minimum=jnp.minimum):
+    """[lo, hi): the q-tiles k-block j visits (dK/dV)."""
+    num_qb = seq_q // block_q
+    if not causal:
+        return 0, num_qb
+    lo = (j * block_k) // block_q  # first q-block with a row attending here
+    if window is None:
+        return lo, num_qb
+    # The window also bounds ABOVE: the newest query still seeing this
+    # k-block's newest key j*bk + bk - 1 is that + window - 1.
+    return lo, minimum(
+        num_qb, pl.cdiv(j * block_k + block_k - 1 + window, block_q))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
                   block_k: int, seq_k: int, causal: bool, scale: float,
                   precision, window: int | None = None):
@@ -79,19 +115,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     q = q_ref[0, :, :].astype(jnp.float32) * scale
     head_dim = q.shape[-1]
 
-    if causal:
-        # Last k-block that the final row of this q-block may attend to.
-        num_kb = pl.cdiv((qi + 1) * block_q, block_k)
-    else:
-        num_kb = seq_k // block_k
-    # Sliding window: first k-block any row of this q-block still sees
-    # (oldest position the LAST row attends is qi*bq + bq-1 - (window-1)...
-    # the FIRST row's oldest is qi*bq - (window-1) — the loop lower bound
-    # must cover the first row, the elementwise mask trims the rest).
-    j_start = (
-        jnp.maximum(qi * block_q - (window - 1), 0) // block_k
-        if (causal and window is not None) else 0
-    )
+    j_start, num_kb = _k_tiles(qi, block_q, block_k, seq_k, causal, window)
 
     def body(j, carry):
         acc, m, l = carry
@@ -145,14 +169,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     delta = delta_ref[0, 0, :][:, None]
     head_dim = q.shape[-1]
 
-    if causal:
-        num_kb = pl.cdiv((qi + 1) * block_q, block_k)
-    else:
-        num_kb = seq_k // block_k
-    j_start = (
-        jnp.maximum(qi * block_q - (window - 1), 0) // block_k
-        if (causal and window is not None) else 0
-    )
+    j_start, num_kb = _k_tiles(qi, block_q, block_k, seq_k, causal, window)
 
     def body(j, dq):
         kb = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
@@ -200,17 +217,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     g = pl.program_id(2)
     kb = k_ref[0, :, :].astype(jnp.float32)
     vb = v_ref[0, :, :].astype(jnp.float32)
-    num_qb = seq_q // block_q
-    # First q-block with any row attending into this k-block.
-    i_start = (kj * block_k) // block_q if causal else 0
-    # Sliding window also bounds ABOVE: the newest query still seeing this
-    # k-block's oldest position kj*bk is kj*bk + window - 1.
-    if causal and window is not None:
-        i_end = jnp.minimum(
-            num_qb, pl.cdiv(kj * block_k + block_k - 1 + window, block_q)
-        )
-    else:
-        i_end = num_qb
+    i_start, i_end = _q_tiles(kj, block_q, block_k, seq_q, causal, window)
 
     def body(i, carry):
         dk, dv = carry
@@ -296,6 +303,65 @@ def _normalize_blocks(sq, sk, block_q, block_k, interpret, dtype):
     return block_q, block_k
 
 
+class FlashPlan(NamedTuple):
+    """What `_plan` decided for one call, and what engages with it."""
+    block_q: int
+    block_k: int
+    tiles: int  # (q-block, k-block) tiles one head visits
+
+
+# The largest tile side the plan picks by itself, from the sweep on the chip
+# at b2 s8192 h32 kv8 d128 bf16 window 4096 (PERF.md section 6, PR 27). The
+# kernels stage whole sequences (K and V; q and do in dK/dV), and that, not
+# the tile, is what runs a long sequence out of VMEM: compiled for a v5e,
+# every shape that fits with 128 x 128 tiles fits with 512 x 512.
+_TILE_CEILING = 512
+
+
+def _auto_block(seq):
+    """A tile side for `seq`: one tile where the sequence is short, else
+    the largest of _TILE_CEILING, half of it, ..., 128 that divides it. A
+    sequence over 128 that no multiple of 128 divides keeps 128, which does
+    not tile it: such shapes take the einsum path, as they always did."""
+    if seq <= 128 or (seq <= _TILE_CEILING and seq % 128 == 0):
+        return seq
+    block = _TILE_CEILING
+    while block > 128 and seq % block:
+        block //= 2
+    return block
+
+
+def _plan(sq, sk, dtype, causal, window, block_q=None, block_k=None,
+          interpret=False):
+    """The tiles of one flash_attention call, from its shapes alone; None
+    where they do not tile and the einsum path runs. Forward and backward
+    both ask here, so they cannot disagree.
+
+    An explicit block_q/block_k wins (tests use tiny ones in interpret
+    mode, the sweep its grid); one given alone is also the other's value.
+    Left to the plan, a tile side is the whole sequence where that is short
+    and else the largest of _TILE_CEILING, half of it, ..., 128 that divides
+    the sequence. The einsum path is taken for ragged tiling, a mixed block
+    ratio under causal, and causal cross-attention (sq != sk): the kernels'
+    causal loop bounds assume aligned q/k positions and
+    block_q % block_k == 0."""
+    if block_q is None and block_k is None:
+        block_q, block_k = _auto_block(sq), _auto_block(sk)
+    else:
+        block_q = block_k if block_q is None else block_q
+        block_k = block_q if block_k is None else block_k
+    block_q, block_k = _normalize_blocks(sq, sk, block_q, block_k, interpret,
+                                         dtype)
+    if (sq % block_q or sk % block_k
+            or (causal and (block_q % block_k or sq != sk))):
+        return None
+    tiles = 0
+    for i in range(sq // block_q):
+        lo, hi = _k_tiles(i, block_q, block_k, sk, causal, window, max)
+        tiles += hi - lo
+    return FlashPlan(block_q, block_k, tiles)
+
+
 def _flatten_heads(x):
     b, s, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -307,9 +373,9 @@ def _unflatten_heads(xf, b, h):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None,
-                    window: int | None = None):
+def flash_attention(q, k, v, causal: bool = False,
+                    block_q: int | None = None, block_k: int | None = None,
+                    interpret: bool | None = None, window: int | None = None):
     """Flash attention. q: (batch, seq, heads, head_dim); k/v may carry
     FEWER heads (grouped-query attention — heads % kv_heads == 0): each
     q-head program's K/V BlockSpec index_map points at its kv head
@@ -322,6 +388,9 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
     prune the k-loop at BOTH ends (and the dK/dV q-loop symmetrically), so
     compute scales O(S·window) instead of O(S²/2) — the long-context FLOPs
     lever when full attention isn't needed.
+
+    block_q, block_k: the (q, k) tile of scores one loop step computes. Left
+    None, `_plan` chooses them from the shapes; an explicit value wins.
 
     Falls back to the reference einsum path (with an explicit kv repeat for
     GQA) when the sequence lengths don't tile evenly — ragged tails are a
@@ -354,14 +423,11 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret,
         raise ValueError("window requires causal=True and window >= 1")
     if interpret is None:
         interpret = _auto_interpret()
-    block_q, block_k = _normalize_blocks(sq, sk, block_q, block_k, interpret, q.dtype)
-    # Fallback cases: ragged tiling, mixed block ratio under causal, and
-    # causal cross-attention (sq != sk) — the kernels' causal k-loop bound
-    # assumes aligned q/k positions and would run past the k blocks.
-    if (sq % block_q or sk % block_k
-            or (causal and (block_q % block_k or sq != sk))):
+    plan = _plan(sq, sk, q.dtype, causal, window, block_q, block_k, interpret)
+    if plan is None:
         return attention_reference(q, _repeat_kv(k, group),
                                    _repeat_kv(v, group), causal, window), None
+    block_q, block_k = plan.block_q, plan.block_k
 
     # (B, S, H, D) -> (B*H, S, D): grid programs are independent per head.
     qf = _flatten_heads(q)
@@ -417,9 +483,10 @@ def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     hk = k.shape[2]
-    # Same normalization as the forward: the forward only saved an lse (vs
-    # taking the fallback) for shapes where this yields a legal tiling.
-    block_q, block_k = _normalize_blocks(sq, sk, block_q, block_k, interpret, q.dtype)
+    # The forward's plan again: it saved an lse (and did not take the einsum
+    # path) only for shapes where the plan gives tiles.
+    block_q, block_k = _plan(sq, sk, q.dtype, causal, window, block_q,
+                             block_k, interpret)[:2]
     scale = 1.0 / math.sqrt(d)
 
     qf, kf, vf = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
