@@ -276,7 +276,7 @@ def test_qlora_trains_through_fit():
 
 
 def _qlora_cross_host_worker(rank: int, world: int, port: int, q) -> None:
-    # QLoRA + cross_host (ADVICE r4 #2): gradients contain float0 leaves
+    # QLoRA + cross_host: gradients contain float0 leaves
     # (frozen int8 base under allow_int) which the DCN tier must pass
     # through — both the single-vector ravel path and the bucketed path
     # used to crash at trace time on ravel/concatenate of float0.

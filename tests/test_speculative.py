@@ -195,15 +195,18 @@ def test_chunked_prefill_parity(chunk):
     np.testing.assert_array_equal(np.asarray(got_sp), np.asarray(want_sp))
 
 
-def test_flash_prefill_matches_reference_prefill():
+@pytest.mark.parametrize("prompt_len", [128, 5], ids=["tiles", "no_tile"])
+def test_flash_prefill_matches_reference_prefill(prompt_len):
     """attn_impl="flash" routes the empty-cache prefill through the Pallas
     kernel (interpreted on CPU); generation must agree with the reference-
     impl model token-for-token at a tileable prompt length — the two
-    prefills differ only in attention blocking."""
+    prefills differ only in attention blocking — and at one no tile
+    divides, where flash_attention's own fallback is the reference einsum:
+    a flash model decodes from a prompt of any length."""
     ref = _tiny(n_kv_heads=2)
     fla = _tiny(n_kv_heads=2, attn_impl="flash")
     params, _ = _params(ref, s=128)
-    prompt = jax.random.randint(jax.random.PRNGKey(0), (2, 128), 0, 64)
+    prompt = jax.random.randint(jax.random.PRNGKey(0), (2, prompt_len), 0, 64)
     want = generate(ref, params, prompt, 6)
     got = generate(fla, params, prompt, 6)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

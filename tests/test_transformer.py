@@ -1,12 +1,16 @@
 """Transformer family tests: forward numerics, TP/SP/EP shardings, training."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from flax.traverse_util import flatten_dict
 
 from tpunet.models import Transformer, transformer_partition_rules
+from tpunet.models.transformer import Block, LayerSpec, SelfAttention
 from tpunet.parallel import batch_sharding, make_named_mesh, replicated, shard_params
 from tpunet.train import TrainState, create_train_state, make_train_step
 
@@ -164,8 +168,8 @@ def test_remat_matches_no_remat(n_experts):
 
 
 def test_train_step_includes_moe_aux_loss():
-    """The Switch balancing term must reach the training loss (ADVICE r1):
-    the same step with a larger moe_aux_weight must report a larger loss."""
+    """The Switch balancing term must reach the training loss: the same
+    step with a larger moe_aux_weight must report a larger loss."""
     model = _tiny(n_experts=4, moe_every=1)
     tx = optax.adam(1e-2)
     toks = _tokens(jax.random.PRNGKey(0), 4, 16)
@@ -199,54 +203,136 @@ def test_train_step_loss_decreases(n_experts, moe_top_k):
     assert all(np.isfinite(l) for l in losses)
 
 
-def test_flash_block_size_plumbing():
-    """Non-default flash tile sizes thread Transformer -> Block ->
-    SelfAttention -> flash_attention and keep parity with the reference
-    path (the knob exists so an on-chip block sweep can be APPLIED —
-    256 is Mosaic-legal on compiled TPU, unlike sub-128 tiles)."""
-    kw = dict(vocab=64, d_model=128, n_layers=1, n_heads=2, d_ff=128,
-              compute_dtype=jnp.bfloat16)
-    m = Transformer(attn_impl="flash", flash_block_q=256, flash_block_k=256,
-                    **kw)
-    toks = jnp.asarray(
-        np.random.default_rng(0).integers(0, 64, (1, 256)), jnp.int32)
-    params = m.init(jax.random.PRNGKey(0), toks)
-    ref = Transformer(attn_impl="reference", **kw)
-    a, b = m.apply(params, toks), ref.apply(params, toks)
-    err = float(jnp.max(jnp.abs(a - b)) / jnp.maximum(jnp.max(jnp.abs(b)), 1.0))
-    assert err < 0.03, f"flash block_q/k=256 parity {err}"
+def _fields(cls):
+    """A flax module's own dataclass fields, by name."""
+    return {f.name: f for f in dataclasses.fields(cls)
+            if f.name not in ("parent", "name")}
 
 
-def test_flash_block_size_validation():
-    """Explicit tile sizes that would be silently ignored (untileable ->
-    reference fallback; non-lane-aligned -> Mosaic clamp) fail loud."""
-    kw = dict(vocab=64, d_model=128, n_layers=1, n_heads=2, d_ff=128,
-              attn_impl="flash", compute_dtype=jnp.bfloat16)
-    toks = jnp.zeros((1, 256), jnp.int32)
-    with pytest.raises(ValueError, match="reference path"):
-        Transformer(flash_block_q=128, flash_block_k=256, **kw).init(
-            jax.random.PRNGKey(0), toks)  # bq % bk != 0
-    with pytest.raises(ValueError, match="Mosaic-legal"):
-        Transformer(flash_block_q=64, flash_block_k=64, **kw).init(
-            jax.random.PRNGKey(0), toks)  # bq not a multiple of 128
+def test_layer_spec_fields_are_transformer_fields():
+    """A block-level field is declared on `Transformer` (default, comment)
+    and on `LayerSpec` (name, type), and `layer_specs()` copies it by name:
+    every spec field but the derived `head_dim` is a model field of the same
+    name and type, and has no default of its own to drift from the
+    model's. The flash tile is `ops.flash_attention._plan`'s, not an
+    option of the model."""
+    model = _fields(Transformer)
+    for f in dataclasses.fields(LayerSpec):
+        assert f.default is dataclasses.MISSING, f.name
+        assert f.default_factory is dataclasses.MISSING, f.name
+        if f.name != "head_dim":
+            assert f.name in model, f.name
+            assert f.type == model[f.name].type, f.name
+    assert not [n for n in model if n.startswith("flash_block")]
+    assert list(_fields(Block)) == ["spec"]
+    assert list(_fields(SelfAttention)) == ["spec"]
+    spec = Transformer(d_model=96, n_heads=4, rope_theta=5e5).layer_specs()[0]
+    assert (spec.head_dim, spec.rope_theta) == (24, 5e5)
+    for f in dataclasses.fields(LayerSpec):  # the model's defaults, unnamed
+        if f.name not in ("head_dim", "n_heads", "rope_theta"):
+            assert getattr(spec, f.name) == model[f.name].default, f.name
 
 
-def test_flash_block_size_decode_exempt():
-    """decode=True never routes cached steps through the flash kernel, so
-    swept tile sizes must not break generation (s=1 steps and arbitrary
-    prompt lengths are legal there)."""
-    from tpunet.models import generate
+def test_layer_specs_place_the_experts():
+    """`layer_specs()` is where layers differ: experts in every
+    `moe_every`-th block, everything else one spec repeated; the
+    initialised tree has `moe` exactly where the specs say."""
+    model = _tiny(n_experts=4, moe_every=2).clone(n_layers=4)
+    specs = model.layer_specs()
+    assert [sp.n_experts for sp in specs] == [0, 4, 0, 4]
+    assert specs[0] == specs[2] and specs[1] == specs[3]
+    assert dataclasses.replace(specs[1], n_experts=0) == specs[0]
+    assert len({hash(sp) for sp in specs}) == 2  # hashable: a module field
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    for i, sp in enumerate(specs):
+        assert ("moe" in params[f"block{i}"]) == (sp.n_experts > 0), i
+        assert ("mlp" in params[f"block{i}"]) == (sp.n_experts == 0), i
+    assert {sp.n_experts for sp in _tiny().layer_specs()} == {0}
 
-    m = Transformer(vocab=64, d_model=64, n_layers=1, n_heads=2, d_ff=64,
-                    attn_impl="flash", flash_block_q=256, flash_block_k=256,
-                    compute_dtype=jnp.float32)
-    # Params come from a tileable training-shape init (real usage: train at
-    # the swept seq, then decode arbitrary prompts).
-    params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 256), jnp.int32))["params"]
-    prompt = jnp.zeros((1, 5), jnp.int32)  # length 5: untileable on purpose
-    out = generate(m, params, prompt, 3)
-    assert out.shape == (1, 8)
 
+def test_clone_reaches_every_layers_spec():
+    """The contract `generate`, `BatchServer` and `serve/prefill.py` rest
+    on: what `model.clone(...)` sets is what every block is built from."""
+    model = _tiny(n_kv_heads=2, attn_window=8).clone(n_layers=3)
+    assert not any(sp.decode or sp.per_row_cache or sp.weight_quant
+                   for sp in model.layer_specs())
+    served = model.clone(decode=True, per_row_cache=True, weight_quant="int8")
+    specs = served.layer_specs()
+    assert len(specs) == 3
+    for sp in specs:
+        assert (sp.decode, sp.per_row_cache, sp.weight_quant) == (
+            True, True, "int8")
+        assert (sp.n_kv_heads, sp.attn_window, sp.prefill) == (2, 8, False)
+
+
+_TOY = dict(vocab=64, d_model=32, n_layers=2, n_heads=4, d_ff=48,
+            compute_dtype=jnp.float32)
+_ATTN_MHA = {"attn/q/kernel": (32, 32), "attn/k/kernel": (32, 32),
+             "attn/v/kernel": (32, 32), "attn/out/kernel": (32, 32)}
+_NORMS = {"norm1/scale": (32,), "norm2/scale": (32,)}
+_SWIGLU = {"mlp/gate/kernel": (32, 48), "mlp/up/kernel": (32, 48),
+           "mlp/down/kernel": (48, 32)}
+# (model fields, a block's leaves, the leaves outside the blocks, a block's
+# cache leaves at batch 2 and capacity 16). perfbench/weights.py,
+# generate._kv_leaves, serve/kv.py, transformer_partition_rules, lora.py and
+# quant.py address these by path, and so does every checkpoint.
+_TREES = {
+    "gelu_mha": (
+        {},
+        {**_ATTN_MHA, **_NORMS,
+         "mlp/up/kernel": (32, 48), "mlp/down/kernel": (48, 32)},
+        {"embed": (64, 32), "norm_f/scale": (32,), "lm_head/kernel": (32, 64)},
+        {"attn/cached_key": (2, 16, 4, 8), "attn/cached_value": (2, 16, 4, 8),
+         "attn/cache_index": ()},
+    ),
+    "swiglu_gqa_window_flash": (
+        dict(mlp_impl="swiglu", n_kv_heads=2, attn_window=8,
+             attn_impl="flash"),
+        {"attn/q/kernel": (32, 32), "attn/k/kernel": (32, 16),
+         "attn/v/kernel": (32, 16), "attn/out/kernel": (32, 32),
+         **_NORMS, **_SWIGLU},
+        {"embed": (64, 32), "norm_f/scale": (32,), "lm_head/kernel": (32, 64)},
+        # the window's ring: min(window, capacity) slots of kv heads
+        {"attn/cached_key": (2, 8, 2, 8), "attn/cached_value": (2, 8, 2, 8),
+         "attn/cache_index": ()},
+    ),
+    "eva_heads8_unit_offset_f32_residual": (
+        dict(mlp_impl="swiglu", attn_impl="eva", eva_window=8, eva_chunk=4,
+             n_pred_heads=8, norm_unit_offset=True,
+             residual_dtype=jnp.float32, compute_dtype=jnp.bfloat16),
+        {**_ATTN_MHA, "attn/adaptive_phi": (4, 8),
+         "attn/adaptive_mu_k": (4, 8), **_NORMS, **_SWIGLU},
+        {"embed": (64, 32), "norm_f/scale": (32,),
+         "lm_head/kernel": (32, 8 * 64)},
+        None,  # no decode path
+    ),
+}
+
+
+def _leaf_shapes(tree):
+    return {k: tuple(v.shape)
+            for k, v in flatten_dict(dict(tree), sep="/").items()}
+
+
+@pytest.mark.parametrize("case", list(_TREES))
+def test_parameter_and_cache_paths_are_pinned(case):
+    """The parameter tree and the cache collection by path and shape, as a
+    literal: a rename under a refactor fails here and not in a checkpoint."""
+    from tpunet.models import init_cache
+
+    kw, block, top, cache = _TREES[case]
+    model = Transformer(**{**_TOY, **kw})
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((2, 16), jnp.int32))["params"]
+    want = dict(top)
+    for i in range(2):
+        want.update({f"block{i}/{k}": v for k, v in block.items()})
+    assert _leaf_shapes(params) == want
+    if cache is not None:
+        got = jax.eval_shape(lambda: init_cache(model, 2, 16))
+        assert _leaf_shapes(got) == {
+            f"block{i}/{k}": v for i in range(2) for k, v in cache.items()}
 
 
 def test_moe_top_k_equals_experts_is_dense_mixture():

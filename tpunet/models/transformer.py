@@ -25,6 +25,7 @@ The reference repo has no model layer at all (SURVEY §2.3: TP/PP/SP/EP
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import flax.linen as nn
@@ -154,7 +155,7 @@ def _dense(features, dtype, name, weight_quant, lora_rank=0, lora_alpha=None):
     return QuantDense(features, dtype=dtype, name=name)
 
 
-def _causal_kernel_attention(q, k, v, attn_impl, window, block_q, block_k):
+def _causal_kernel_attention(q, k, v, attn_impl, window):
     """The flash/reference causal-attention pair on rotary'd (b, s, heads,
     dh) tensors — ONE dispatch shared by the ordinary forward and the
     kernel-routed prefill, so window handling and the GQA convention can't
@@ -162,13 +163,51 @@ def _causal_kernel_attention(q, k, v, attn_impl, window, block_q, block_k):
     reference einsum gets a (fused) group repeat, a no-op when k/v already
     carry full heads."""
     if attn_impl == "flash":
-        return flash_attention(q, k, v, True, block_q=block_q,
-                               block_k=block_k, window=window)
+        return flash_attention(q, k, v, True, window=window)
     from tpunet.ops.flash_attention import _repeat_kv
 
     group = q.shape[2] // k.shape[2]
     return attention_reference(q, _repeat_kv(k, group), _repeat_kv(v, group),
                                True, window=window)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What ONE block is built from: the one field of `Block` and of
+    `SelfAttention`, each of which reads `self.spec.<name>` where it uses
+    it. `Transformer.layer_specs()` is the only code that makes one, and it
+    fills every field but `head_dim` from the model's field of the same
+    name — so a new block-level field is a declaration here, one on
+    `Transformer` (which holds the default and the comment users read) and
+    its use. No defaults here: a spec never exists apart from a model."""
+
+    n_heads: int
+    head_dim: int
+    d_ff: int
+    n_experts: int
+    capacity_factor: float
+    moe_top_k: int
+    compute_dtype: jnp.dtype
+    attn_impl: str
+    mesh: Mesh | None
+    dp_axis: str | None
+    sp_axis: str
+    tp_axis: str | None
+    n_kv_heads: int | None
+    mlp_impl: str
+    decode: bool
+    attn_window: int | None
+    weight_quant: str | None
+    prefill: bool
+    per_row_cache: bool
+    decode_ring_cache: bool
+    lora_rank: int
+    lora_alpha: float | None
+    norm_eps: float
+    norm_unit_offset: bool
+    rope_theta: float
+    eva_window: int | None
+    eva_chunk: int | None
 
 
 class SelfAttention(nn.Module):
@@ -208,103 +247,42 @@ class SelfAttention(nn.Module):
     memory, O(window) decode compute), not just a masking pattern.
     """
 
-    n_heads: int
-    head_dim: int
-    compute_dtype: jnp.dtype = jnp.bfloat16
-    attn_impl: str = "reference"
-    mesh: Mesh | None = None
-    dp_axis: str | None = "dp"
-    sp_axis: str = "sp"
-    tp_axis: str | None = None
-    n_kv_heads: int | None = None
-    decode: bool = False
-    attn_window: int | None = None  # sliding-window causal (flash/reference)
-    # Flash kernel tile sizes (attn_impl="flash" only). None leaves the
-    # choice to tpunet.ops.flash_attention._plan, which makes it from the
-    # shapes of each call; an explicit value wins, so that an on-chip block
-    # sweep (benchmarks.mfu_attribution --sweep-blocks) can be applied to the
-    # model without editing kernel code.
-    flash_block_q: int | None = None
-    flash_block_k: int | None = None
-    weight_quant: str | None = None
-    prefill: bool = False  # decode=True only: first fill of an EMPTY cache
-    #   runs block-causal attention through the configured kernel (flash on
-    #   chip) instead of the s x cap masked dense einsum below
-    per_row_cache: bool = False  # decode=True: cache_index is (b,) — each
-    #   batch slot advances independently (continuous batching)
-    decode_ring_cache: bool = True  # attn_window + decode: the cache is a
-    #   rolling ring buffer — leaves sized min(window, capacity), O(window)
-    #   decode contraction. False = full-capacity masked cache.
-    #   speculative_generate keeps the ring when gamma + 1 <= window
-    #   (rollback stashes/restores the overwritten slots) and falls back
-    #   to the masked cache for narrower windows.
-    lora_rank: int = 0
-    lora_alpha: float | None = None
-    rope_theta: float = 10000.0
-    eva_window: int | None = None  # attn_impl="eva": positions a window
-    eva_chunk: int | None = None   #   and positions a summary
+    spec: LayerSpec
 
     @nn.compact
     def __call__(self, x):
+        spec = self.spec
         b, s, _ = x.shape
-        h, dh = self.n_heads, self.head_dim
-        kv = self.n_kv_heads or h
+        h, dh = spec.n_heads, spec.head_dim
+        kv = spec.n_kv_heads or h
         if h % kv:
             raise ValueError(f"n_heads {h} not divisible by n_kv_heads {kv}")
-        if self.attn_impl == "eva" and (
-                kv != h or not self.eva_window or not self.eva_chunk):
+        if spec.attn_impl == "eva" and (
+                kv != h or not spec.eva_window or not spec.eva_chunk):
             raise ValueError("attn_impl='eva' needs eva_window, eva_chunk and "
                              "n_kv_heads == n_heads")
-        if (self.attn_impl == "flash" and not self.decode
-                and (self.flash_block_q, self.flash_block_k) != (None, None)):
-            # Explicit tile sizes must actually be honored:
-            # flash_attention silently falls back to the O(S^2) reference
-            # einsum for untileable shapes, and compiled Mosaic silently
-            # clamps non-lane-aligned block_q to 128 — either would make a
-            # swept "faster" block size a fiction. Fail loud instead.
-            # decode=True is exempt: cached steps never reach the flash
-            # kernel (dense-einsum branch below) and prefill prompts have
-            # arbitrary lengths, where the reference fallback is the point.
-            # one given alone is also the other's value, as in the plan
-            bq = self.flash_block_q or self.flash_block_k
-            bk = self.flash_block_k or self.flash_block_q
-            if s % bq or s % bk or bq % bk:
-                raise ValueError(
-                    f"flash_block_q/k=({bq},{bk}) do not tile seq {s} under "
-                    "the causal kernel (need s%bq==0, s%bk==0, bq%bk==0) — "
-                    "flash_attention would silently take the reference path"
-                )
-            min_sublane = 32 // jnp.dtype(self.compute_dtype).itemsize
-            if (bq % 128 and bq != s) or (bk % min_sublane and bk != s):
-                raise ValueError(
-                    f"flash_block_q/k=({bq},{bk}) are not Mosaic-legal for "
-                    f"{jnp.dtype(self.compute_dtype).name} on compiled TPU "
-                    f"(block_q: multiple of 128 or full seq; block_k: "
-                    f"multiple of {min_sublane}) — the kernel would silently "
-                    "clamp them"
-                )
-        if self.attn_window is not None and self.attn_impl not in (
+        if spec.attn_window is not None and spec.attn_impl not in (
             "reference", "flash"
         ):
             raise ValueError(
                 f"attn_window is only supported by attn_impl 'reference'/"
-                f"'flash', not {self.attn_impl!r}"
+                f"'flash', not {spec.attn_impl!r}"
             )
-        dt = self.compute_dtype
-        proj = lambda nh, name: _dense(nh * dh, dt, name, self.weight_quant, self.lora_rank, self.lora_alpha)
+        dt = spec.compute_dtype
+        proj = lambda nh, name: _dense(nh * dh, dt, name, spec.weight_quant, spec.lora_rank, spec.lora_alpha)
         q = proj(h, "q")(x).reshape(b, s, h, dh)
         k = proj(kv, "k")(x).reshape(b, s, kv, dh)
         v = proj(kv, "v")(x).reshape(b, s, kv, dh)
 
-        if self.decode:
+        if spec.decode:
             # The cached step below is dense local attention — correct for
             # "reference"/"flash" (same math), semantically WRONG for the
             # sequence-parallel impls (sharded/permuted inputs, cross-device
             # k/v). Fail loud rather than generate silent garbage.
-            if self.attn_impl not in ("reference", "flash"):
+            if spec.attn_impl not in ("reference", "flash"):
                 raise ValueError(
                     f"decode=True does not support attn_impl="
-                    f"{self.attn_impl!r}; decode on the full sequence with "
+                    f"{spec.attn_impl!r}; decode on the full sequence with "
                     "attn_impl='reference' (e.g. model.clone("
                     "attn_impl='reference') before generate())"
                 )
@@ -312,26 +290,26 @@ class SelfAttention(nn.Module):
             # init call (whose input sets the cache capacity = its seq len)
             # which otherwise runs the ordinary causal path below; every
             # later apply with mutable=["cache"] takes the step branch.
-            ring = self.attn_window is not None and self.decode_ring_cache
+            ring = spec.attn_window is not None and spec.decode_ring_cache
             # Ring mode sizes the leaves at min(window, capacity) — the
             # init call's s IS the capacity (init with a max-length dummy),
             # so eval_shape-based init_cache allocates O(window) for free.
-            cshape = ((b, min(self.attn_window, s), kv, dh) if ring
+            cshape = ((b, min(spec.attn_window, s), kv, dh) if ring
                       else k.shape)
             filled = self.has_variable("cache", "cached_key")
             ckey = self.variable("cache", "cached_key", jnp.zeros, cshape, k.dtype)
             cval = self.variable("cache", "cached_value", jnp.zeros, cshape, v.dtype)
             cidx = self.variable(
                 "cache", "cache_index",
-                lambda: jnp.zeros((b,) if self.per_row_cache else (),
+                lambda: jnp.zeros((b,) if spec.per_row_cache else (),
                                   jnp.int32)
             )
             if filled:
                 idx = cidx.value
                 cap = ckey.value.shape[1]
                 step_pos = (idx[..., None] + jnp.arange(s)).astype(jnp.float32)
-                q = rotary_embed(q, self.rope_theta, positions=step_pos)
-                k = rotary_embed(k, self.rope_theta, positions=step_pos)
+                q = rotary_embed(q, spec.rope_theta, positions=step_pos)
+                k = rotary_embed(k, spec.rope_theta, positions=step_pos)
                 rows = jnp.arange(b)[:, None]
                 if ring:
                     # A full-width ring never overflows: writes land at pos
@@ -341,7 +319,7 @@ class SelfAttention(nn.Module):
                     # the ring wraps before the window does — eviction would
                     # silently corrupt in-window history, so keep the loud
                     # NaN-poison past capacity. Both sizes are static.
-                    if cap < self.attn_window:
+                    if cap < spec.attn_window:
                         overflow = idx + s > cap
                     else:
                         overflow = jnp.zeros(idx.shape, bool)
@@ -353,7 +331,7 @@ class SelfAttention(nn.Module):
                     m = min(s, cap)  # static: a step writes its last m
                     wpos = idx[..., None] + jnp.arange(s - m, s)
                     slot = jnp.mod(wpos, cap)  # (m,) or (b, m), all distinct
-                    if self.per_row_cache:
+                    if spec.per_row_cache:
                         ckey.value = ckey.value.at[rows, slot].set(k[:, s - m:])
                         cval.value = cval.value.at[rows, slot].set(v[:, s - m:])
                     else:
@@ -370,7 +348,7 @@ class SelfAttention(nn.Module):
                     # row, and the cache write is a per-row scatter instead
                     # of one slice.
                     overflow = idx + s > cap
-                    if self.per_row_cache:
+                    if spec.per_row_cache:
                         pos_i = idx[:, None] + jnp.arange(s)  # (b, s)
                         ckey.value = ckey.value.at[rows, pos_i].set(k)
                         cval.value = cval.value.at[rows, pos_i].set(v)
@@ -382,7 +360,7 @@ class SelfAttention(nn.Module):
                             cval.value, v, (0, idx, 0, 0)
                         )
                 cidx.value = idx + s
-                if self.prefill:
+                if spec.prefill:
                     # First fill of an EMPTY cache: the block attends only
                     # within itself, which is plain causal self-attention —
                     # run it through the configured kernel (flash: O(s)
@@ -393,15 +371,14 @@ class SelfAttention(nn.Module):
                     # valid at idx == 0 — poisoned to NaN otherwise, same
                     # discipline as the overflow guard.
                     o = _causal_kernel_attention(
-                        q, k, v, self.attn_impl, self.attn_window,
-                        self.flash_block_q, self.flash_block_k)
+                        q, k, v, spec.attn_impl, spec.attn_window)
                     bad = overflow | (idx != 0)
-                    if self.per_row_cache:
+                    if spec.per_row_cache:
                         bad = bad[:, None, None, None]  # poison own row only
                     o = jnp.where(bad, jnp.nan, o).astype(dt)
                     o = o.reshape(b, s, h * dh)
-                    return _dense(x.shape[-1], dt, "out", self.weight_quant,
-                                  self.lora_rank, self.lora_alpha)(o)
+                    return _dense(x.shape[-1], dt, "out", spec.weight_quant,
+                                  spec.lora_rank, spec.lora_alpha)(o)
                 # Grouped einsum: q reshaped to (b, s, kv, group, dh)
                 # contracts DIRECTLY against the (b, K, kv, dh) cache —
                 # the group-repeated K/V never exists in HBM. This is the
@@ -438,15 +415,15 @@ class SelfAttention(nn.Module):
                 kp = (key_pos[:, None, None, None, :] if key_pos.ndim == 2
                       else key_pos[None, None, None, None, :])
                 pos = idx[..., None] + jnp.arange(s)  # (s,) or (b, s)
-                if self.per_row_cache:
+                if spec.per_row_cache:
                     q_pos = pos[:, None, None, :, None]
                     row_overflow = overflow[:, None, None, None]
                 else:
                     q_pos = pos[None, None, None, :, None]
                     row_overflow = overflow
                 keep = (kp >= 0) & (kp <= q_pos)
-                if self.attn_window is not None:
-                    keep &= (q_pos - kp) < self.attn_window
+                if spec.attn_window is not None:
+                    keep &= (q_pos - kp) < spec.attn_window
                 scores = jnp.where(keep, scores, -jnp.inf)
                 probs = jax.nn.softmax(scores, axis=-1)
                 o = jnp.einsum(
@@ -454,18 +431,18 @@ class SelfAttention(nn.Module):
                 ).reshape(b, s, h, dh)
                 o = jnp.where(row_overflow, jnp.nan, o)
                 o = o.astype(dt).reshape(b, s, h * dh)
-                return _dense(x.shape[-1], dt, "out", self.weight_quant,
-                              self.lora_rank, self.lora_alpha)(o)
+                return _dense(x.shape[-1], dt, "out", spec.weight_quant,
+                              spec.lora_rank, spec.lora_alpha)(o)
 
         pos_offset = 0
         positions = None
-        if self.attn_impl in ("dcn_ring", "dcn_ulysses"):
+        if spec.attn_impl in ("dcn_ring", "dcn_ulysses"):
             # The per-process model sees only its sequence shard; rotary
             # must use global positions for the ring to be coherent.
             from tpunet import distributed
 
             pos_offset = distributed.rank() * s
-        elif self.attn_impl == "dcn_zigzag":
+        elif spec.attn_impl == "dcn_zigzag":
             # Per-process shard = zigzag chunk pair of the global sequence.
             from tpunet import distributed
             from tpunet.parallel.zigzag_attention import zigzag_positions
@@ -475,20 +452,20 @@ class SelfAttention(nn.Module):
                 distributed.world_size() * s,
                 distributed.rank(),
             ).astype(jnp.float32)
-        elif self.attn_impl == "zigzag":
+        elif spec.attn_impl == "zigzag":
             # The WHOLE sequence axis is in zigzag chunk order (tokens fed
             # through to_zigzag); rotary needs each row's natural position.
             from tpunet.parallel.zigzag_attention import to_zigzag
 
-            if self.mesh is None:
+            if spec.mesh is None:
                 raise ValueError("attn_impl='zigzag' requires a mesh")
             positions = to_zigzag(
                 jnp.arange(s, dtype=jnp.float32),
-                self.mesh.shape[self.sp_axis], axis=0,
+                spec.mesh.shape[spec.sp_axis], axis=0,
             )
-        q = rotary_embed(q, self.rope_theta, pos_offset, positions)
-        k = rotary_embed(k, self.rope_theta, pos_offset, positions)
-        if kv != h and self.attn_impl != "flash":
+        q = rotary_embed(q, spec.rope_theta, pos_offset, positions)
+        k = rotary_embed(k, spec.rope_theta, pos_offset, positions)
+        if kv != h and spec.attn_impl != "flash":
             # GQA broadcast AFTER rotary (rotary runs on the kv heads): the
             # projection savings are already banked; every impl below then
             # sees plain MHA shapes. XLA fuses the repeat into the consumer.
@@ -498,7 +475,7 @@ class SelfAttention(nn.Module):
             k = jnp.repeat(k, h // kv, axis=2)
             v = jnp.repeat(v, h // kv, axis=2)
 
-        if self.attn_impl == "eva":
+        if spec.attn_impl == "eva":
             from tpunet.ops.eva_attention import eva_attention
 
             def vec(key, shape):  # the released model's initialisation
@@ -506,42 +483,41 @@ class SelfAttention(nn.Module):
 
             o = eva_attention(q, k, v, self.param("adaptive_phi", vec, (h, dh)),
                               self.param("adaptive_mu_k", vec, (h, dh)),
-                              self.eva_window, self.eva_chunk)
-        elif self.attn_impl == "zigzag":
+                              spec.eva_window, spec.eva_chunk)
+        elif spec.attn_impl == "zigzag":
             from tpunet.parallel.zigzag_attention import zigzag_self_attention
 
             o = zigzag_self_attention(
-                q, k, v, self.mesh,
-                dp_axis=self.dp_axis, sp_axis=self.sp_axis, tp_axis=self.tp_axis,
+                q, k, v, spec.mesh,
+                dp_axis=spec.dp_axis, sp_axis=spec.sp_axis, tp_axis=spec.tp_axis,
             )
-        elif self.attn_impl in ("ring", "ulysses"):
-            if self.mesh is None:
-                raise ValueError(f"attn_impl={self.attn_impl!r} requires a mesh")
-            sp_fn = ring_self_attention if self.attn_impl == "ring" else ulysses_self_attention
+        elif spec.attn_impl in ("ring", "ulysses"):
+            if spec.mesh is None:
+                raise ValueError(f"attn_impl={spec.attn_impl!r} requires a mesh")
+            sp_fn = ring_self_attention if spec.attn_impl == "ring" else ulysses_self_attention
             o = sp_fn(
-                q, k, v, self.mesh, causal=True,
-                dp_axis=self.dp_axis, sp_axis=self.sp_axis, tp_axis=self.tp_axis,
+                q, k, v, spec.mesh, causal=True,
+                dp_axis=spec.dp_axis, sp_axis=spec.sp_axis, tp_axis=spec.tp_axis,
             )
-        elif self.attn_impl == "dcn_ring":
+        elif spec.attn_impl == "dcn_ring":
             from tpunet.parallel.dcn_ring_attention import dcn_ring_attention
 
             o = dcn_ring_attention(q, k, v, causal=True)
-        elif self.attn_impl == "dcn_zigzag":
+        elif spec.attn_impl == "dcn_zigzag":
             from tpunet.parallel.dcn_ring_attention import dcn_zigzag_attention
 
             o = dcn_zigzag_attention(q, k, v)
-        elif self.attn_impl == "dcn_ulysses":
+        elif spec.attn_impl == "dcn_ulysses":
             from tpunet.parallel.ulysses import dcn_ulysses_attention
 
             o = dcn_ulysses_attention(q, k, v, causal=True)
         else:  # flash / reference — k/v are pre-broadcast for non-flash
             o = _causal_kernel_attention(
-                q, k, v, self.attn_impl, self.attn_window,
-                self.flash_block_q, self.flash_block_k)
+                q, k, v, spec.attn_impl, spec.attn_window)
 
         o = o.reshape(b, s, h * dh)
-        return _dense(x.shape[-1], dt, "out", self.weight_quant,
-                      self.lora_rank, self.lora_alpha)(o)
+        return _dense(x.shape[-1], dt, "out", spec.weight_quant,
+                      spec.lora_rank, spec.lora_alpha)(o)
 
 
 class Mlp(nn.Module):
@@ -647,61 +623,21 @@ class MoeMlp(nn.Module):
 
 
 class Block(nn.Module):
-    n_heads: int
-    head_dim: int
-    d_ff: int
-    n_experts: int = 0
-    capacity_factor: float = 1.25
-    compute_dtype: jnp.dtype = jnp.bfloat16
-    attn_impl: str = "reference"
-    mesh: Mesh | None = None
-    dp_axis: str | None = "dp"
-    sp_axis: str = "sp"
-    tp_axis: str | None = None
-    n_kv_heads: int | None = None
-    mlp_impl: str = "gelu"
-    decode: bool = False
-    attn_window: int | None = None
-    flash_block_q: int | None = None
-    flash_block_k: int | None = None
-    moe_top_k: int = 1
-    weight_quant: str | None = None
-    prefill: bool = False
-    per_row_cache: bool = False
-    decode_ring_cache: bool = True
-    lora_rank: int = 0
-    lora_alpha: float | None = None
-    norm_eps: float = 1e-6
-    norm_unit_offset: bool = False
-    rope_theta: float = 10000.0
-    eva_window: int | None = None
-    eva_chunk: int | None = None
+    spec: LayerSpec
 
     @nn.compact
     def __call__(self, x):
+        spec = self.spec
         norm = lambda name: RMSNorm(  # noqa: E731
-            self.norm_eps, self.norm_unit_offset, self.compute_dtype, name=name)
-        x = x + SelfAttention(
-            self.n_heads, self.head_dim, self.compute_dtype, self.attn_impl,
-            self.mesh, self.dp_axis, self.sp_axis, self.tp_axis,
-            n_kv_heads=self.n_kv_heads, decode=self.decode,
-            attn_window=self.attn_window,
-            flash_block_q=self.flash_block_q,
-            flash_block_k=self.flash_block_k,
-            weight_quant=self.weight_quant, prefill=self.prefill,
-            per_row_cache=self.per_row_cache,
-            decode_ring_cache=self.decode_ring_cache,
-            lora_rank=self.lora_rank,
-            lora_alpha=self.lora_alpha, rope_theta=self.rope_theta,
-            eva_window=self.eva_window, eva_chunk=self.eva_chunk, name="attn",
-        )(norm("norm1")(x))
-        if self.n_experts > 0:
-            mlp = MoeMlp(self.n_experts, self.d_ff, self.capacity_factor,
-                         self.compute_dtype, top_k=self.moe_top_k, name="moe")
+            spec.norm_eps, spec.norm_unit_offset, spec.compute_dtype, name=name)
+        x = x + SelfAttention(spec, name="attn")(norm("norm1")(x))
+        if spec.n_experts > 0:
+            mlp = MoeMlp(spec.n_experts, spec.d_ff, spec.capacity_factor,
+                         spec.compute_dtype, top_k=spec.moe_top_k, name="moe")
         else:
-            mlp = Mlp(self.d_ff, self.compute_dtype, self.mlp_impl,
-                      weight_quant=self.weight_quant,
-                      lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
+            mlp = Mlp(spec.d_ff, spec.compute_dtype, spec.mlp_impl,
+                      weight_quant=spec.weight_quant,
+                      lora_rank=spec.lora_rank, lora_alpha=spec.lora_alpha,
                       name="mlp")
         return x + mlp(norm("norm2")(x))
 
@@ -734,8 +670,6 @@ class Transformer(nn.Module):
     attn_window: int | None = None  # sliding-window causal attention (Mistral
     #   -style): each token sees the window most recent positions; flash
     #   kernels prune to O(S*window) FLOPs. reference/flash impls only.
-    flash_block_q: int | None = None  # flash kernel tile sizes; None = chosen
-    flash_block_k: int | None = None  #   from the shapes (ops.flash_attention._plan)
     weight_quant: str | None = None  # "int8" = weight-only quantized matmuls
     #   (inference: pair with tpunet.models.quantize_params on a trained
     #   fp tree; halves the weight HBM traffic decode is bound by)
@@ -747,8 +681,9 @@ class Transformer(nn.Module):
     #   the continuous-batching substrate (tpunet.models.serve.BatchServer)
     decode_ring_cache: bool = True  # attn_window + decode: rolling ring-
     #   buffer KV cache, leaves sized min(window, cap) — bounded memory and
-    #   O(window) decode contraction. speculative_generate keeps it when
-    #   gamma + 1 <= window (stash/restore rollback), else masked cache.
+    #   O(window) decode contraction; False = full-capacity masked cache.
+    #   speculative_generate keeps the ring when gamma + 1 <= window
+    #   (stash/restore rollback), else falls back to the masked cache.
     lora_rank: int = 0             # > 0: rank-r LoRA adapters on every Dense
     #   (tpunet.models.lora: lora_mask to train only A/B, graft_base to
     #   load a base checkpoint, merge_lora to fold back); composes with
@@ -764,6 +699,23 @@ class Transformer(nn.Module):
     #   logits, (b, s, n, vocab); head j predicts the token at t + 1 + j
     eva_window: int | None = None  # attn_impl="eva" (tpunet.ops.eva_attention)
     eva_chunk: int | None = None
+
+    @nn.nowrap
+    def layer_specs(self) -> tuple[LayerSpec, ...]:
+        """One LayerSpec a block. The only code that reads the model's
+        fields into specs — by NAME, so a field declared on both needs no
+        line here — and the only place a layer is made to differ from its
+        neighbours: today the experts, in every `moe_every`-th block."""
+        base = LayerSpec(head_dim=self.d_model // self.n_heads, **{
+            f.name: getattr(self, f.name)
+            for f in dataclasses.fields(LayerSpec) if f.name != "head_dim"})
+
+        def layer(i):
+            moe = self.n_experts > 0 and (i + 1) % self.moe_every == 0
+            return dataclasses.replace(
+                base, n_experts=self.n_experts if moe else 0)
+
+        return tuple(layer(i) for i in range(self.n_layers))
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, features_only: bool = False):
@@ -799,7 +751,6 @@ class Transformer(nn.Module):
             "embed", nn.initializers.normal(0.02), (self.vocab, self.d_model)
         )
         x = emb[tokens].astype(self.residual_dtype or self.compute_dtype)
-        head_dim = self.d_model // self.n_heads
         # remat drops block activations in the forward pass and recomputes
         # them in the backward — the standard long-context memory lever
         # (sequence activations dominate HBM; FLOPs are MXU-cheap).
@@ -818,29 +769,8 @@ class Transformer(nn.Module):
         else:
             pol = policies[self.remat_policy]
             block_cls = nn.remat(Block, policy=pol) if pol else nn.remat(Block)
-        for i in range(self.n_layers):
-            moe = self.n_experts > 0 and (i + 1) % self.moe_every == 0
-            x = block_cls(
-                self.n_heads, head_dim, self.d_ff,
-                n_experts=self.n_experts if moe else 0,
-                capacity_factor=self.capacity_factor,
-                moe_top_k=self.moe_top_k,
-                compute_dtype=self.compute_dtype, attn_impl=self.attn_impl,
-                mesh=self.mesh, dp_axis=self.dp_axis, sp_axis=self.sp_axis,
-                tp_axis=self.tp_axis, n_kv_heads=self.n_kv_heads,
-                mlp_impl=self.mlp_impl, decode=self.decode,
-                attn_window=self.attn_window,
-                flash_block_q=self.flash_block_q,
-                flash_block_k=self.flash_block_k,
-                weight_quant=self.weight_quant, prefill=self.prefill,
-                per_row_cache=self.per_row_cache,
-                decode_ring_cache=self.decode_ring_cache,
-                lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
-                norm_eps=self.norm_eps, norm_unit_offset=self.norm_unit_offset,
-                rope_theta=self.rope_theta,
-                eva_window=self.eva_window, eva_chunk=self.eva_chunk,
-                name=f"block{i}",
-            )(x)
+        for i, spec in enumerate(self.layer_specs()):
+            x = block_cls(spec, name=f"block{i}")(x)
         x = RMSNorm(self.norm_eps, self.norm_unit_offset, self.compute_dtype,
                     name="norm_f")(x)
         if features_only:
