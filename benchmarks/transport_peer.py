@@ -7,8 +7,11 @@ joins a Communicator and contributes constant vectors to the all-reduces it
 is told about. One JSON object per line in, one per line out:
 
   {"op": "init", "lib": path, "coordinator": "127.0.0.1:P", "rank": r, "world": w}
-  {"op": "all_reduce", "dtype": "float32", "n": N, "fill": v, "reps": k,
-   "expect": e | null}                 k blocking all-reduces of N x v
+  {"op": "all_reduce", "dtype": "float32", "n": N | [N0, N1, ...],
+   "fill": v, "reps": k, "expect": e | null}
+                                       k times: a blocking all-reduce of
+                                       N x v (of each N in turn: the chunks
+                                       of the trainer's boundary exchange)
   {"op": "close"}
 
 Every reply is {"ok": true, "op": ..., "seconds": [...]}; a failure replies
@@ -54,16 +57,18 @@ def serve(lines, out) -> None:
             comm = Communicator(msg["coordinator"], msg["rank"], msg["world"])
             reply(ok=True, op=op)
         elif op == "all_reduce":
-            send = np.full(msg["n"], msg["fill"], _dtype(msg["dtype"]))
+            sizes = msg["n"] if isinstance(msg["n"], list) else [msg["n"]]
+            sends = [np.full(n, msg["fill"], _dtype(msg["dtype"])) for n in sizes]
             seconds = []
             for _ in range(msg["reps"]):
                 t0 = time.perf_counter()
-                got = comm.all_reduce(send)
+                gots = [comm.all_reduce(send) for send in sends]
                 seconds.append(time.perf_counter() - t0)
                 want = msg.get("expect")
-                if want is not None and not (got == got.dtype.type(want)).all():
-                    raise RuntimeError(
-                        f"all_reduce gave {got[:4]}..., expected {want}")
+                for got in gots:
+                    if want is not None and not (got == got.dtype.type(want)).all():
+                        raise RuntimeError(
+                            f"all_reduce gave {got[:4]}..., expected {want}")
             reply(ok=True, op=op, seconds=seconds)
         elif op == "close":
             break

@@ -507,7 +507,7 @@ def phase_dcn(sz: Sizes, peer: Peer, lib_path, train_first_loss: float) -> dict:
     import numpy as np
 
     from tpunet import distributed
-    from tpunet.interop import dcn_pmean, dcn_psum
+    from tpunet.interop import boundary_chunks, dcn_pmean, dcn_psum
 
     join_world(peer, lib_path)
     compile_s, t_run = 0.0, time.perf_counter()
@@ -546,14 +546,16 @@ def phase_dcn(sz: Sizes, peer: Peer, lib_path, train_first_loss: float) -> dict:
     peer.reply()
     del x, fn
 
-    # The cross-host step: the whole gradient as ONE flat f32 all-reduce per
-    # step (trainer.py), to which the peer adds zeros of the same length.
+    # The cross-host step: the whole gradient as one flat f32 vector a step,
+    # all-reduced a chunk at a time (trainer.py, interop.boundary_chunks); the
+    # peer adds zeros of each chunk's length.
     state, step, tokens, labels, key = train_setup(sz, cross_host=True)
     n_grad = sum(x.size for x in jax.tree.leaves(state.params))
     compiled, c_s, kernels = compile_counted(
         step, (state, tokens, labels, key), sz.train_kernels)
     compile_s += c_s
-    peer.send(op="all_reduce", dtype="float32", n=n_grad, fill=0.0,
+    peer.send(op="all_reduce", dtype="float32",
+              n=list(boundary_chunks(n_grad, 4, 2)), fill=0.0,
               reps=sz.dcn_steps, expect=None)
     losses, step_s = fit_timed(compiled, state, tokens, labels, key,
                                sz.dcn_steps)

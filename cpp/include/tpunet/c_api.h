@@ -338,13 +338,14 @@ int32_t tpunet_c_trace_set_dir(const char* dir);
  * CLOCK_MONOTONIC in microseconds (Python: time.monotonic_ns() // 1000).
  * Args written: seq (the root span's per-process counter, shared by its
  * children), nbytes, and — when given — parent (the enclosing span's name),
- * kind, step (step < 0 = none); never comm_id/coll_seq, the collective
- * phases' join key. `name`, `parent`, `kind`: [A-Za-z0-9_.:-]{1,64} (parent
+ * kind, step, chunk (step, chunk < 0 = none; chunk numbers the pieces of a
+ * boundary exchange); never comm_id/coll_seq, the collective phases' join
+ * key. `name`, `parent`, `kind`: [A-Za-z0-9_.:-]{1,64} (parent
  * and kind may be NULL or ""). Returns 1 when recorded, 0 when tracing is
  * off (the caller stops calling), or TPUNET_ERR_INVALID. */
 int32_t tpunet_c_trace_span(const char* name, uint64_t start_us, uint64_t dur_us,
                             uint64_t seq, uint64_t nbytes, const char* parent,
-                            const char* kind, int64_t step);
+                            const char* kind, int64_t step, int64_t chunk);
 /* Count one host callback of the DCN bridge (tpunet/interop.py's
  * io_callback path) and its operand bytes into
  * tpunet_bridge_{calls,bytes}_total{kind=...}: 0 = all_reduce,
@@ -352,6 +353,12 @@ int32_t tpunet_c_trace_span(const char* name, uint64_t start_us, uint64_t dur_us
  * 4 = reduce_scatter, 5 = all_to_all, 6 = broadcast,
  * 7 = neighbor_exchange. */
 int32_t tpunet_c_bridge_call(int32_t kind, uint64_t nbytes);
+/* Count the chunks of one boundary exchange (tpunet/interop.py's
+ * host_all_reduce) into tpunet_bridge_chunks_total{kind=...} and keep the
+ * most that were in flight at one time (copy to the host started,
+ * device_put not yet returned) in tpunet_bridge_chunks_in_flight_max{kind=...}.
+ * `kind` as for tpunet_c_bridge_call. */
+int32_t tpunet_c_bridge_chunks(int32_t kind, uint64_t chunks, uint64_t in_flight);
 /* Bound port of the on-demand /metrics listener, or 0 when no listener is
  * up. TPUNET_METRICS_PORT unset/empty = no listener; an explicit 0 binds an
  * EPHEMERAL port (multi-tier loopback: several processes on one box each

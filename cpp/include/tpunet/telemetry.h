@@ -199,6 +199,11 @@ struct MetricsSnapshot {
   // it, by collective kind. The FFI path never feeds these.
   uint64_t bridge_calls[kBridgeKindCount] = {0};
   uint64_t bridge_bytes[kBridgeKindCount] = {0};
+  // The boundary exchange's chunks (tpunet.interop.host_all_reduce): how
+  // many crossed, and the most that were between the start of their copy to
+  // the host and the return of their device_put at one time.
+  uint64_t bridge_chunks[kBridgeKindCount] = {0};
+  uint64_t bridge_chunks_in_flight_max[kBridgeKindCount] = {0};
   // Zero-copy data-path counters (docs/DESIGN.md "Data path"): wire syscalls
   // indexed by utils.h IoOp (send, recv, sendmsg, recvmsg) and bytes
   // produced by the reduction kernels. syscalls/MiB is derived from these in
@@ -293,13 +298,17 @@ class Telemetry {
   // loop) buffered into the SAME trace file, stamped by the caller with
   // MonotonicUs()'s clock. Tagged {seq, parent}, never {comm_id, coll_seq}:
   // those stay the collective phases' join key. `parent`/`kind` may be
-  // empty, `step` < 0 means none. Returns false when tracing is off.
+  // empty, `step` and `chunk` < 0 mean none. Returns false when tracing is
+  // off.
   bool OnProgramSpan(const char* name, uint64_t start_us, uint64_t dur_us,
                      uint64_t seq, uint64_t nbytes, const char* parent,
-                     const char* kind, int64_t step);
+                     const char* kind, int64_t step, int64_t chunk);
   // One host callback of the DCN bridge (tpunet_c_bridge_call): `kind`
   // indexes kBridgeKindCount, `nbytes` is the operand's size.
   void OnBridgeCall(int kind, uint64_t nbytes);
+  // One boundary exchange's chunks (tpunet_c_bridge_chunks): `chunks`
+  // crossed, at most `in_flight` of them at one time (kept as a maximum).
+  void OnBridgeChunks(int kind, uint64_t chunks, uint64_t in_flight);
   // Failure-containment hooks (cold paths). `action` indexes FaultAction.
   void OnFaultInjected(int action);
   void OnStreamFailover();

@@ -609,14 +609,15 @@ bool SpanNameOk(const char* s, bool may_be_empty) {
 
 int32_t tpunet_c_trace_span(const char* name, uint64_t start_us, uint64_t dur_us,
                             uint64_t seq, uint64_t nbytes, const char* parent,
-                            const char* kind, int64_t step) {
+                            const char* kind, int64_t step, int64_t chunk) {
   if (!SpanNameOk(name, false) || !SpanNameOk(parent, true) ||
       !SpanNameOk(kind, true)) {
     return Fail(TPUNET_ERR_INVALID,
                 "span name/parent/kind must be 1-64 chars of [A-Za-z0-9_.:-]");
   }
   return tpunet::Telemetry::Get().OnProgramSpan(name, start_us, dur_us, seq,
-                                                nbytes, parent, kind, step)
+                                                nbytes, parent, kind, step,
+                                                chunk)
              ? 1
              : 0;
 }
@@ -629,6 +630,14 @@ int32_t tpunet_c_bridge_call(int32_t kind, uint64_t nbytes) {
                 "broadcast, neighbor_exchange)");
   }
   tpunet::Telemetry::Get().OnBridgeCall(kind, nbytes);
+  return TPUNET_OK;
+}
+
+int32_t tpunet_c_bridge_chunks(int32_t kind, uint64_t chunks, uint64_t in_flight) {
+  if (kind < 0 || kind >= tpunet::kBridgeKindCount) {
+    return Fail(TPUNET_ERR_INVALID, "kind must be 0..7 (as tpunet_c_bridge_call)");
+  }
+  tpunet::Telemetry::Get().OnBridgeChunks(kind, chunks, in_flight);
   return TPUNET_OK;
 }
 

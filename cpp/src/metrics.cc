@@ -241,6 +241,8 @@ struct Telemetry::Impl {
   // operand bytes they staged, by collective kind.
   std::atomic<uint64_t> bridge_calls[kBridgeKindCount] = {};
   std::atomic<uint64_t> bridge_bytes[kBridgeKindCount] = {};
+  std::atomic<uint64_t> bridge_chunks[kBridgeKindCount] = {};
+  std::atomic<uint64_t> bridge_chunks_in_flight_max[kBridgeKindCount] = {};
 
   // TCP introspection (always on unless TPUNET_TCPINFO_INTERVAL_MS=0).
   uint64_t tcp_interval_us =
@@ -781,7 +783,7 @@ void Telemetry::OnCollPhase(uint64_t comm_id, uint64_t coll_seq, const char* pha
 
 bool Telemetry::OnProgramSpan(const char* name, uint64_t start_us, uint64_t dur_us,
                               uint64_t seq, uint64_t nbytes, const char* parent,
-                              const char* kind, int64_t step) {
+                              const char* kind, int64_t step, int64_t chunk) {
   if (!tracing_enabled()) return false;
   Impl* im = impl_.get();
   Span s;
@@ -798,6 +800,7 @@ bool Telemetry::OnProgramSpan(const char* name, uint64_t start_us, uint64_t dur_
   if (parent && *parent) s.extra += std::string(",\"parent\":\"") + parent + "\"";
   if (kind && *kind) s.extra += std::string(",\"kind\":\"") + kind + "\"";
   if (step >= 0) s.extra += ",\"step\":" + std::to_string(step);
+  if (chunk >= 0) s.extra += ",\"chunk\":" + std::to_string(chunk);
   bool flush = false;
   {
     MutexLock lk(im->span_mu);
@@ -812,6 +815,16 @@ void Telemetry::OnBridgeCall(int kind, uint64_t nbytes) {
   if (kind < 0 || kind >= kBridgeKindCount) return;
   impl_->bridge_calls[kind].fetch_add(1, std::memory_order_relaxed);
   impl_->bridge_bytes[kind].fetch_add(nbytes, std::memory_order_relaxed);
+}
+
+void Telemetry::OnBridgeChunks(int kind, uint64_t chunks, uint64_t in_flight) {
+  if (kind < 0 || kind >= kBridgeKindCount) return;
+  impl_->bridge_chunks[kind].fetch_add(chunks, std::memory_order_relaxed);
+  auto& deepest = impl_->bridge_chunks_in_flight_max[kind];
+  uint64_t seen = deepest.load(std::memory_order_relaxed);
+  while (seen < in_flight &&
+         !deepest.compare_exchange_weak(seen, in_flight, std::memory_order_relaxed)) {
+  }
 }
 
 void Telemetry::OnFaultInjected(int action) {
@@ -943,6 +956,8 @@ void Telemetry::Reset() {
   im->weight_version.store(0, std::memory_order_relaxed);
   for (auto& c : im->bridge_calls) c.store(0, std::memory_order_relaxed);
   for (auto& c : im->bridge_bytes) c.store(0, std::memory_order_relaxed);
+  for (auto& c : im->bridge_chunks) c.store(0, std::memory_order_relaxed);
+  for (auto& c : im->bridge_chunks_in_flight_max) c.store(0, std::memory_order_relaxed);
   {
     MutexLock lk(im->win_mu);
     im->win_init = false;
@@ -1085,6 +1100,9 @@ MetricsSnapshot Telemetry::Snapshot() const {
   for (int k = 0; k < kBridgeKindCount; ++k) {
     s.bridge_calls[k] = im->bridge_calls[k].load(std::memory_order_relaxed);
     s.bridge_bytes[k] = im->bridge_bytes[k].load(std::memory_order_relaxed);
+    s.bridge_chunks[k] = im->bridge_chunks[k].load(std::memory_order_relaxed);
+    s.bridge_chunks_in_flight_max[k] =
+        im->bridge_chunks_in_flight_max[k].load(std::memory_order_relaxed);
   }
   s.weight_version = im->weight_version.load(std::memory_order_relaxed);
   for (int t = 0; t < kServeTierCount; ++t) {
@@ -1486,6 +1504,23 @@ std::string Telemetry::PrometheusText() const {
     emit("tpunet_bridge_bytes_total{rank=\"%lld\",kind=\"%s\"} %llu\n",
          (long long)rank, kBridgeKinds[k],
          (unsigned long long)s.bridge_bytes[k]);
+  }
+  family("tpunet_bridge_chunks_total", "counter",
+         "Chunks of a boundary exchange (tpunet/interop.py host_all_reduce) "
+         "that crossed the bridge, by collective kind: K an exchange.");
+  for (int k = 0; k < kBridgeKindCount; ++k) {
+    emit("tpunet_bridge_chunks_total{rank=\"%lld\",kind=\"%s\"} %llu\n",
+         (long long)rank, kBridgeKinds[k],
+         (unsigned long long)s.bridge_chunks[k]);
+  }
+  family("tpunet_bridge_chunks_in_flight_max", "gauge",
+         "Most chunks of one boundary exchange that were between the start of "
+         "their copy to the host and the return of their device_put at one "
+         "time, by collective kind, since the last reset.");
+  for (int k = 0; k < kBridgeKindCount; ++k) {
+    emit("tpunet_bridge_chunks_in_flight_max{rank=\"%lld\",kind=\"%s\"} %llu\n",
+         (long long)rank, kBridgeKinds[k],
+         (unsigned long long)s.bridge_chunks_in_flight_max[k]);
   }
   family("tpunet_hold_on_request", "gauge",
          "Requests posted but not yet test()ed done (in flight).");

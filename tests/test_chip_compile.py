@@ -202,18 +202,25 @@ def test_cross_host_train_step_bucketed(one_chip, as_on_chip):
 def test_cross_host_train_step_is_two_programs_without_a_host_transfer(
         one_chip, as_on_chip):
     """The flat cross-host step: the gradient leaves the chip between its two
-    programs, so neither holds a callback, a send or a recv, and the kernels
-    are all in the first."""
+    programs, as a tuple of chunks with static shapes, so neither program
+    holds a callback, a send or a recv, and the kernels are all in the
+    first."""
     from conftest import free_port
 
-    from tpunet import distributed
+    from tpunet import distributed, interop
 
     distributed.finalize()
     distributed.initialize(f"127.0.0.1:{free_port()}", 0, 1)
     try:
-        text = _train_program(one_chip, cross_host=True).as_text()
+        compiled = _train_program(one_chip, cross_host=True)
     finally:
         distributed.finalize()
+    text = compiled.as_text()
+    # 2.9 GB of f32 gradient: whole chunks of the shipped size and a last one
+    chunks = compiled._grad.output_shardings[1]
+    per = interop._CHUNK_BYTES // 4
+    assert len(chunks) > 2 and text.count(f"f32[{per}]") >= 2 * (len(chunks) - 1)
+    assert compiled._grad.as_text().count(KERNEL) == FULL.train_kernels
     assert text.count(KERNEL) == FULL.train_kernels
     assert "jit_grad_program" in text and "jit_apply_program" in text
     for mark in ("is_host_transfer", "callback", "send-done", "recv-done"):

@@ -124,11 +124,23 @@ def test_serve_token_off_the_reference_is_refused(monkeypatch):
         chip_smoke.phase_decode_serve(TINY)
 
 
-def test_phase_dcn(built_once, peer, io_callback_bridge, train_result):
-    from tpunet import _native
+@pytest.mark.parametrize("chunk_bytes", [None, 1 << 16], ids=["whole", "chunked"])
+def test_phase_dcn(built_once, peer, io_callback_bridge, train_result, chunk_bytes,
+                   monkeypatch):
+    """chunked: the cross-host step's vector crosses in several chunks, as at
+    the chip's size, and the transport-only peer is told each one's length."""
+    from tpunet import _native, interop, telemetry
 
+    if chunk_bytes:
+        monkeypatch.setattr(interop, "_CHUNK_BYTES", chunk_bytes)
+    telemetry.reset()
     out = chip_smoke.phase_dcn(TINY, peer, _native.build_native(),
                                train_result["losses"][0])
+    chunks = sum(telemetry.metrics()["tpunet_bridge_chunks_total"].values())
+    exchanges = len(out["cross_host_losses"])
+    assert chunks == exchanges * len(interop.boundary_chunks(
+        out["cross_host_allreduce_bytes"] // 4, 4, 2))
+    assert (chunks > exchanges) == bool(chunk_bytes)
     assert out["world_size"] == 2
     assert [p["dtype"] for p in out["psum"]] == ["float32", "bfloat16"]
     assert out["cross_host_losses"][0] == train_result["losses"][0]

@@ -2,7 +2,9 @@
 all-reduce between them on the host (make_train_step(cross_host=True)):
 bit-identical to the single program that held dcn_pmean(flat), which stays
 here as the oracle; what the ahead-of-time compile gives back; the bridge's
-counters and spans once a step."""
+counters and spans once a step. Since PR 29 the vector crosses in chunks:
+every case runs with the vector whole (the tiny model is under one chunk of
+the shipped size) and again cut into CHUNKED bytes a chunk."""
 
 from __future__ import annotations
 
@@ -23,6 +25,9 @@ jax.config.update("jax_platforms", "cpu")
 from conftest import free_port, run_spawn_workers  # noqa: E402
 
 STEPS = 3
+# bytes a chunk for the cut cases: the tiny model's gradient (N_GRAD f32) in
+# K_CHUNKED chunks, the last one ragged
+CHUNKED, N_GRAD, K_CHUNKED = 4096, 3120, 4
 BRIDGE_CHILDREN = ["dcn.bridge.stage_in", "dcn.bridge.collective", "dcn.bridge.stage_out"]
 
 
@@ -113,26 +118,34 @@ CASES = {
 }
 
 
-def _parity_worker(rank: int, world: int, port: int, q, case: str) -> None:
+def _parity_worker(rank: int, world: int, port: int, q, case: str,
+                   chunk_bytes: int | None) -> None:
     try:
         kw = dict(CASES[case])
         os.environ["TPUNET_FFI_COLLECTIVES"] = kw.pop("ffi", "1")
         qlora = kw.pop("qlora", False)
-        from tpunet import distributed, telemetry
+        from tpunet import distributed, interop, telemetry
         from tpunet.train import make_train_step
 
+        if chunk_bytes:
+            interop._CHUNK_BYTES = chunk_bytes
         distributed.initialize(f"127.0.0.1:{port}", rank, world)
         model, tx, fresh, toks, labels = _tiny(rank, qlora)
         want = _run(_in_jit_step(model, tx, **kw), fresh(), toks, labels)
 
-        def calls() -> float:
-            return sum(telemetry.metrics()["tpunet_bridge_calls_total"].values())
+        def count(family: str) -> float:
+            return sum(telemetry.metrics()[family].values())
 
-        before = calls()
+        before = count("tpunet_bridge_calls_total")
         got = _run(make_train_step(model, tx, cross_host=True, **kw), fresh(),
                    toks, labels)
         # the boundary is taken whatever the oracle's bridge was
-        assert calls() - before == STEPS
+        assert count("tpunet_bridge_calls_total") - before == STEPS
+        if chunk_bytes and not qlora:  # LoRA's gradient is its adapters' alone
+            assert count("tpunet_bridge_chunks_total") == STEPS * len(
+                interop.boundary_chunks(
+                    N_GRAD, 2 if kw.get("grad_compression") else 4, world))
+        # two ranks' sum is one addition an element however the vector is cut
         _assert_bitwise(got, want)
         if qlora:
             assert any(leaf.dtype == np.int8 for leaf in jax.tree.leaves(got[0].params))
@@ -145,9 +158,39 @@ def _parity_worker(rank: int, world: int, port: int, q, case: str) -> None:
         q.put((rank, f"FAIL: {type(e).__name__}: {e}\n{traceback.format_exc()[-800:]}"))
 
 
+@pytest.mark.parametrize("chunk_bytes", [None, CHUNKED], ids=["whole", "chunked"])
 @pytest.mark.parametrize("case", list(CASES))
-def test_three_steps_bit_identical_to_the_in_jit_path(case):
-    run_spawn_workers(_parity_worker, 2, extra_args=(case,))
+def test_three_steps_bit_identical_to_the_in_jit_path(case, chunk_bytes):
+    run_spawn_workers(_parity_worker, 2, extra_args=(case, chunk_bytes))
+
+
+def _world4_worker(rank: int, world: int, port: int, q) -> None:
+    try:
+        from tpunet import distributed, interop
+        from tpunet.train import make_train_step
+
+        distributed.initialize(f"127.0.0.1:{port}", rank, world)
+        model, tx, fresh, toks, labels = _tiny(rank)
+        whole = _run(make_train_step(model, tx, cross_host=True), fresh(), toks, labels)
+        interop._CHUNK_BYTES = CHUNKED
+        cut = _run(make_train_step(model, tx, cross_host=True), fresh(), toks, labels)
+        # four terms an element, added in the order of the ring segment the
+        # element falls in: the last bit may differ, no more
+        for a, b in zip(jax.tree.leaves(cut[0].params), jax.tree.leaves(whole[0].params),
+                        strict=True):
+            assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b)
+        np.testing.assert_allclose(cut[1], whole[1], rtol=1e-6)
+        assert not np.array_equal(cut[1][0], cut[1][-1])
+        distributed.finalize()
+        q.put((rank, "OK"))
+    except Exception as e:  # noqa: BLE001
+        import traceback
+
+        q.put((rank, f"FAIL: {type(e).__name__}: {e}\n{traceback.format_exc()[-800:]}"))
+
+
+def test_four_ranks_chunked_equal_whole_to_the_last_bits():
+    run_spawn_workers(_world4_worker, 4)
 
 
 # -- one rank, in this process ---------------------------------------------------
@@ -175,7 +218,66 @@ def _n_grad(state) -> int:
     return sum(x.size for x in jax.tree.leaves(state.params))
 
 
-def test_lowered_and_compiled_is_callable_and_holds_both_programs(world_of_one):
+@pytest.fixture(params=[None, CHUNKED], ids=["whole", "chunked"])
+def chunk_bytes(request, monkeypatch):
+    """The vector whole (None: the shipped chunk size, far above the tiny
+    model) and cut into chunks of CHUNKED bytes."""
+    from tpunet import interop
+
+    if request.param:
+        monkeypatch.setattr(interop, "_CHUNK_BYTES", request.param)
+    return request.param
+
+
+def test_chunk_sizes_from_the_vectors_bytes_and_the_world():
+    from tpunet import interop
+    from tpunet.interop import boundary_chunks
+
+    per = interop._CHUNK_BYTES // 4
+    assert boundary_chunks(N_GRAD, 4, 2) == (N_GRAD,)  # under one chunk: whole
+    assert boundary_chunks(0, 4, 2) == (0,)
+    assert boundary_chunks(per, 4, 4) == (per,)
+    assert boundary_chunks(2 * per + 5, 4, 4) == (per, per, 5)  # a ragged last one
+    vgg = boundary_chunks(138_357_544, 4, 4)
+    assert sum(vgg) == 138_357_544 and set(vgg[:-1]) == {per} and 0 < vgg[-1] < per
+    assert boundary_chunks(138_357_544, 2, 2)[0] == 2 * per  # bytes, not elements
+    # every rank's share of a whole chunk is a whole number of 64-byte lines
+    for world in (2, 3, 4, 5, 8):
+        for itemsize in (2, 4):
+            first = boundary_chunks(1 << 30, itemsize, world)[0]
+            assert first * itemsize % (64 * world) == 0
+            assert first * itemsize <= interop._CHUNK_BYTES
+
+
+def test_the_programs_hand_over_a_tuple_of_chunks(world_of_one, chunk_bytes):
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    state = fresh()
+    assert _n_grad(state) == N_GRAD
+    step = make_train_step(model, tx, cross_host=True, donate=False)
+    loss, chunks = step._grad(state, toks, labels, jax.random.PRNGKey(0))
+    want = (1024, 1024, 1024, 48) if chunk_bytes else (N_GRAD,)
+    assert isinstance(chunks, tuple) and tuple(c.shape for c in chunks) == tuple(
+        (n,) for n in want) and len(want) == (K_CHUNKED if chunk_bytes else 1)
+    step(state, toks, labels, jax.random.PRNGKey(0))
+    # every chunk's slice of the kept buffer starts on a 64-byte line
+    assert step._out.shape == (N_GRAD,) and step._out.ctypes.data % 64 == 0
+    # The way back: on the CPU backend device_put is an alias, so the vector
+    # comes back whole; an accelerator gets the reduced chunks one by one,
+    # and the apply program joins them to the same vector.
+    from tpunet.interop import host_all_reduce, host_buffer_like, reduced_like
+
+    back = host_all_reduce(chunks, host_buffer_like(step._out))
+    assert [b.shape for b in back] == [(N_GRAD,)]
+    assert [(s.shape, s.dtype) for s in reduced_like(
+        chunks, [c.sharding for c in chunks])] == [((N_GRAD,), chunks[0].dtype)]
+    _assert_bitwise((step._apply(state, tuple(chunks)), []),
+                    (step._apply(state, back), []))
+
+
+def test_lowered_and_compiled_is_callable_and_holds_both_programs(world_of_one,
+                                                                  chunk_bytes):
     from tpunet.train import make_train_step
 
     model, tx, fresh, toks, labels = _tiny(0)
@@ -199,7 +301,7 @@ def test_lowered_and_compiled_is_callable_and_holds_both_programs(world_of_one):
 @pytest.mark.parametrize("grad_compression,itemsize", [(None, 4), ("bf16", 2)],
                          ids=["f32", "bf16"])
 def test_one_bridge_call_and_the_vectors_bytes_a_step(world_of_one, grad_compression,
-                                                      itemsize):
+                                                      itemsize, chunk_bytes):
     from tpunet import telemetry
     from tpunet.train import make_train_step
 
@@ -212,9 +314,42 @@ def test_one_bridge_call_and_the_vectors_bytes_a_step(world_of_one, grad_compres
     _run(step, state, toks, labels)
     assert _bridge_counts() == {("calls", "all_reduce"): STEPS,
                                 ("bytes", "all_reduce"): STEPS * itemsize * n}
+    # K chunks a step; bf16 halves the bytes. In flight at one time: the
+    # chunk waited for and the copies that run ahead of it, 2 or more if K > 1
+    from tpunet import interop
+
+    k = -(-itemsize * n // chunk_bytes) if chunk_bytes else 1
+    assert k == (1 if not chunk_bytes else K_CHUNKED if itemsize == 4 else 2)
+    m = telemetry.metrics()
+    assert sum(m["tpunet_bridge_chunks_total"].values()) == STEPS * k
+    deepest = sum(m["tpunet_bridge_chunks_in_flight_max"].values())
+    assert deepest == min(k, interop._COPIES_AHEAD + 1) and (k == 1 or deepest >= 2)
 
 
-def test_bridge_spans_nest_once_a_step_under_fit(world_of_one, tmp_path):
+@pytest.mark.parametrize("ahead,deepest", [(0, 1), (1, 2), (2, 3), (8, K_CHUNKED)])
+def test_copies_out_run_a_bounded_number_of_chunks_ahead(world_of_one, monkeypatch,
+                                                         ahead, deepest):
+    """Chunk k and the `ahead` chunks after it are on their way out while
+    chunk k is waited for and reduced: that many and no more, whatever K is;
+    the result does not depend on it."""
+    from tpunet import interop, telemetry
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    whole = _run(make_train_step(model, tx, cross_host=True, donate=False),
+                 fresh(), toks, labels)
+    monkeypatch.setattr(interop, "_CHUNK_BYTES", CHUNKED)
+    monkeypatch.setattr(interop, "_COPIES_AHEAD", ahead)
+    telemetry.reset()
+    cut = _run(make_train_step(model, tx, cross_host=True, donate=False),
+               fresh(), toks, labels)
+    _assert_bitwise(cut, whole)
+    m = telemetry.metrics()
+    assert sum(m["tpunet_bridge_chunks_total"].values()) == STEPS * K_CHUNKED
+    assert sum(m["tpunet_bridge_chunks_in_flight_max"].values()) == deepest
+
+
+def test_bridge_spans_nest_once_a_step_under_fit(world_of_one, tmp_path, chunk_bytes):
     from tpunet import telemetry
     from tpunet.train import fit, make_train_step
 
@@ -237,7 +372,13 @@ def test_bridge_spans_nest_once_a_step_under_fit(world_of_one, tmp_path):
         assert b["args"]["kind"] == "all_reduce" and b["args"]["nbytes"] == 4 * n
         kids = sorted((e for e in events if e["args"].get("parent") == "dcn.bridge"
                        and e["args"]["seq"] == b["args"]["seq"]), key=lambda e: e["ts"])
-        assert [k["name"] for k in kids] == BRIDGE_CHILDREN
+        # the three stages once a chunk, chunk after chunk on this thread
+        n_chunks = K_CHUNKED if chunk_bytes else 1
+        assert [k["name"] for k in kids] == BRIDGE_CHILDREN * n_chunks
+        assert [k["args"]["chunk"] for k in kids] == [
+            c for c in range(n_chunks) for _ in BRIDGE_CHILDREN]
+        assert sum(k["args"]["nbytes"] for k in kids
+                   if k["name"] == "dcn.bridge.collective") == 4 * n
         assert all(b["ts"] <= k["ts"] and k["ts"] + k["dur"] <= b["ts"] + b["dur"]
                    and k["tid"] == b["tid"] for k in kids)
     assert len({b["args"]["seq"] for b in bridges}) == STEPS
@@ -272,12 +413,15 @@ def test_the_other_steps_are_still_one_jitted_program(world_of_one, kw):
                                           jax.random.PRNGKey(0)).as_text()
 
 
-def test_the_result_buffer_is_kept_and_free_again_when_a_call_returns(world_of_one):
+def test_the_result_buffer_is_kept_and_free_again_when_a_call_returns(world_of_one,
+                                                                      chunk_bytes):
     """The ring reduces into ONE buffer a step object, 64-byte aligned (the
     CPU backend's device_put then aliases it). Calls that no data flow
     chains (the same state twice) must not see each other's bytes: a call
     returns only when its apply program has read the buffer."""
     from tpunet.train import make_train_step
+
+    from tpunet import distributed
 
     model, tx, fresh, toks, labels = _tiny(0)
     step = make_train_step(model, tx, cross_host=True, donate=False)
@@ -286,12 +430,27 @@ def test_the_result_buffer_is_kept_and_free_again_when_a_call_returns(world_of_o
     first = jax.tree.map(np.asarray, step(state, toks, labels, key))
     buf = step._out
     assert buf.ctypes.data % 64 == 0 and buf.nbytes == 4 * _n_grad(state)
+    # every ring of a later step is handed a part of that buffer, each part
+    # once a step, and only when no apply program is running: the step
+    # before has returned, so its state is ready
+    comm = distributed.global_communicator()
+    ring, handed, last = comm.all_reduce, [], {}
+
+    def watched(arr, op="sum", inplace=False, out=None):
+        assert np.shares_memory(out, buf) and out.ctypes.data % 64 == 0
+        assert "state" not in last or last["state"].step.is_ready()
+        handed.append(out.ctypes.data)
+        return ring(arr, op, inplace, out)
+
+    comm.all_reduce = watched
     for _ in range(3):
-        step(state, other, labels, key)  # another gradient through the same buffer
+        last["state"], _ = step(state, other, labels, key)  # another gradient, same buffer
         again = jax.tree.map(np.asarray, step(state, toks, labels, key))
         assert step._out is buf
         for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(first), strict=True):
             assert a.tobytes() == b.tobytes()
+    k = K_CHUNKED if chunk_bytes else 1
+    assert len(handed) == 6 * k and len(set(handed)) == k
 
 
 def test_all_reduce_into_a_callers_buffer(world_of_one):

@@ -344,19 +344,21 @@ def test_trace_span_entry_validates_and_says_when_it_is_off(tmp_path):
     from tpunet import _native, telemetry
 
     lib = _native.load()
-    args = (10, 5, 1, 0, None, None, -1)
+    args = (10, 5, 1, 0, None, None, -1, -1)
     assert lib.tpunet_c_trace_span(b"dcn.bridge", *args) == 0  # tracing off
     for bad in (b"", b'a"b', b"a b", b"x" * 65):
         assert lib.tpunet_c_trace_span(bad, *args) < 0
-    assert lib.tpunet_c_trace_span(b"ok", 10, 5, 1, 0, b'p"', None, -1) < 0
+    assert lib.tpunet_c_trace_span(b"ok", 10, 5, 1, 0, b'p"', None, -1, -1) < 0
     assert lib.tpunet_c_bridge_call(8, 1) < 0 and lib.tpunet_c_bridge_call(-1, 1) < 0
+    assert lib.tpunet_c_bridge_chunks(8, 1, 1) < 0 and lib.tpunet_c_bridge_chunks(-1, 1, 1) < 0
     with telemetry.profile(str(tmp_path)):
         assert lib.tpunet_c_trace_span(b"dcn.bridge", *args) == 1
         assert lib.tpunet_c_trace_span(b"a.b:c-d_E9", 10, 5, 1, 0, b"dcn.bridge",
-                                       b"all_reduce", 3) == 1
-    last = _native_events(str(tmp_path))[-1]
+                                       b"all_reduce", 3, 2) == 1
+    first, last = _native_events(str(tmp_path))[-2:]
+    assert first["args"] == {"seq": 1, "nbytes": 0}  # no step, no chunk
     assert last["args"] == {"seq": 1, "nbytes": 0, "parent": "dcn.bridge",
-                            "kind": "all_reduce", "step": 3}
+                            "kind": "all_reduce", "step": 3, "chunk": 2}
 
 
 def test_lints_know_the_new_entries():
@@ -367,6 +369,9 @@ def test_lints_know_the_new_entries():
 
     root = Path(ROOT)
     assert check_c_abi(root) == [] and check_metric_registry(root) == []
-    assert {"tpunet_bridge_calls_total", "tpunet_bridge_bytes_total"} <= registry_families(root)
+    assert {"tpunet_bridge_calls_total", "tpunet_bridge_bytes_total",
+            "tpunet_bridge_chunks_total",
+            "tpunet_bridge_chunks_in_flight_max"} <= registry_families(root)
     header = (root / "cpp" / "include" / "tpunet" / "c_api.h").read_text()
-    assert "tpunet_c_trace_span(" in header and "tpunet_c_bridge_call(" in header
+    assert all(f"{name}(" in header for name in (
+        "tpunet_c_trace_span", "tpunet_c_bridge_call", "tpunet_c_bridge_chunks"))

@@ -49,6 +49,7 @@ NAMING_EXCEPTIONS = {
     "tpunet_lane_weight": "dimensionless stripe weight (1..16) per lane in the WRR scheduler",
     "tpunet_world_size": "dimensionless rank count of the live communicator (churn gauge)",
     "tpunet_weight_version": "dimensionless checkpoint version stamp (hot-swap gauge)",
+    "tpunet_bridge_chunks_in_flight_max": "dimensionless high-water count of a boundary exchange's chunks in flight",
 }
 
 _SNAKE = re.compile(r"^tpunet_[a-z0-9]+(?:_[a-z0-9]+)*$")

@@ -223,10 +223,12 @@ def load(lib_file: Path | None = None) -> ctypes.CDLL:
     lib.tpunet_c_trace_set_dir.restype = i32
     lib.tpunet_c_trace_span.argtypes = [
         ctypes.c_char_p, u64, u64, u64, u64, ctypes.c_char_p,
-        ctypes.c_char_p, ctypes.c_int64]
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64]
     lib.tpunet_c_trace_span.restype = i32
     lib.tpunet_c_bridge_call.argtypes = [i32, u64]
     lib.tpunet_c_bridge_call.restype = i32
+    lib.tpunet_c_bridge_chunks.argtypes = [i32, u64, u64]
+    lib.tpunet_c_bridge_chunks.restype = i32
     lib.tpunet_c_metrics_port.argtypes = []
     lib.tpunet_c_metrics_port.restype = i32
     lib.tpunet_c_serve_observe.argtypes = [i32, u64]

@@ -4,7 +4,8 @@ and one between them.
 A collective that has a program boundary to stand at does not enter a
 program at all: `host_all_reduce` takes a device array to the host by the
 runtime's own array transfer, reduces it over the ring and puts the result
-back, from the calling thread. The trainer's flat gradient exchange
+back, from the calling thread, in chunks that are in different stages at
+one time. The trainer's flat gradient exchange
 (make_train_step(cross_host=True): between backward and the optimizer) is
 that case and uses nothing else of this module. The in-jit seam below is for
 collectives in the MIDDLE of a program — ZeRO's reduce-scatter and
@@ -172,15 +173,22 @@ def _bridge(kind: str, collective, *, stage_in=_host_operand, stage_out=None):
         nbytes = int(x.nbytes)
         with telemetry.span("dcn.bridge", kind=kind, nbytes=nbytes):
             telemetry.bridge_call(kind, nbytes)
-            with telemetry.span("dcn.bridge.stage_in", nbytes=nbytes):
-                comm = _comm()
-                staged = stage_in(comm, x)
-            with telemetry.span("dcn.bridge.collective", nbytes=nbytes):
-                out = collective(comm, staged)
-            with telemetry.span("dcn.bridge.stage_out"):
-                return out if stage_out is None else stage_out(comm, out)
+            return _stages(x, stage_in, collective, stage_out)
 
     return cb
+
+
+def _stages(x, stage_in, collective, stage_out, **attrs):
+    """One operand through the bridge's three child spans (`attrs`: further
+    span attributes, the boundary exchange's `chunk`)."""
+    nbytes = int(x.nbytes)
+    with telemetry.span("dcn.bridge.stage_in", nbytes=nbytes, **attrs):
+        comm = _comm()
+        staged = stage_in(comm, x)
+    with telemetry.span("dcn.bridge.collective", nbytes=nbytes, **attrs):
+        out = collective(comm, staged)
+    with telemetry.span("dcn.bridge.stage_out", **attrs):
+        return out if stage_out is None else stage_out(comm, out)
 
 
 # -- all-reduce -------------------------------------------------------------
@@ -252,25 +260,109 @@ def host_buffer_like(x) -> np.ndarray:
     return raw[start:start + nbytes].view(x.dtype).reshape(x.shape)
 
 
-def host_all_reduce(x: jax.Array, op: str = "sum",
-                    out: np.ndarray | None = None) -> jax.Array:
-    """AllReduce the device array `x` across processes BETWEEN two device
-    programs, from the calling thread: to the host by the runtime's own
-    array transfer (np.asarray: a copy off an accelerator, a view of a CPU
-    array), one Communicator.all_reduce, and back by jax.device_put to where
-    `x` was. For a collective that has a program boundary to stand at (the
-    trainer's gradient exchange, between backward and the optimizer): no
-    host-transfer operation in either program, and both stay cacheable.
-    Same spans and counters as the in-jit bridge, whose helper this calls.
+# The boundary exchange's two numbers, from the sweeps on the v5e host in
+# both VGG16 cells (world 2 over TCP, world 4 over shared memory; PERF.md
+# section 6, PR 29, has every row, the sizes and depths that lost among
+# them): bytes of one chunk, and how many chunks' copies to the host run
+# ahead of the chunk the exchange waits for.
+_CHUNK_BYTES = 32 << 20
+_COPIES_AHEAD = 4
 
-    `out` (from host_buffer_like): the ring's result buffer, kept by the
-    caller across calls. jax.device_put returns before an accelerator has
-    the bytes, and on the CPU backend the array that comes back IS `out`: so
-    the caller may pass `out` again only once whatever consumed the last
-    result has finished."""
-    return _bridge(
-        "all_reduce", lambda c, a: c.all_reduce(a, op, out=out),
-        stage_out=lambda c, res: jax.device_put(res, x.sharding))(x)
+
+def boundary_chunks(size: int, itemsize: int, world: int) -> tuple[int, ...]:
+    """Element counts of the contiguous chunks in which a flat vector of
+    `size` elements crosses the program boundary: whole chunks of the
+    sweep's bytes, rounded to a count every rank of `world` gets an equal,
+    64-byte-aligned share of, and what is left as the last one. A vector
+    under one chunk is one chunk. The grad program cuts its output by this,
+    so the counts are static shapes of both programs."""
+    step = 64 * world // itemsize
+    per = max(_CHUNK_BYTES // itemsize // step, 1) * step
+    whole, rest = divmod(size, per)
+    return (per,) * whole + ((rest,) if rest or not whole else ())
+
+
+def reduced_like(chunks, shardings) -> tuple:
+    """What host_all_reduce hands back for a sequence of chunks placed by
+    `shardings`, as ShapeDtypeStructs: the reduced chunks, each where its
+    chunk was; or, where jax.device_put is an alias of the host's buffer
+    (every device is the CPU backend's: there is no way back to overlap
+    with anything, and joining K operands costs a CPU program a pass over
+    the vector), the whole reduced vector as ONE array."""
+    if len(chunks) > 1 and all(d.platform == "cpu" for s in shardings
+                               for d in s.device_set):
+        return (jax.ShapeDtypeStruct((sum(int(c.size) for c in chunks),),
+                                     chunks[0].dtype, sharding=shardings[0]),)
+    return tuple(jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=s)
+                 for c, s in zip(chunks, shardings))
+
+
+def host_all_reduce(chunks, out: np.ndarray, op: str = "sum") -> tuple:
+    """AllReduce a flat device vector across processes BETWEEN two device
+    programs, from the calling thread. `chunks` is the sequence of
+    contiguous chunks a program cut the vector into (boundary_chunks; one
+    chunk for a small vector); the result is the tuple reduced_like
+    describes. For a collective that has a program boundary to stand at
+    (the trainer's gradient exchange, between backward and the optimizer):
+    no host-transfer operation in either program, and both stay cacheable.
+
+    Three stages a chunk, and the chunks in different stages at one time:
+      out   chunk k's copy to the host and those of the _COPIES_AHEAD chunks
+            after it are running (copy_to_host_async: the runtime's own
+            array transfer, behind the program that produces the chunk)
+            when np.asarray waits for chunk k alone (a view of a CPU
+            array); a bounded number, because the runtime serves the
+            copies it is given side by side, and all K at once land
+            together, late;
+      ring  one Communicator.all_reduce of chunk k into its slice of `out`,
+            on this thread, while the chunks after k are still landing;
+      back  jax.device_put of that slice to where the chunk was, which
+            returns before an accelerator has the bytes: chunk k goes back
+            while chunk k+1 is in the ring. (On the CPU backend, where the
+            put is an alias: one put of the whole buffer after the last
+            ring.)
+    So the exchange tends to its longest stage plus one chunk's way through
+    the other two, not the three stages' sum. One `dcn.bridge` span and one
+    count of tpunet_bridge_{calls,bytes}_total an exchange, as for the
+    in-jit bridge; its three child spans once a chunk, with `chunk`;
+    tpunet_bridge_chunks_total and ..._in_flight_max say how many chunks
+    crossed and how many were between the start of their copy out and the
+    return of their device_put at one time.
+
+    `out` (from host_buffer_like, flat, of the whole vector): the ring's
+    result buffer, kept by the caller across calls. jax.device_put returns
+    before an accelerator has the bytes, and on the CPU backend the array
+    that comes back IS `out`: so the caller may pass `out` again only once
+    whatever consumed the last result has finished."""
+    chunks = tuple(chunks)
+    nbytes = sum(int(c.nbytes) for c in chunks)
+    pieces = np.split(out, np.cumsum([int(c.size) for c in chunks])[:-1])
+    like = reduced_like(chunks, [c.sharding for c in chunks])
+    back, deepest = [], 0
+    with telemetry.span("dcn.bridge", kind="all_reduce", nbytes=nbytes):
+        telemetry.bridge_call("all_reduce", nbytes)
+        started = 0
+        for k, (c, piece) in enumerate(zip(chunks, pieces)):
+            # chunk k and the _COPIES_AHEAD after it are on their way out
+            # while chunk k is waited for and reduced
+            while started < min(k + 1 + _COPIES_AHEAD, len(chunks)):
+                chunks[started].copy_to_host_async()
+                started += 1
+            deepest = max(deepest, started - k)
+
+            # _stages calls both before it returns: they see this k
+            def ring(comm, staged):
+                return comm.all_reduce(staged, op, out=piece)
+
+            def put(comm, reduced):
+                if len(like) == len(chunks):
+                    back.append(jax.device_put(reduced, c.sharding))
+                elif k == len(chunks) - 1:  # an alias: the vector whole
+                    back.append(jax.device_put(out, like[0].sharding))
+
+            _stages(c, _host_operand, ring, put, chunk=k)
+        telemetry.bridge_chunks("all_reduce", len(chunks), deepest)
+    return tuple(back)
 
 
 # -- nonblocking all-reduce (gradient-bucket overlap) -----------------------

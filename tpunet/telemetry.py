@@ -34,6 +34,7 @@ Chrome-trace spans for every request plus collective phase spans tagged
   swap_event()        -> count one weight-swap event by kind
   weight_version()    -> set the serving checkpoint-version gauge
   bridge_call()       -> count one DCN-bridge host callback and its bytes
+  bridge_chunks()     -> count one boundary exchange's chunks and their depth
   flightrec_dump()    -> write this rank's flight-recorder ring to disk
   flightrec_stats()   -> (events_recorded, ring_capacity) of the recorder
 
@@ -380,8 +381,8 @@ class span:
       spans, on their clock (CLOCK_MONOTONIC), with args ``seq`` (a
       per-process count of ROOT spans; children carry their root's),
       ``parent`` (the enclosing span's name), ``nbytes`` and, when given,
-      ``kind`` and ``step`` (from ``step_num``). Other ``args`` reach only
-      the profiler. Never ``comm_id``/``coll_seq``: those mark collective
+      ``kind``, ``step`` (from ``step_num``) and ``chunk``. Other ``args``
+      reach only the profiler. Never ``comm_id``/``coll_seq``: those mark collective
       phases for merge_traces() and the ring's readers.
     - the JAX profiler, always, but only in a process that has imported
       jax already: ``jax.profiler.TraceAnnotation("tpunet:" + name,
@@ -438,7 +439,7 @@ class span:
             int(self.args.get("nbytes", 0)),
             self._outer.name.encode() if self._outer is not None else None,
             str(kind).encode() if kind is not None else None,
-            int(self.args.get("step_num", -1)))
+            int(self.args.get("step_num", -1)), int(self.args.get("chunk", -1)))
         if rc == 0:  # the native tracer is off for this rank: stop asking
             _native_spans = False
         elif rc < 0 and exc_type is None:
@@ -461,6 +462,15 @@ def bridge_call(kind: str, nbytes: int) -> None:
     lib = _native.load()
     _native.check(lib.tpunet_c_bridge_call(_BRIDGE_KINDS[kind], max(0, int(nbytes))),
                   "bridge_call")
+
+
+def bridge_chunks(kind: str, chunks: int, in_flight: int) -> None:
+    """Count the chunks of one boundary exchange (tpunet/interop.py's
+    host_all_reduce) into ``tpunet_bridge_chunks_total{kind=...}`` and keep
+    the most that were in flight at one time in
+    ``tpunet_bridge_chunks_in_flight_max{kind=...}``."""
+    _native.check(_native.load().tpunet_c_bridge_chunks(
+        _BRIDGE_KINDS[kind], int(chunks), int(in_flight)), "bridge_chunks")
 
 
 def _coll_tags(events: list[dict]) -> dict[tuple, int]:

@@ -15,7 +15,9 @@ Design (TPU-first):
   * That all-reduce sits between backward and the optimizer, which is a
     program boundary: the flat cross-host step is TWO device programs (grad,
     apply) with the ring between them on the host, the vector crossing by
-    the runtime's ordinary array transfers (`_BoundaryStep`). Inside one
+    the runtime's ordinary array transfers (`_BoundaryStep`), in chunks
+    large enough to stripe (tens of MiB) whose ways out, rings and ways
+    back overlap (tpunet.interop.host_all_reduce). Inside one
     program it would cross as an `io_callback` host transfer, which on the
     v5e cost 2.7 s of a 3.15 s VGG16 step around a 0.29 s ring (PERF.md,
     PR 24 and 25). Collectives in the MIDDLE of a program (ZeRO's
@@ -338,17 +340,24 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     `.lower(*args).compile()` compiles both ahead of time, `.as_text()` of
     the result is both programs' text):
       1. grad: forward and backward, the gradient raveled into one flat
-         vector; returns (loss, flat). Nothing is donated: the apply
-         program still needs `state`.
-      2. on the host, tpunet.interop.host_all_reduce(flat): device to host,
-         ONE Communicator.all_reduce(sum) over the process-default
-         communicator as it is at that call (elastic recovery re-points it
-         under compiled programs) into a result buffer the step object
-         keeps across steps, and back to the device.
-      3. apply: the mean (the sum over `world`, on the device, in the
-         vector's dtype), unraveled, through `tx` into the parameters;
-         `state` donated when `donate`. The call returns when this program
-         has finished (the kept buffer is then free again).
+         vector and cut into contiguous chunks (static shapes, from the
+         vector's bytes and the world size: interop.boundary_chunks; a
+         vector under one chunk stays whole); returns (loss, chunks).
+         Nothing is donated: the apply program still needs `state`.
+      2. on the host, tpunet.interop.host_all_reduce(chunks): the chunks'
+         copies to the host run a few ahead, a
+         Communicator.all_reduce(sum) a chunk over the
+         process-default communicator as it is at that call (elastic
+         recovery re-points it under compiled programs) into a result
+         buffer the step object keeps across steps, and back to the
+         device: chunk k on its way back while chunk k+1 is in the ring
+         and the ones after it still on their way out.
+      3. apply: the reduced chunks joined (on the CPU backend the vector
+         comes back whole: interop.reduced_like), the mean (the sum over
+         `world`, on the device, in the vector's dtype), unraveled, through
+         `tx` into the parameters; `state` donated when `donate`. The call
+         returns when this program has finished (the kept buffer is then
+         free again).
     The all-reduce sits at the boundary because it can: a collective between
     backward and the optimizer needs nothing of either program while it
     runs, and outside a program its bytes move by the runtime's plain array
@@ -378,6 +387,7 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     if cross_host:
         # Import here so single-host training never touches the transport.
         from tpunet import distributed
+        from tpunet.interop import boundary_chunks
 
         world = distributed.world_size()  # raises early if initialize() was skipped
         # One cast path: when the wire already compresses to bf16, ship f32
@@ -415,7 +425,8 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
                                 if g.dtype != jax.dtypes.float0])
         if grad_compression == "bf16":
             flat = flat.astype(jnp.bfloat16)
-        return loss, flat
+        sizes = boundary_chunks(flat.size, flat.dtype.itemsize, world)
+        return loss, tuple(jnp.split(flat, np.cumsum(sizes)[:-1]))
 
     def apply_program(state: TrainState, reduced):
         # A gradient has its parameter's shape and dtype, and an integer
@@ -425,6 +436,7 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
         f0 = [not jnp.issubdtype(p.dtype, jnp.inexact) for p in leaves]
         like, unravel = ravel_pytree(
             [p for p, skip in zip(leaves, f0) if not skip])
+        reduced = jnp.concatenate(reduced)
         # the mean in the wire's dtype, as dcn_pmean forms it
         mean = reduced / jnp.asarray(world, reduced.dtype)
         it = iter(unravel(mean.astype(like.dtype)))
@@ -433,8 +445,8 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
                       for p, skip in zip(leaves, f0)])
         return updated(state, grads)
 
-    # The reduced vector is not donated: no output has its shape, so XLA
-    # could not reuse it, and it is dropped when the call returns anyway.
+    # The reduced chunks are not donated: no output has their shape, so XLA
+    # could not reuse them, and they are dropped when the call returns anyway.
     return _BoundaryStep(jax.jit(grad_program),
                          jax.jit(apply_program, donate_argnums=donated))
 
@@ -447,12 +459,15 @@ class _BoundaryStep:
     `.as_text()` is both programs' text.
 
     The ring's result buffer lives here, across steps (`_out`, made at the
-    first call): a new one a step costs its page faults, 0.6 s for VGG16's
-    553 MB on the v5e's host, on the chip rank and on a CPU rank alike. It
+    first call, the whole vector's; every chunk's ring writes its own slice
+    of it): a new one a step costs its page faults, 0.6 s for VGG16's 553 MB
+    on the v5e's host, on the chip rank and on a CPU rank alike. Each slice
     is handed to jax.device_put, which returns before an accelerator has
     the bytes and which on the CPU backend aliases it: so a call returns
-    only when its apply program has finished, and the next call's ring
-    finds the buffer free whatever state that call is given."""
+    only when its apply program, which reads all the slices, has finished,
+    and the next call's first ring finds every slice free whatever state
+    that call is given. Within a call a slice is written once, before its
+    device_put."""
 
     def __init__(self, grad, apply):
         self._grad, self._apply = grad, apply
@@ -461,10 +476,11 @@ class _BoundaryStep:
     def __call__(self, state, images, labels, dropout_rng):
         from tpunet.interop import host_all_reduce, host_buffer_like
 
-        loss, flat = self._grad(state, images, labels, dropout_rng)
+        loss, chunks = self._grad(state, images, labels, dropout_rng)
         if self._out is None:
-            self._out = host_buffer_like(flat)
-        state = self._apply(state, host_all_reduce(flat, out=self._out))
+            self._out = host_buffer_like(jax.ShapeDtypeStruct(
+                (sum(c.size for c in chunks),), chunks[0].dtype))
+        state = self._apply(state, host_all_reduce(chunks, self._out))
         jax.block_until_ready(state.step)
         return state, loss
 
@@ -478,18 +494,18 @@ class _BoundaryStep:
 
 
 class _LoweredBoundaryStep:
-    """The apply program's operand is placed where the grad program leaves
-    its vector, which only the compiled grad program says: so the second
+    """The apply program's operands are placed where the grad program leaves
+    its chunks, which only the compiled grad program says: so the second
     half is lowered here, in compile()."""
 
     def __init__(self, grad_lowered, apply, state):
         self._grad, self._apply, self._state = grad_lowered, apply, state
 
     def compile(self) -> _BoundaryStep:
+        from tpunet.interop import reduced_like
+
         grad = self._grad.compile()
-        flat = self._grad.out_info[1]
-        reduced = jax.ShapeDtypeStruct(flat.shape, flat.dtype,
-                                       sharding=grad.output_shardings[1])
+        reduced = reduced_like(self._grad.out_info[1], grad.output_shardings[1])
         return _BoundaryStep(grad, self._apply.lower(self._state, reduced).compile())
 
 
