@@ -49,8 +49,11 @@ def as_on_chip(monkeypatch):
     import tpunet.interop  # noqa: F401
     import tpunet.ops  # noqa: F401
 
-    monkeypatch.setattr(sys.modules["tpunet.ops.flash_attention"],
-                        "_auto_interpret", lambda: False)
+    import tpunet.ops.grouped_matmul  # noqa: F401
+
+    for kernels in ("flash_attention", "grouped_matmul"):
+        monkeypatch.setattr(sys.modules[f"tpunet.ops.{kernels}"],
+                            "_auto_interpret", lambda: False)
     monkeypatch.setattr(sys.modules["tpunet.interop"],
                         "_ffi_available", lambda: False)
 
@@ -113,6 +116,90 @@ def test_flash_mistral_b2_s8192_window4096(one_chip, direction):
         fn = jax.grad(fn, argnums=(0, 1, 2))
     text = jax.jit(fn).lower(*_qkv(one_chip, 2, 8192, 8, heads=32)).compile().as_text()
     assert text.count(KERNEL) == (1 if direction == "fwd" else 3)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window4096"])
+def test_flash_smallthinker_b2_s8192_group7(one_chip, direction, window):
+    """The SmallThinker cell's two kinds of layer in one step: 28 query
+    heads over 4 key-value heads (a group of 7), causal with and without
+    the window; the plan gives each call its own visit count."""
+    from tpunet.ops.flash_attention import _plan
+
+    assert _plan(8192, 8192, jnp.bfloat16, True, window) == (
+        512, 512, 136 if window is None else 108)
+    fn = _flash_loss(window=window)
+    if direction == "bwd":
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*_qkv(one_chip, 2, 8192, 4, heads=28)).compile().as_text()
+    assert text.count(KERNEL) == (1 if direction == "fwd" else 3)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("k,n", [(2560, 768), (768, 2560)], ids=["gate_up", "down"])
+def test_grouped_matmul_smallthinker_rows98304_groups16(one_chip, direction, k, n):
+    """The SmallThinker cell's grouped products: 16 held experts, a buffer
+    for the worst case of 16,384 tokens x 6 choices (98,304 rows and a tile
+    an expert), bf16 with float32 accumulation, the matrices float32. A
+    group's whole matrix sits in VMEM. The kernels carry their own names
+    into the HLO, which the benchmark's readers match."""
+    from tpunet.ops import grouped_matmul as gm
+
+    tile_m = gm.tile_rows(98304, jnp.bfloat16)
+    rows = gm.buffer_rows(98304, 16, tile_m)
+    assert (tile_m, rows) == (512, 106496)
+    assert gm._plan(tile_m, k, n, 2) == (k, n)
+
+    def fn(x, w, tile_group, n_tiles):
+        out = gm.grouped_matmul(x, w, tile_group, n_tiles, tile_m=tile_m,
+                                interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    if direction == "bwd":
+        fn = jax.grad(fn, argnums=(0, 1))
+    shapes = [((rows, k), jnp.bfloat16), ((16, k, n), jnp.float32),
+              ((rows // tile_m,), jnp.int32), ((1,), jnp.int32)]
+    text = jax.jit(fn).lower(*(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                               for s, d in shapes)).compile().as_text()
+    names = ["moe_gmm_fwd"] if direction == "fwd" else ["moe_gmm_dx", "moe_tgmm_dw"]
+    assert text.count(KERNEL) == len(names)
+    for name in names:
+        assert len([ln for ln in text.splitlines()
+                    if name in ln.split(" = ")[0] and KERNEL in ln]) == 1, name
+
+
+def test_smallthinker_train_step_has_its_kernels_and_fits(one_chip, as_on_chip):
+    """The benchmark's SmallThinker configuration through make_train_step
+    at the cell's b2 x s8192: a layer holds 4 flash and 12 grouped kernels
+    (forward, remat's recompute, backward), and weights, AdamW state and the
+    step's scratch fit one chip."""
+    import optax
+
+    from perfbench import harness
+    from perfbench.models import smallthinker
+    from tpunet.train import TrainState, make_train_step
+
+    cfg = harness.load("configs", "smallthinker-21b-a3b-ep4-l4")
+    layers = cfg["num_hidden_layers"]
+    model = smallthinker.build(cfg, {"remat": True})
+    tx = optax.adamw(3e-4)
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def state_of(t):
+        params = model.init(jax.random.PRNGKey(0), t)["params"]
+        return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+
+    state = jax.eval_shape(state_of, tokens)
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 656_529_920
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    compiled = make_train_step(model, tx).lower(
+        _on(one_chip, state), tokens, tokens, _on(one_chip, key)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == layers * 16
+    for name, count in (("moe_gmm_fwd", 6), ("moe_gmm_dx", 3), ("moe_tgmm_dw", 3)):
+        assert len([ln for ln in text.splitlines() if KERNEL in ln
+                    and name in ln.split(" = ")[0]]) == layers * count, name
+    assert _device_bytes(compiled) < HBM_BYTES
 
 
 def test_flash_s32768_is_refused_for_vmem(one_chip):

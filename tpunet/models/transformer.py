@@ -26,6 +26,7 @@ The reference repo has no model layer at all (SURVEY §2.3: TP/PP/SP/EP
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import flax.linen as nn
@@ -176,10 +177,12 @@ class LayerSpec:
     """What ONE block is built from: the one field of `Block` and of
     `SelfAttention`, each of which reads `self.spec.<name>` where it uses
     it. `Transformer.layer_specs()` is the only code that makes one, and it
-    fills every field but `head_dim` from the model's field of the same
-    name — so a new block-level field is a declaration here, one on
-    `Transformer` (which holds the default and the comment users read) and
-    its use. No defaults here: a spec never exists apart from a model."""
+    fills every field but `head_dim` (the model's, or d_model / n_heads)
+    and `rotary` (by the layer's place in `attn_pattern`) from the model's
+    field of the same name — so a new block-level field is a declaration
+    here, one on `Transformer` (which holds the default and the comment
+    users read) and its use. No defaults here: a spec never exists apart
+    from a model."""
 
     n_heads: int
     head_dim: int
@@ -208,6 +211,9 @@ class LayerSpec:
     rope_theta: float
     eva_window: int | None
     eva_chunk: int | None
+    rotary: bool
+    moe_impl: str
+    moe_held: tuple[int, int] | None
 
 
 class SelfAttention(nn.Module):
@@ -307,9 +313,10 @@ class SelfAttention(nn.Module):
             if filled:
                 idx = cidx.value
                 cap = ckey.value.shape[1]
-                step_pos = (idx[..., None] + jnp.arange(s)).astype(jnp.float32)
-                q = rotary_embed(q, spec.rope_theta, positions=step_pos)
-                k = rotary_embed(k, spec.rope_theta, positions=step_pos)
+                if spec.rotary:
+                    step_pos = (idx[..., None] + jnp.arange(s)).astype(jnp.float32)
+                    q = rotary_embed(q, spec.rope_theta, positions=step_pos)
+                    k = rotary_embed(k, spec.rope_theta, positions=step_pos)
                 rows = jnp.arange(b)[:, None]
                 if ring:
                     # A full-width ring never overflows: writes land at pos
@@ -463,8 +470,9 @@ class SelfAttention(nn.Module):
                 jnp.arange(s, dtype=jnp.float32),
                 spec.mesh.shape[spec.sp_axis], axis=0,
             )
-        q = rotary_embed(q, spec.rope_theta, pos_offset, positions)
-        k = rotary_embed(k, spec.rope_theta, pos_offset, positions)
+        if spec.rotary:
+            q = rotary_embed(q, spec.rope_theta, pos_offset, positions)
+            k = rotary_embed(k, spec.rope_theta, pos_offset, positions)
         if kv != h and spec.attn_impl != "flash":
             # GQA broadcast AFTER rotary (rotary runs on the kv heads): the
             # projection savings are already banked; every impl below then
@@ -622,6 +630,179 @@ class MoeMlp(nn.Module):
         return yt.reshape(b, s, d)
 
 
+_SPARE_ROWS = 512  # zero rows appended for the padding rows to read
+
+
+def _gather_rows(src, plan):
+    """(tokens, d) -> (rows, d): buffer row r takes its token's row, a
+    padding row one of the zero rows appended for them (one gather, no mask
+    after; MANY zero rows, because three rows in four are padding and a
+    gather whose indices all name one row is served by one memory channel)."""
+    rows_token = plan[0]
+    spare = jnp.zeros((_SPARE_ROWS, src.shape[1]), src.dtype)
+    return jnp.concatenate([src, spare])[rows_token]
+
+
+def _sum_choices(buf, weight, place):
+    """(rows, d) -> (tokens, d): token t takes sum_j weight[t, j] *
+    buf[place[t, j]], accumulated in float32 a choice at a time (k gathers
+    of (tokens, d), never a (tokens, k, d) copy)."""
+    out = jnp.zeros((place.shape[0], buf.shape[1]), jnp.float32)
+    for j in range(place.shape[1]):
+        out = out + weight[:, j, None] * buf[place[:, j]].astype(jnp.float32)
+    return out
+
+
+# The two permutations of an expert layer, each the other's transpose, so
+# that forward AND backward are gathers: a row scatter-add, which is what
+# autodiff makes of a gather, serialises on a TPU. `plan` = (rows_token,
+# rows_pair, place, here): per buffer row its token (for a padding row one
+# of `_SPARE_ROWS` indices past the tokens) and the (token, choice) pair that
+# sits in it (as token * k + choice; for a padding row any pair); per pair
+# its row (for a pair that fell elsewhere any row: its weight is 0) and
+# whether it fell on an expert held here. The indices that stand for
+# nothing are SPREAD, never one index many times: see `_gather_rows`.
+
+@jax.custom_vjp
+def _dispatch(u, plan):
+    return _gather_rows(u, plan)
+
+
+def _dispatch_fwd(u, plan):
+    return _dispatch(u, plan), plan
+
+
+def _dispatch_bwd(plan, dxs):
+    _, _, place, here = plan
+    return _sum_choices(dxs, here.astype(jnp.float32), place).astype(dxs.dtype), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, gates, plan):
+    _, _, place, here = plan
+    return _sum_choices(y, jnp.where(here, gates, 0.0), place).astype(y.dtype)
+
+
+def _combine_fwd(y, gates, plan):
+    return _combine(y, gates, plan), (y, gates, plan)
+
+
+def _combine_bwd(res, dout):
+    y, gates, plan = res
+    _, rows_pair, place, here = plan
+    dy = (_gather_rows(dout, plan)
+          * gates.reshape(-1)[rows_pair][:, None].astype(dout.dtype))
+    dgates = jnp.stack(
+        [jnp.sum(dout.astype(jnp.float32) * y[place[:, j]].astype(jnp.float32), axis=-1)
+         for j in range(place.shape[1])], axis=1)
+    return dy, jnp.where(here, dgates, 0.0).astype(gates.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+class GroupedExperts(nn.Module):
+    """Top-k mixture of gated experts that drops nothing, and that is told
+    which experts it holds.
+
+    The router is `n_experts` wide and reads `h`, the input of the block's
+    ATTENTION (so the choice is known before attention has run); every
+    token takes its `top_k` best logits and weighs them by a softmax over
+    those k. Of the experts this module holds `held` = (first, count),
+    default all: it routes over all `n_experts`, computes its own experts'
+    part of the result, down(relu(gate u) * (up u)) of the block's second
+    norm `u`, and adds NOTHING for the others — what the chips that hold
+    them would add is theirs to send (expert parallelism; on one chip the
+    layer runs without an exchange, and the partial result is the layer's
+    output). With all held it is the whole layer.
+
+    Dispatch is sorted and grouped, not one-hot: the (token, choice) pairs
+    that fall on held experts are ranked inside their expert, placed into a
+    row buffer in which every expert starts on a tile boundary
+    (tpunet.ops.grouped_matmul), multiplied by three grouped products,
+    scaled by their gate and summed back into their token. The buffer is
+    sized for the worst case a static shape must allow (tokens x min(top_k,
+    count) rows and a tile an expert), so NO token is dropped whatever the
+    imbalance; tiles past the true count cost no product. Nothing is sown
+    under `moe_aux_loss`: the loss is the cross-entropy alone. Sown under
+    `intermediates`: `moe_rows_held`, the (token, choice) pairs that fell on
+    held experts, and `moe_rows_max`, the fullest held expert's.
+
+    Weights: router (d, n_experts); gate, up (count, d, d_ff); down (count,
+    d_ff, d); float32 masters, compute_dtype into the products. The router's
+    product is float32 at Precision.HIGHEST: under the default an f32 x f32
+    product is ONE bf16 pass on a TPU, and its rounding decides which
+    experts a token gets."""
+
+    n_experts: int
+    top_k: int
+    d_ff: int
+    held: tuple[int, int] | None = None
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u, h):
+        from tpunet.ops.grouped_matmul import (buffer_rows, group_tiles,
+                                               grouped_matmul, tile_rows)
+
+        b, s, d = u.shape
+        e, k, f, dt = self.n_experts, self.top_k, self.d_ff, self.compute_dtype
+        first, count = self.held or (0, e)
+        if not 1 <= k <= e or first < 0 or count < 1 or first + count > e:
+            raise ValueError(f"top_k {k}, held {(first, count)} outside "
+                             f"n_experts={e}")
+        t = b * s
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (d, e))
+        gate = self.param("gate", init, (count, d, f))
+        up = self.param("up", init, (count, d, f))
+        down = self.param("down", init, (count, f, d))
+
+        with jax.named_scope("moe.route"):
+            logits = jnp.dot(h.reshape(t, d).astype(jnp.float32),
+                             router.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            top, experts = jax.lax.top_k(logits, k)       # (t, k), best first
+            gates = jax.nn.softmax(top, axis=-1)
+
+        with jax.named_scope("moe.dispatch"):
+            local = experts - first
+            here = (local >= 0) & (local < count)
+            local = jnp.where(here, local, count)          # count = "not here"
+            onehot = jax.nn.one_hot(local.reshape(-1), count + 1, dtype=jnp.int32)
+            sizes = jnp.sum(onehot, axis=0)[:count]
+            self.sow("intermediates", "moe_rows_held", jnp.sum(sizes))
+            self.sow("intermediates", "moe_rows_max", jnp.max(sizes))
+            # rank of each pair inside its expert, token-major
+            rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=-1)
+            tile_m = tile_rows(t * min(k, count), dt)
+            rows = buffer_rows(t * min(k, count), count, tile_m)
+            starts, tile_group, n_tiles = group_tiles(sizes, tile_m, rows)
+            place = (jnp.append(starts, 0)[local.reshape(-1)] + rank).reshape(t, k)
+            pairs = jnp.full((rows,), t * k, jnp.int32).at[
+                jnp.where(here, place, rows).reshape(-1)].set(
+                    jnp.arange(t * k, dtype=jnp.int32), mode="drop",
+                    unique_indices=True)
+            row = jnp.arange(rows, dtype=jnp.int32)
+            live = pairs < t * k
+            plan = (jnp.where(live, pairs // k, t + row % _SPARE_ROWS),
+                    jnp.where(live, pairs, row % (t * k)),
+                    jnp.where(here, place, jnp.arange(t, dtype=jnp.int32)[:, None]),
+                    here)
+            xs = _dispatch(u.reshape(t, d).astype(dt), plan)
+
+        mm = functools.partial(grouped_matmul, tile_group=tile_group,
+                               n_tiles=n_tiles, tile_m=tile_m)
+        act = nn.relu(mm(xs, gate)) * mm(xs, up)
+        y = mm(act, down)
+        with jax.named_scope("moe.combine"):
+            out = _combine(y, gates, plan)
+        return out.reshape(b, s, d)
+
+
 class Block(nn.Module):
     spec: LayerSpec
 
@@ -630,7 +811,14 @@ class Block(nn.Module):
         spec = self.spec
         norm = lambda name: RMSNorm(  # noqa: E731
             spec.norm_eps, spec.norm_unit_offset, spec.compute_dtype, name=name)
-        x = x + SelfAttention(spec, name="attn")(norm("norm1")(x))
+        h = norm("norm1")(x)
+        x = x + SelfAttention(spec, name="attn")(h)
+        if spec.n_experts > 0 and spec.moe_impl == "grouped":
+            # the router reads the ATTENTION's input: its choice does not
+            # wait for attention
+            return x + GroupedExperts(
+                spec.n_experts, spec.moe_top_k, spec.d_ff, spec.moe_held,
+                spec.compute_dtype, name="moe")(norm("norm2")(x), h)
         if spec.n_experts > 0:
             mlp = MoeMlp(spec.n_experts, spec.d_ff, spec.capacity_factor,
                          spec.compute_dtype, top_k=spec.moe_top_k, name="moe")
@@ -699,21 +887,44 @@ class Transformer(nn.Module):
     #   logits, (b, s, n, vocab); head j predicts the token at t + 1 + j
     eva_window: int | None = None  # attn_impl="eva" (tpunet.ops.eva_attention)
     eva_chunk: int | None = None
+    head_dim: int | None = None    # a head's size; None = d_model // n_heads.
+    #   Set, it is free of the model's width: q is n_heads * head_dim wide
+    attn_pattern: tuple[tuple[bool, bool], ...] | None = None  # the kinds of
+    #   attention layer, repeated over the depth: layer i is
+    #   attn_pattern[i % len], a pair (takes attn_window, takes the rotary).
+    #   None = every layer takes both, as ((True, True),). A layer without
+    #   the rotary has no positional encoding at all (NoPE). Training and
+    #   full-sequence scoring; decode with more than one kind raises
+    moe_impl: str = "capacity"     # the expert layer of an n_experts > 0
+    #   block: "capacity" = MoeMlp (one-hot dispatch into capacity-bounded
+    #   slots, ungated experts, tokens over capacity dropped, a sown aux
+    #   loss); "grouped" = GroupedExperts (sorted dispatch that drops
+    #   nothing, gated ReLU experts of width d_ff, router on the attention's
+    #   input, no aux loss)
+    moe_held: tuple[int, int] | None = None  # moe_impl="grouped": (first,
+    #   count), the experts this model holds of the n_experts it routes
+    #   over (expert parallelism's share); None = all
 
     @nn.nowrap
     def layer_specs(self) -> tuple[LayerSpec, ...]:
         """One LayerSpec a block. The only code that reads the model's
         fields into specs — by NAME, so a field declared on both needs no
         line here — and the only place a layer is made to differ from its
-        neighbours: today the experts, in every `moe_every`-th block."""
-        base = LayerSpec(head_dim=self.d_model // self.n_heads, **{
+        neighbours: the experts, in every `moe_every`-th block, and the kind
+        of attention, by the layer's place in `attn_pattern`."""
+        derived = {"head_dim": self.head_dim or self.d_model // self.n_heads,
+                   "rotary": True}
+        base = LayerSpec(**derived, **{
             f.name: getattr(self, f.name)
-            for f in dataclasses.fields(LayerSpec) if f.name != "head_dim"})
+            for f in dataclasses.fields(LayerSpec) if f.name not in derived})
+        pattern = self.attn_pattern or ((True, True),)
 
         def layer(i):
             moe = self.n_experts > 0 and (i + 1) % self.moe_every == 0
+            window, rotary = pattern[i % len(pattern)]
             return dataclasses.replace(
-                base, n_experts=self.n_experts if moe else 0)
+                base, n_experts=self.n_experts if moe else 0, rotary=rotary,
+                attn_window=self.attn_window if window else None)
 
         return tuple(layer(i) for i in range(self.n_layers))
 
@@ -743,6 +954,15 @@ class Transformer(nn.Module):
                 "['kernel'], but the adapted tree nests it under 'base' "
                 "(and the lm_head adapters would be silently dropped) - "
                 "merge_lora first, or train without fused xent")
+        if self.moe_impl not in ("capacity", "grouped"):
+            raise ValueError(f"unknown moe_impl {self.moe_impl!r}")
+        if self.decode and len(set(self.attn_pattern or ())) > 1:
+            raise ValueError(
+                "decode=True with more than one kind of layer in attn_pattern "
+                "is not supported yet: the servers keep one kind of KV cache "
+                "for every layer, and a ring cache beside a full one is "
+                "ROADMAP Reach A2's serving half; score the full sequence "
+                "with decode=False")
         if self.n_pred_heads > 1 and (features_only or self.decode):
             raise ValueError(
                 "n_pred_heads > 1 has no fused cross-entropy and no decode "
