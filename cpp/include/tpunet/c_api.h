@@ -359,6 +359,12 @@ int32_t tpunet_c_bridge_call(int32_t kind, uint64_t nbytes);
  * device_put not yet returned) in tpunet_bridge_chunks_in_flight_max{kind=...}.
  * `kind` as for tpunet_c_bridge_call. */
 int32_t tpunet_c_bridge_chunks(int32_t kind, uint64_t chunks, uint64_t in_flight);
+/* Count the minor page faults the process took across one boundary exchange
+ * (getrusage(RUSAGE_SELF).ru_minflt, every thread's, read by the caller at
+ * the two ends of host_all_reduce's dcn.bridge span) into
+ * tpunet_bridge_minor_faults_total{kind=...}. `kind` as for
+ * tpunet_c_bridge_call. */
+int32_t tpunet_c_bridge_minor_faults(int32_t kind, uint64_t faults);
 /* Bound port of the on-demand /metrics listener, or 0 when no listener is
  * up. TPUNET_METRICS_PORT unset/empty = no listener; an explicit 0 binds an
  * EPHEMERAL port (multi-tier loopback: several processes on one box each

@@ -204,6 +204,9 @@ struct MetricsSnapshot {
   // the host and the return of their device_put at one time.
   uint64_t bridge_chunks[kBridgeKindCount] = {0};
   uint64_t bridge_chunks_in_flight_max[kBridgeKindCount] = {0};
+  // Minor page faults of the whole process across boundary exchanges: what
+  // the exchange's host blocks cost when their pages are new every step.
+  uint64_t bridge_minor_faults[kBridgeKindCount] = {0};
   // Zero-copy data-path counters (docs/DESIGN.md "Data path"): wire syscalls
   // indexed by utils.h IoOp (send, recv, sendmsg, recvmsg) and bytes
   // produced by the reduction kernels. syscalls/MiB is derived from these in
@@ -309,6 +312,8 @@ class Telemetry {
   // One boundary exchange's chunks (tpunet_c_bridge_chunks): `chunks`
   // crossed, at most `in_flight` of them at one time (kept as a maximum).
   void OnBridgeChunks(int kind, uint64_t chunks, uint64_t in_flight);
+  // One boundary exchange's minor page faults (tpunet_c_bridge_minor_faults).
+  void OnBridgeMinorFaults(int kind, uint64_t faults);
   // Failure-containment hooks (cold paths). `action` indexes FaultAction.
   void OnFaultInjected(int action);
   void OnStreamFailover();

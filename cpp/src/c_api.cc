@@ -641,6 +641,14 @@ int32_t tpunet_c_bridge_chunks(int32_t kind, uint64_t chunks, uint64_t in_flight
   return TPUNET_OK;
 }
 
+int32_t tpunet_c_bridge_minor_faults(int32_t kind, uint64_t faults) {
+  if (kind < 0 || kind >= tpunet::kBridgeKindCount) {
+    return Fail(TPUNET_ERR_INVALID, "kind must be 0..7 (as tpunet_c_bridge_call)");
+  }
+  tpunet::Telemetry::Get().OnBridgeMinorFaults(kind, faults);
+  return TPUNET_OK;
+}
+
 int32_t tpunet_c_metrics_port(void) {
   return tpunet::Telemetry::Get().MetricsPort();
 }

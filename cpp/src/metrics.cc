@@ -243,6 +243,7 @@ struct Telemetry::Impl {
   std::atomic<uint64_t> bridge_bytes[kBridgeKindCount] = {};
   std::atomic<uint64_t> bridge_chunks[kBridgeKindCount] = {};
   std::atomic<uint64_t> bridge_chunks_in_flight_max[kBridgeKindCount] = {};
+  std::atomic<uint64_t> bridge_minor_faults[kBridgeKindCount] = {};
 
   // TCP introspection (always on unless TPUNET_TCPINFO_INTERVAL_MS=0).
   uint64_t tcp_interval_us =
@@ -827,6 +828,11 @@ void Telemetry::OnBridgeChunks(int kind, uint64_t chunks, uint64_t in_flight) {
   }
 }
 
+void Telemetry::OnBridgeMinorFaults(int kind, uint64_t faults) {
+  if (kind < 0 || kind >= kBridgeKindCount) return;
+  impl_->bridge_minor_faults[kind].fetch_add(faults, std::memory_order_relaxed);
+}
+
 void Telemetry::OnFaultInjected(int action) {
   if (action < 0 || action >= kFaultActionSlots) return;
   impl_->faults_injected[action].fetch_add(1, std::memory_order_relaxed);
@@ -958,6 +964,7 @@ void Telemetry::Reset() {
   for (auto& c : im->bridge_bytes) c.store(0, std::memory_order_relaxed);
   for (auto& c : im->bridge_chunks) c.store(0, std::memory_order_relaxed);
   for (auto& c : im->bridge_chunks_in_flight_max) c.store(0, std::memory_order_relaxed);
+  for (auto& c : im->bridge_minor_faults) c.store(0, std::memory_order_relaxed);
   {
     MutexLock lk(im->win_mu);
     im->win_init = false;
@@ -1103,6 +1110,8 @@ MetricsSnapshot Telemetry::Snapshot() const {
     s.bridge_chunks[k] = im->bridge_chunks[k].load(std::memory_order_relaxed);
     s.bridge_chunks_in_flight_max[k] =
         im->bridge_chunks_in_flight_max[k].load(std::memory_order_relaxed);
+    s.bridge_minor_faults[k] =
+        im->bridge_minor_faults[k].load(std::memory_order_relaxed);
   }
   s.weight_version = im->weight_version.load(std::memory_order_relaxed);
   for (int t = 0; t < kServeTierCount; ++t) {
@@ -1521,6 +1530,16 @@ std::string Telemetry::PrometheusText() const {
     emit("tpunet_bridge_chunks_in_flight_max{rank=\"%lld\",kind=\"%s\"} %llu\n",
          (long long)rank, kBridgeKinds[k],
          (unsigned long long)s.bridge_chunks_in_flight_max[k]);
+  }
+  family("tpunet_bridge_minor_faults_total", "counter",
+         "Minor page faults of the process (every thread) across boundary "
+         "exchanges (tpunet/interop.py host_all_reduce), by collective kind: "
+         "over tpunet_bridge_bytes_total, what the exchange's host blocks "
+         "cost in pages that were new to the process.");
+  for (int k = 0; k < kBridgeKindCount; ++k) {
+    emit("tpunet_bridge_minor_faults_total{rank=\"%lld\",kind=\"%s\"} %llu\n",
+         (long long)rank, kBridgeKinds[k],
+         (unsigned long long)s.bridge_minor_faults[k]);
   }
   family("tpunet_hold_on_request", "gauge",
          "Requests posted but not yet test()ed done (in flight).");

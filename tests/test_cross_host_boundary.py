@@ -4,7 +4,9 @@ bit-identical to the single program that held dcn_pmean(flat), which stays
 here as the oracle; what the ahead-of-time compile gives back; the bridge's
 counters and spans once a step. Since PR 29 the vector crosses in chunks:
 every case runs with the vector whole (the tiny model is under one chunk of
-the shipped size) and again cut into CHUNKED bytes a chunk."""
+the shipped size) and again cut into CHUNKED bytes a chunk. Since PR 31 a
+chunk's host block stays under the allocator's mmap ceiling and the first
+boundary step tells the allocator to keep freed blocks."""
 
 from __future__ import annotations
 
@@ -247,6 +249,130 @@ def test_chunk_sizes_from_the_vectors_bytes_and_the_world():
             first = boundary_chunks(1 << 30, itemsize, world)[0]
             assert first * itemsize % (64 * world) == 0
             assert first * itemsize <= interop._CHUNK_BYTES
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_no_block_reaches_the_allocators_ceiling(itemsize):
+    """A block whose allocation reaches glibc's DEFAULT_MMAP_THRESHOLD_MAX is
+    a fresh mapping every step whatever the allocator is told."""
+    from tpunet import interop
+
+    assert interop._MMAP_CEILING == 4 * 1024 * 1024 * 8
+    room = interop._MMAP_CEILING - interop._ALLOC_MARGIN
+    assert interop._CHUNK_BYTES <= room
+    for world in range(1, 9):
+        for size in (1, N_GRAD, 138_357_544, (1 << 30) + 7):
+            blocks = interop.boundary_chunks(size, itemsize, world)
+            assert sum(blocks) == size and max(blocks) * itemsize <= room
+
+
+class _FakeLibc:
+    """Stands where interop looks for the C library: counts mallopt's calls."""
+
+    def __init__(self, has_mallopt: bool = True):
+        self.calls, self.c_int = [], None
+        if has_mallopt:
+            self.mallopt = lambda param, value: self.calls.append((param, value)) or 1
+
+    def CDLL(self, name):
+        assert name is None  # the process's own C library
+        return self
+
+
+@pytest.fixture()
+def fake_libc(request, monkeypatch):
+    from tpunet import interop
+
+    libc = _FakeLibc(*getattr(request, "param", ()))
+    monkeypatch.setattr(interop, "ctypes", libc)
+    interop.retain_freed_host_blocks.cache_clear()
+    yield libc
+    interop.retain_freed_host_blocks.cache_clear()  # the next caller asks the real one
+
+
+def test_the_allocator_is_told_once_and_by_a_boundary_step_alone(world_of_one,
+                                                                 fake_libc):
+    from tpunet import interop
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    key = jax.random.PRNGKey(0)
+    for kw in (dict(), dict(cross_host=True, bucket_bytes=1 << 10)):
+        make_train_step(model, tx, **kw)(fresh(), toks, labels, key)
+    assert fake_libc.calls == []  # a step that is one program never asks
+    step = make_train_step(model, tx, cross_host=True)
+    told = [(-3, interop._MMAP_CEILING), (-1, 2 ** 31 - 1),
+            (-2, 2 * interop._MMAP_CEILING)]  # <malloc.h>'s M_MMAP_THRESHOLD, ...
+    assert fake_libc.calls == told
+    # the compiled twin, a second step and a direct call find it done
+    step.lower(fresh(), toks, labels, key).compile()(fresh(), toks, labels, key)
+    make_train_step(model, tx, cross_host=True, donate=False)
+    assert interop.retain_freed_host_blocks() is True
+    assert fake_libc.calls == told
+
+
+@pytest.mark.parametrize("fake_libc", [(False,)], indirect=True, ids=["no-mallopt"])
+def test_a_c_library_without_mallopt_is_left_alone(world_of_one, fake_libc):
+    from tpunet import interop
+    from tpunet.train import make_train_step
+
+    model, tx, fresh, toks, labels = _tiny(0)
+    want = _run(_in_jit_step(model, tx, donate=True), fresh(), toks, labels)
+    got = _run(make_train_step(model, tx, cross_host=True), fresh(), toks, labels)
+    assert interop.retain_freed_host_blocks() is False and fake_libc.calls == []
+    _assert_bitwise(got, want)
+
+
+FAULT_STEPS = 8
+
+
+def _faults_worker(rank: int, world: int, port: int, q) -> None:
+    try:
+        import flax.linen as nn
+        import jax.numpy as jnp
+        import optax
+
+        from tpunet import distributed, interop, telemetry
+        from tpunet.train import TrainState, make_train_step
+
+        n = 4 * interop._CHUNK_BYTES // 4  # four blocks of the shipped size
+
+        class OneLeaf(nn.Module):
+            @nn.compact
+            def __call__(self, x, train=False):
+                w = self.param("w", nn.initializers.zeros, (n,), jnp.float32)
+                z = jnp.vdot(w, x)
+                return jnp.stack([z, jnp.zeros_like(z)])[None]
+
+        distributed.initialize(f"127.0.0.1:{port}", rank, world)
+        params = {"w": jnp.zeros((n,), jnp.float32)}
+        tx = optax.sgd(0.01)
+        state = TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+        step = make_train_step(OneLeaf(), tx, cross_host=True)
+        x, labels = jnp.ones((n,), jnp.float32), jnp.zeros((1,), jnp.int32)
+        per_mib = []
+        for i in range(FAULT_STEPS):
+            telemetry.reset()
+            state, _ = step(state, x, labels, jax.random.PRNGKey(i))
+            m = telemetry.metrics()
+            assert sum(m["tpunet_bridge_chunks_total"].values()) == 4
+            per_mib.append(sum(m["tpunet_bridge_minor_faults_total"].values())
+                           / (sum(m["tpunet_bridge_bytes_total"].values()) / 2 ** 20))
+        # The first step's blocks are pages new to the process, one fault
+        # every 4 KiB (fewer where the host backs them with huge pages); once
+        # each arena that serves them has grown to hold them, none. A loose
+        # factor: which of the runtime's threads allocates varies.
+        assert per_mib[0] > 8 and min(per_mib[4:]) <= per_mib[0] / 4, per_mib
+        distributed.finalize()
+        q.put((rank, "OK"))
+    except Exception as e:  # noqa: BLE001
+        import traceback
+
+        q.put((rank, f"FAIL: {type(e).__name__}: {e}\n{traceback.format_exc()[-800:]}"))
+
+
+def test_a_cpu_rank_stops_faulting_once_its_heap_holds_the_blocks():
+    run_spawn_workers(_faults_worker, 1)
 
 
 def test_the_programs_hand_over_a_tuple_of_chunks(world_of_one, chunk_bytes):

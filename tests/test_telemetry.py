@@ -170,6 +170,44 @@ def test_metrics_text_parses_without_activity():
     _lint_exposition(text)
 
 
+def test_bridge_minor_faults_counter_is_registered_and_only_grows():
+    """tpunet_bridge_minor_faults_total{kind}: a series a bridge kind, fed by
+    host_all_reduce alone (kind all_reduce), a counter in the exposition and
+    in the lint's registry."""
+    from pathlib import Path
+
+    import pytest
+
+    from tools.lint.metricsreg import check_metric_registry, registry_families
+    from tpunet import _native, telemetry
+
+    fam = "tpunet_bridge_minor_faults_total"
+
+    def by_kind() -> dict:
+        return {telemetry.labels(key)["kind"]: v
+                for key, v in telemetry.metrics()[fam].items()}
+
+    before = by_kind()
+    assert set(before) == set(telemetry._BRIDGE_KINDS)
+    for faults, grown in ((7, 7), (0, 7), (-3, 7), (2 ** 40, 7 + 2 ** 40)):
+        telemetry.bridge_minor_faults("all_reduce", faults)
+        now = by_kind()
+        assert now.pop("all_reduce") == before["all_reduce"] + grown
+        assert now == {k: v for k, v in before.items() if k != "all_reduce"}
+    with pytest.raises(KeyError):
+        telemetry.bridge_minor_faults("all_reduc", 1)
+    lib = _native.load()
+    assert lib.tpunet_c_bridge_minor_faults(8, 1) < 0
+    assert lib.tpunet_c_bridge_minor_faults(-1, 1) < 0
+    text = telemetry.metrics_text()
+    assert f"# TYPE {fam} counter" in text and f'{fam}{{rank="' in text
+    _lint_exposition(text)
+    root = Path(__file__).resolve().parent.parent
+    assert fam in registry_families(root) and check_metric_registry(root) == []
+    header = (root / "cpp" / "include" / "tpunet" / "c_api.h").read_text()
+    assert "tpunet_c_bridge_minor_faults(" in header
+
+
 def test_metrics_parser_accepts_label_less_lines(monkeypatch):
     """Prometheus exposition allows plain `name value` lines; the old
     mandatory-`{labels}` regex silently dropped them from metrics()."""

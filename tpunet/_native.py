@@ -229,6 +229,8 @@ def load(lib_file: Path | None = None) -> ctypes.CDLL:
     lib.tpunet_c_bridge_call.restype = i32
     lib.tpunet_c_bridge_chunks.argtypes = [i32, u64, u64]
     lib.tpunet_c_bridge_chunks.restype = i32
+    lib.tpunet_c_bridge_minor_faults.argtypes = [i32, u64]
+    lib.tpunet_c_bridge_minor_faults.restype = i32
     lib.tpunet_c_metrics_port.argtypes = []
     lib.tpunet_c_metrics_port.restype = i32
     lib.tpunet_c_serve_observe.argtypes = [i32, u64]

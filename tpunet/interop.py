@@ -71,7 +71,9 @@ the ticket API on its own program segments.
 
 from __future__ import annotations
 
-from functools import partial
+import ctypes
+import resource
+from functools import cache, partial
 from typing import Any
 
 import jax
@@ -260,13 +262,73 @@ def host_buffer_like(x) -> np.ndarray:
     return raw[start:start + nbytes].view(x.dtype).reshape(x.shape)
 
 
-# The boundary exchange's two numbers, from the sweeps on the v5e host in
-# both VGG16 cells (world 2 over TCP, world 4 over shared memory; PERF.md
-# section 6, PR 29, has every row, the sizes and depths that lost among
-# them): bytes of one chunk, and how many chunks' copies to the host run
-# ahead of the chunk the exchange waits for.
-_CHUNK_BYTES = 32 << 20
+# The largest request glibc's malloc may serve from its heap, whatever the
+# threshold is set to: a request that reaches it is ALWAYS a fresh mmap,
+# returned by munmap at free, so every page of it is faulted in anew
+# (glibc malloc/malloc.c: `# define DEFAULT_MMAP_THRESHOLD_MAX (4 * 1024 *
+# 1024 * sizeof(long))`, 32 MiB on a 64-bit host).
+_MMAP_CEILING = 32 << 20
+# Room under the ceiling for what an allocation asks for beyond its block's
+# bytes: malloc's 16-byte header, and the runtime's 64-byte alignment, which
+# aligned_alloc serves from a request of alignment + MINSIZE more. Far more
+# than they need; it is the distance the sweep below ran at.
+_ALLOC_MARGIN = 64 << 10
+
+# The boundary exchange's two numbers: bytes of one chunk, under the
+# allocator's ceiling (a chunk's host block, the landing of its way out on an
+# accelerator's rank and the grad program's output on a CPU rank, then comes
+# from the heap the process keeps: retain_freed_host_blocks), and how many
+# chunks' copies to the host run ahead of the chunk the exchange waits for.
+# The winner of the sweep on four chips of the v5e host (world 4 over shared
+# memory, the cell vgg16-dp4-shm's exchange; PERF.md section 6, PR 31, has
+# every row, the sizes and depths that lost among them): the largest size
+# tried under the ceiling, 17 ring calls for VGG16's 553 MB as when a chunk
+# was 32 MiB; 31, 28, 24 and 16 MiB and depths 2 and 8 lose by 0.4 to 10%,
+# the nearest of them inside the noise.
+_CHUNK_BYTES = _MMAP_CEILING - _ALLOC_MARGIN
 _COPIES_AHEAD = 4
+
+_M_TRIM_THRESHOLD, _M_TOP_PAD, _M_MMAP_THRESHOLD = -1, -2, -3  # <malloc.h>
+
+
+@cache
+def retain_freed_host_blocks() -> bool:
+    """Tell the C library's allocator, once a process, to serve every block
+    under _MMAP_CEILING from its heap and to keep the heap when blocks are
+    freed: the host blocks a boundary exchange allocates anew every step
+    then lie in pages the process already owns, where a fresh mapping costs
+    a page fault every 4 KiB of every block of every step. Three calls:
+    mallopt(M_MMAP_THRESHOLD, ceiling), which also ends the allocator's
+    dynamic threshold; mallopt(M_TRIM_THRESHOLD, INT_MAX), without which a
+    heap's top is given back whenever the step's blocks have all been
+    freed; and mallopt(M_TOP_PAD, 2 * ceiling), for the blocks a thread of
+    the runtime allocates (a CPU rank's grad program): they come from that
+    thread's arena, whose heaps are mappings of twice the ceiling that are
+    unmapped whole when they empty, unless the heap before has less room
+    left than the pad. The price: the process keeps its high-water heap
+    (one vector's bytes; an arena each its own) instead of returning it
+    between steps, for every block under the ceiling whoever allocates
+    it, and an arena's new heap is mapped writable whole. mallopt takes
+    an int: a free top of 2 GiB or more is still trimmed, so a vector
+    that large is kept in part only. False, and nothing done, where the
+    C library has no mallopt.
+
+    Called when a _BoundaryStep is built: not at import, and not by a
+    process whose steps never cross hosts."""
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    mallopt.argtypes, mallopt.restype = [ctypes.c_int, ctypes.c_int], ctypes.c_int
+    # a list: all three are called whatever the first returns
+    return all([mallopt(_M_MMAP_THRESHOLD, _MMAP_CEILING),
+                mallopt(_M_TRIM_THRESHOLD, 2 ** 31 - 1),
+                mallopt(_M_TOP_PAD, 2 * _MMAP_CEILING)])
+
+
+def _minor_faults() -> int:
+    """The process's minor page faults so far, every thread's."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
 
 
 def boundary_chunks(size: int, itemsize: int, world: int) -> tuple[int, ...]:
@@ -327,7 +389,11 @@ def host_all_reduce(chunks, out: np.ndarray, op: str = "sum") -> tuple:
     in-jit bridge; its three child spans once a chunk, with `chunk`;
     tpunet_bridge_chunks_total and ..._in_flight_max say how many chunks
     crossed and how many were between the start of their copy out and the
-    return of their device_put at one time.
+    return of their device_put at one time;
+    tpunet_bridge_minor_faults_total the page faults the whole process took
+    across the span (over the bytes: near 256 a MiB when every chunk's host
+    block is a fresh mapping, near 0 when retain_freed_host_blocks
+    engaged).
 
     `out` (from host_buffer_like, flat, of the whole vector): the ring's
     result buffer, kept by the caller across calls. jax.device_put returns
@@ -341,6 +407,7 @@ def host_all_reduce(chunks, out: np.ndarray, op: str = "sum") -> tuple:
     back, deepest = [], 0
     with telemetry.span("dcn.bridge", kind="all_reduce", nbytes=nbytes):
         telemetry.bridge_call("all_reduce", nbytes)
+        faults = _minor_faults()
         started = 0
         for k, (c, piece) in enumerate(zip(chunks, pieces)):
             # chunk k and the _COPIES_AHEAD after it are on their way out
@@ -362,6 +429,7 @@ def host_all_reduce(chunks, out: np.ndarray, op: str = "sum") -> tuple:
 
             _stages(c, _host_operand, ring, put, chunk=k)
         telemetry.bridge_chunks("all_reduce", len(chunks), deepest)
+        telemetry.bridge_minor_faults("all_reduce", _minor_faults() - faults)
     return tuple(back)
 
 

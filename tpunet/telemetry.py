@@ -35,6 +35,7 @@ Chrome-trace spans for every request plus collective phase spans tagged
   weight_version()    -> set the serving checkpoint-version gauge
   bridge_call()       -> count one DCN-bridge host callback and its bytes
   bridge_chunks()     -> count one boundary exchange's chunks and their depth
+  bridge_minor_faults() -> count the page faults one boundary exchange paid
   flightrec_dump()    -> write this rank's flight-recorder ring to disk
   flightrec_stats()   -> (events_recorded, ring_capacity) of the recorder
 
@@ -471,6 +472,16 @@ def bridge_chunks(kind: str, chunks: int, in_flight: int) -> None:
     ``tpunet_bridge_chunks_in_flight_max{kind=...}``."""
     _native.check(_native.load().tpunet_c_bridge_chunks(
         _BRIDGE_KINDS[kind], int(chunks), int(in_flight)), "bridge_chunks")
+
+
+def bridge_minor_faults(kind: str, faults: int) -> None:
+    """Count the minor page faults the process took across one boundary
+    exchange (``resource.getrusage(RUSAGE_SELF).ru_minflt`` at the two ends
+    of host_all_reduce's ``dcn.bridge`` span: every thread's, the runtime's
+    copy threads among them) into
+    ``tpunet_bridge_minor_faults_total{kind=...}``."""
+    _native.check(_native.load().tpunet_c_bridge_minor_faults(
+        _BRIDGE_KINDS[kind], max(0, int(faults))), "bridge_minor_faults")
 
 
 def _coll_tags(events: list[dict]) -> dict[tuple, int]:

@@ -461,7 +461,11 @@ class _BoundaryStep:
     The ring's result buffer lives here, across steps (`_out`, made at the
     first call, the whole vector's; every chunk's ring writes its own slice
     of it): a new one a step costs its page faults, 0.6 s for VGG16's 553 MB
-    on the v5e's host, on the chip rank and on a CPU rank alike. Each slice
+    on the v5e's host, on the chip rank and on a CPU rank alike. The way out
+    pays the same for the blocks the runtime allocates a step (a chunk's
+    landing on an accelerator's rank, the grad program's chunk outputs on a
+    CPU rank), which this object cannot keep: building one tells the
+    allocator to (interop.retain_freed_host_blocks). Each slice
     is handed to jax.device_put, which returns before an accelerator has
     the bytes and which on the CPU backend aliases it: so a call returns
     only when its apply program, which reads all the slices, has finished,
@@ -470,6 +474,9 @@ class _BoundaryStep:
     device_put."""
 
     def __init__(self, grad, apply):
+        from tpunet.interop import retain_freed_host_blocks
+
+        retain_freed_host_blocks()
         self._grad, self._apply = grad, apply
         self._out = None
 
