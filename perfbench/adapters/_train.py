@@ -243,7 +243,9 @@ def run_rank(cell: dict, seed: int, seconds: float, trace: bool, *,
               "median_seconds": sorted(step_times)[len(step_times) // 2]},
              "worst_leaves": where, "loss_first_steps": probe["loss"],
              "reference_loss": ref_out["loss"], "losses_finite": finite,
-             "memory": peak_parts, "roofline_bound": run.get("roofline_bound")}
+             "memory": peak_parts, "roofline_bound": run.get("roofline_bound"),
+             "kernel_calls": run.get("kernel_calls"),
+             "roofline_skipped": run.get("roofline_skipped")}
     return harness.result(ok, steps, 0 if finite else 1, metrics, dev, compared,
                           bd, extra)
 

@@ -17,6 +17,7 @@ import socket
 import subprocess
 import sys
 import tempfile
+import time
 
 from perfbench import harness
 
@@ -35,9 +36,20 @@ def chip_env(rank: int) -> dict:
 
 
 def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+    """A loopback port for the job's coordinator, below the kernel's
+    ephemeral range and searched from a number of this process's own: the
+    kernel's next ephemeral port (bind to 0) can be handed to another
+    parent, or to any outgoing connection, in the seconds before rank 0
+    binds it."""
+    base = 20000 + os.getpid() * 61 % 12000
+    for port in range(base, base + 61):
+        with socket.socket() as s:
+            try:
+                s.bind(("127.0.0.1", port))
+            except OSError:
+                continue
+            return port
+    raise SystemExit(f"no free loopback port in {base}..{base + 60}")
 
 
 def run(cell: dict, seed: int, seconds: float, trace: bool,
@@ -82,12 +94,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         for rd, wr in pipes:
             os.close(rd)
             os.close(wr)
-        codes = []
-        for p in procs:
-            try:
-                codes.append(p.wait(timeout=RANK_TIMEOUT_S))
-            except subprocess.TimeoutExpired:
-                codes.append(None)
+        # a rank that failed leaves the others waiting for it in the
+        # bootstrap or the ring until their own timeouts: stop at the first
+        deadline = time.monotonic() + RANK_TIMEOUT_S
+        while time.monotonic() < deadline:
+            codes = [p.poll() for p in procs]
+            if all(c is not None for c in codes) or any(codes):
+                break
+            time.sleep(0.2)
         if any(c != 0 for c in codes):
             raise SystemExit(f"rank exit codes {codes}")
         with open(out_path) as f:

@@ -144,14 +144,17 @@ def memory_peak() -> tuple[int, dict]:
     return best, parts
 
 
-def count_kernels(compiled, want: int | None) -> int:
-    """Pallas kernels in the executable that will run. A program built by
-    the interpreter, or one whose shapes fell back to plain einsums, is
-    refused before a window opens."""
+def count_kernels(compiled, least: int | None) -> int:
+    """Pallas kernels in the executable that will run. The cell's file
+    states the LEAST a sound program holds (every forward kernel once); one
+    that runs some of them again, as remat's recompute does, holds more, and
+    how often each ran is the trace's to say (readers/kernel_roofline.py). A
+    program built by the interpreter, or one whose shapes fell back to plain
+    einsums, holds fewer and is refused before a window opens."""
     n = compiled.as_text().count(KERNEL)
-    if want is not None and n != want:
+    if least is not None and n < least:
         raise SystemExit(f"the compiled program holds {n} tpu_custom_call "
-                         f"kernels, the cell's file says {want}")
+                         f"kernels, the cell's file says at least {least}")
     return n
 
 
@@ -260,6 +263,26 @@ def result(correct: bool, attempted: int, failed: int, metrics: dict,
         out["notes"] = extra
     out["compared"] = compared  # last: the driver keeps the line's end
     return out
+
+
+REQUIRED = ["correct", "attempted", "failed", "metrics", "device"]
+
+
+def check_line(res: dict, traced: bool) -> None:
+    """What the driver reads of a result line, asserted; the tests of every
+    adapter share it."""
+    keys = list(res)
+    assert keys[:5] == REQUIRED and keys[-1] == "compared"
+    assert set(keys) <= set(REQUIRED) | {"breakdown", "notes", "compared"}
+    assert set(res["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
+    if traced:
+        assert {"busy_s", "window_s"} <= set(res["device"])
+        assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+    for m in res["metrics"].values():
+        assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
+    for c in res["compared"].values():
+        assert set(c) == {"value", "limit"}
+    json.dumps(res)
 
 
 def emit(res: dict) -> None:

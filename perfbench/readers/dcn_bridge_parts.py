@@ -1,17 +1,18 @@
-"""The DCN bridge from inside, a step: the three parts of the host-transfer
-wait around the native collective, cut at the program's span dcn.bridge
-(the whole body of tpunet/interop.py's host callback), which the profiler
-holds on the device operations' timeline.
+"""The DCN bridge from inside, a step: what the exchange costs the host
+outside the native collective, cut at the program's span dcn.bridge (the
+whole of tpunet/interop.py's exchange between the trainer's two programs, or
+the body of a host callback), which the profiler holds on the device
+operations' timeline.
 
-  d2h   from the start of the device's host transfer (the first send,
-        send-done, recv or recv-done with is_host_transfer since the
-        callback before) to the entry of dcn.bridge: the copy off the chip
-        and all that the runtime and JAX do before the program's code runs
-  host  dcn.bridge less dcn.bridge.collective: the program's own staging
-  h2d   from the return of dcn.bridge to the end of the device's recv-done
-        that contains it: the result's way back
+  host  dcn.bridge less every dcn.bridge.collective inside it: the waits
+        for the chunks' landings (stage_in), the device_put calls back
+        (stage_out) and whatever else the exchange does on the host
 
-Each summed over the bridge spans of the traced window, over its steps.
+Summed over the bridge spans whole in the traced window, over its steps. The
+two stages by themselves are readers/span_sum.py's. (Until PR 25 the
+exchange was a host callback inside the step's program and two more parts,
+d2h and h2d, were cut at the device's host-transfer operations; those
+operations are gone from the timeline and the parts with them.)
 
 By hand, on a trace kept with PERFBENCH_KEEP_TRACE=1:
     python3 -m perfbench.readers.dcn_bridge_parts <trace dir>
@@ -22,36 +23,18 @@ tracer and the profiler hold."""
 import glob
 import json
 import os
-import re
 import statistics
 import sys
 
 from perfbench import trace
-from perfbench.readers import program_spans
+from perfbench.readers import program_spans, span_self_time, span_sum
 
 
-def split(dev: trace.Trace, prog: trace.Trace, lo: float, hi: float,
-          params: dict) -> list:
-    """One {"d2h", "host", "h2d", "collective"} a bridge span; a part the
-    trace cannot give is None. The spans' names and the pattern of the
-    device's host-transfer operations come with the metric's file."""
-    bridges = program_spans.named(prog, params["span"], lo, hi)
-    inner = program_spans.named(prog, params["collective"], lo, hi)
-    transfer = re.compile(params["transfer"])
-    wait = re.compile(params.get("wait", trace.WAIT_OPS))
-    per_plane = (sorted((s, s + d, bool(wait.search(n))) for n, s, d in ev
-                        if transfer.search(n)) for ev in dev.ops.values())
-    moves = next((m for m in per_plane if m), [])
-    out, before = [], lo
-    for s, d in bridges:
-        coll = program_spans.inside(inner, s, d)
-        starts = [a for a, _, _ in moves if before <= a <= s]
-        ends = [b for a, b, is_wait in moves if is_wait and a <= s + d <= b]
-        out.append({"d2h": s - min(starts) if starts else None,
-                    "host": d - coll, "collective": coll,
-                    "h2d": max(ends) - (s + d) if ends else None})
-        before = s + d
-    return out
+def host_seconds(prog: trace.Trace, lo: float, hi: float, params: dict) -> list:
+    """dcn.bridge less the collectives inside it, one a bridge span whole in
+    [lo, hi]. The spans' names come with the metric's file."""
+    return span_self_time.self_seconds(prog, lo, hi, params["span"],
+                                       [params["collective"]])
 
 
 def read(ctx: dict, params: dict):
@@ -59,11 +42,8 @@ def read(ctx: dict, params: dict):
     steps = ctx["run"].get("traced_steps")
     if prog is None or not steps:
         return None
-    got = [p[params["part"]] for p in split(ctx["trace"], prog, ctx["lo"], ctx["hi"], params)]
-    got = [x for x in got if x is not None]
-    if not got:
-        return None
-    return sum(got) / steps
+    got = host_seconds(prog, ctx["lo"], ctx["hi"], params)
+    return sum(got) / steps if got else None
 
 
 # -- by hand --------------------------------------------------------------------
@@ -95,15 +75,17 @@ def mirrored_offsets(xplane: str, native_dir: str) -> list:
 def main(trace_dir: str) -> None:
     from perfbench import harness
 
-    params = harness.load("metrics", "dcn_bridge_d2h_s_per_step")["params"]
+    params = harness.load("metrics", "dcn_bridge_host_s_per_step")["params"]
     path = trace.find_xplane(trace_dir)
     dev = trace.load(path)
     prog = trace.load(path, host_prefix=program_spans.PREFIX)
     lo, hi = trace.window_of(dev)
-    parts = split(dev, prog, lo, hi, params)
-    print(f"window {hi - lo:.6f} s, {len(parts)} bridge span(s)")
-    for key in ("d2h", "host", "collective", "h2d"):
-        got = [p[key] for p in parts if p[key] is not None]
+    rows = {"host": host_seconds(prog, lo, hi, params)}
+    print(f"window {hi - lo:.6f} s, {len(rows['host'])} bridge span(s)")
+    for part in ("collective", "stage_in", "stage_out"):
+        rows[part] = span_sum.sums(prog, lo, hi, params["span"],
+                                   f"{params['span']}.{part}")
+    for key, got in rows.items():
         print(f"  {key:<10} {len(got):3d} span(s), sum {sum(got):.6f} s" +
               (f", median {statistics.median(got):.6f} s" if got else ""))
     print("device idle gaps by innermost program span:")

@@ -1,8 +1,6 @@
-"""perfbench's own tests: CPU, tiny sizes, run by hand from the repo's root
+"""perfbench's own tests: CPU, tiny sizes, from the repo's root
 
-    JAX_PLATFORMS=cpu python3 -m pytest perfbench/tests -q
-
-`pytest tests/` never collects this directory."""
+    JAX_PLATFORMS=cpu python3 -m pytest perfbench/tests -q -p xdist -n 6 --dist loadfile"""
 
 import copy
 import os

@@ -1,69 +1,15 @@
-"""Each adapter end to end at a tiny size on the CPU, the harness's look
-for a chip skipped: the result line's keys, `correct` true on a sound run,
-and `correct` false once for each fault the timed path can have and for the
-control (the reference one precision down, put in the program's place)."""
-
-import json
-import os
-import subprocess
-import sys
+"""The dp_ranks adapter end to end at a tiny size on the CPU, two ranks over
+loopback, the harness's look for a chip skipped: the result line's keys,
+`correct` true on a sound run, `correct` false once for each fault the timed
+path can have and for the control; and the shape twin's tree."""
 
 import jax
 import numpy as np
 import pytest
 
 from perfbench import compare, harness, twin, weights
-from perfbench.adapters import _models, _train, dp_ranks, train_step
-
-REQUIRED = ["correct", "attempted", "failed", "metrics", "device"]
-
-
-def check_line(res: dict, traced: bool) -> None:
-    keys = list(res)
-    assert keys[:5] == REQUIRED and keys[-1] == "compared"
-    assert set(keys) <= set(REQUIRED) | {"breakdown", "notes", "compared"}
-    assert set(res["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
-    if traced:
-        assert {"busy_s", "window_s"} <= set(res["device"])
-        assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
-    for m in res["metrics"].values():
-        assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
-    for c in res["compared"].values():
-        assert set(c) == {"value", "limit"}
-    json.dumps(res)
-
-
-def test_train_step_sound_run(train_cell):
-    res = train_step.run(train_cell, 2 ** 31 + 3, 1.0, False, platform="cpu")
-    check_line(res, traced=False)
-    assert res["correct"] and set(res["metrics"]) == {"step_s", "setup_s"}
-    assert res["attempted"] > 0 and res["failed"] == 0
-
-
-def test_train_step_traced_run(train_cell):
-    res = train_step.run(train_cell, 5, 2.0, True, platform="cpu")
-    check_line(res, traced=True)
-    assert res["correct"]
-    # no TPU plane in a CPU trace: the device readers find nothing and say nothing
-    assert "device_idle_share.step" not in res["metrics"]
-    assert "train_step_mfu" not in res["metrics"]
-
-
-@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch", "loss_altered"])
-def test_train_step_faults_come_out_incorrect(train_cell, fault):
-    res = train_step.run(train_cell, 7, 0.5, False, platform="cpu", fault=fault)
-    assert not res["correct"], res["compared"]
-
-
-def test_train_control_fails_the_comparison(train_cell):
-    """float32 configuration: the control is the reference in bfloat16."""
-    exact = _train.reference_steps(train_cell, 11, "f32")
-    control = _train.reference_steps(train_cell, 11, "bf16")
-    readings, _ = compare.train(control, exact)
-    ok, _ = compare.judge(readings, train_cell["limits"])
-    assert not ok, readings
-    again, _ = compare.train(_train.reference_steps(train_cell, 11, "f32"), exact)
-    assert compare.judge(again, train_cell["limits"])[0]
+from perfbench.adapters import _models, _train, dp_ranks
+from perfbench.harness import check_line
 
 
 def test_dp_ranks_two_ranks_over_loopback(dp_cell):
@@ -128,16 +74,3 @@ def test_twin_has_the_real_models_tree(dp_cell):
     g = jax.grad(loss)(params)["tree"]["a"]
     np.testing.assert_allclose(g["kernel"], twin.grad_leaf(5, "a/kernel", (3, 4), 0.7), rtol=1e-6)
     assert float(jnp.linalg.norm(g["bias"])) == pytest.approx(0.7, rel=0.5)
-
-
-def test_the_command_refuses_a_cpu():
-    """python3 -m perfbench.run on a machine with no TPU: non-zero exit and
-    no result line."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    for cell in ("mistral7b-train-s8192", "vgg16-dp2-tcp"):
-        p = subprocess.run(
-            [sys.executable, "-m", "perfbench.run", "--workload", cell, "--seed", "1",
-             "--seconds", "1", "--trace", "0"], cwd=harness.ROOT, env=env,
-            capture_output=True, text=True, timeout=300)
-        assert p.returncode != 0
-        assert not any(line.startswith("{") for line in p.stdout.splitlines())

@@ -8,8 +8,8 @@ import pytest
 
 from perfbench import compare, harness
 from perfbench.adapters import _train, train_step
+from perfbench.harness import check_line
 from perfbench.models import evabyte
-from perfbench.tests.test_adapters import check_line
 
 
 @pytest.fixture

@@ -2,11 +2,13 @@
 plane beside "tpunet:" host spans) and in the two adapters at a tiny size on
 the CPU, where no device plane exists."""
 
+import math
+
 import pytest
 
 from perfbench import harness, trace
-from perfbench.adapters import dp_ranks, train_step
-from perfbench.readers import dcn_bridge_parts, program_spans, span_self_time
+from perfbench.adapters import _models, dp_ranks, train_step
+from perfbench.readers import dcn_bridge_parts, program_spans, span_self_time, span_sum
 
 SEND = "%io_callback.6 = (f32[8]{0}, u32[], token[]) send(f32[8]{0} %x, token[] %t), channel_id=2, is_host_transfer=true"
 SEND_DONE = "%io_callback.7 = token[] send-done((f32[8]{0}, u32[], token[]) %io_callback.6), channel_id=2, is_host_transfer=true"
@@ -14,9 +16,10 @@ RECV_DONE = "%io_callback.9 = (f32[8]{0}, token[]) recv-done((f32[8]{0}, u32[], 
 
 
 def two_steps(with_send: bool = True):
-    """Two steps of 10 s: compute 1 s, send at +1.0, send-done +1.0..1.2,
-    recv-done +1.2..9.0; the bridge runs +3.0..7.0 with the collective at
-    +3.5..6.5. So d2h 2.0, host 1.0, h2d 2.0 a step."""
+    """Two steps of 10 s: compute 1 s, and (an in-jit collective's) send at
+    +1.0, send-done +1.0..1.2, recv-done +1.2..9.0; the bridge runs
+    +3.0..7.0 with stage_in +3.0..3.5, the collective +3.5..6.5 and
+    stage_out +6.5..7.0. So host 1.0 a step, half of it each stage."""
     dev, prog = trace.Trace(), trace.Trace()
     ops = dev.ops.setdefault("/device:TPU:0", [])
     for k in range(2):
@@ -35,9 +38,9 @@ def two_steps(with_send: bool = True):
     return dev, prog
 
 
-def part(name: str) -> dict:
-    """The reader's parameters as the metric's own file gives them."""
-    return harness.load("metrics", f"dcn_bridge_{name}_s_per_step")["params"]
+HOST = harness.load("metrics", "dcn_bridge_host_s_per_step")["params"]
+STAGES = {name: harness.load("metrics", f"dcn_bridge_{name}_s_per_step")["params"]
+          for name in ("stage_in", "stage_out")}
 
 
 def ctx_of(dev, prog, steps=2):
@@ -45,27 +48,30 @@ def ctx_of(dev, prog, steps=2):
             "run": {"traced_steps": steps}}
 
 
-@pytest.mark.parametrize("name, want", [("d2h", 2.0), ("host", 1.0), ("h2d", 2.0)])
-def test_bridge_parts(name, want):
-    assert dcn_bridge_parts.read(ctx_of(*two_steps()), part(name)) == pytest.approx(want)
+def test_bridge_host_part():
+    assert dcn_bridge_parts.read(ctx_of(*two_steps()), HOST) == pytest.approx(1.0)
 
 
-def test_bridge_d2h_without_a_send_starts_at_the_first_wait():
+@pytest.mark.parametrize("name", ["stage_in", "stage_out"])
+def test_bridge_stages(name):
+    assert span_sum.read(ctx_of(*two_steps()), STAGES[name]) == pytest.approx(0.5)
+
+
+def test_bridge_host_needs_no_device_plane():
+    """The host transfers it was once cut beside are gone from the device's
+    timeline (PR 25): the spans alone give it, with or without them."""
     dev, prog = two_steps(with_send=False)
-    assert dcn_bridge_parts.read(ctx_of(dev, prog), part("d2h")) == pytest.approx(2.0)
+    assert dcn_bridge_parts.read(ctx_of(dev, prog), HOST) == pytest.approx(1.0)
+    assert dcn_bridge_parts.read(ctx_of(trace.Trace(), prog), HOST) == pytest.approx(1.0)
 
 
 def test_bridge_parts_say_nothing_where_there_is_nothing():
     dev, prog = two_steps()
-    empty = trace.Trace()
-    for name in ("d2h", "host", "h2d"):
-        assert dcn_bridge_parts.read(ctx_of(dev, empty), part(name)) is None
-        assert dcn_bridge_parts.read(ctx_of(dev, None), part(name)) is None
-        assert dcn_bridge_parts.read(ctx_of(dev, prog, steps=0), part(name)) is None
-    # spans but no device plane: the host's share alone can be told
-    assert dcn_bridge_parts.read(ctx_of(empty, prog), part("d2h")) is None
-    assert dcn_bridge_parts.read(ctx_of(empty, prog), part("h2d")) is None
-    assert dcn_bridge_parts.read(ctx_of(empty, prog), part("host")) == pytest.approx(1.0)
+    for read, params in [(dcn_bridge_parts.read, HOST)] + [
+            (span_sum.read, p) for p in STAGES.values()]:
+        assert read(ctx_of(dev, trace.Trace()), params) is None
+        assert read(ctx_of(dev, None), params) is None
+    assert dcn_bridge_parts.read(ctx_of(dev, prog, steps=0), HOST) is None
     # no trace directory at all
     assert program_spans.load({"run": {"native_dir": "/nonexistent/native"}}) is None
     assert program_spans.load({"run": {}}) is None
@@ -74,7 +80,8 @@ def test_bridge_parts_say_nothing_where_there_is_nothing():
 def test_a_span_cut_by_the_window_is_left_out():
     dev, prog = two_steps()
     c = dict(ctx_of(dev, prog), hi=16.0)  # the second bridge ends at 17
-    assert dcn_bridge_parts.read(c, part("host")) == pytest.approx(0.5)  # 1.0 over 2 steps
+    assert dcn_bridge_parts.read(c, HOST) == pytest.approx(0.5)  # 1.0 over 2 steps
+    assert span_sum.read(c, STAGES["stage_in"]) == pytest.approx(0.5)  # the mean of the one whole
 
 
 def test_self_time():
@@ -91,17 +98,22 @@ def test_train_step_traced_run_reports_fits_own_time(train_cell):
     assert 0 < res["metrics"]["fit_host_s_per_step"]["value"] < 0.1
 
 
-def test_dp_ranks_over_the_callback_bridge(dp_cell):
-    dp_cell["env"] = dict(dp_cell.get("env", {}), TPUNET_FFI_COLLECTIVES="0")
+@pytest.mark.parametrize("ffi", ["0", None], ids=["ffi_off", "ffi_as_set"])
+def test_dp_ranks_flat_step_counts_one_bridge_call_a_step(dp_cell, ffi):
+    """Since PR 25 the flat cross-host step exchanges at a program boundary
+    whatever TPUNET_FFI_COLLECTIVES says: one bridge call of the whole
+    gradient a step, in one chunk at this size, with its stages' spans."""
+    if ffi is not None:
+        dp_cell["env"] = dict(dp_cell.get("env", {}), TPUNET_FFI_COLLECTIVES=ffi)
     res = dp_ranks.run(dp_cell, 12345, 2.0, True, platform="cpu")
     assert res["correct"], res["compared"]
-    m = res["metrics"]
-    assert m["dcn_bridge_host_s_per_step"]["value"] > 0
-    assert m["dcn_bridge_bytes_per_call"]["value"] % 4 == 0
-    assert "dcn_bridge_d2h_s_per_step" not in m and "dcn_bridge_h2d_s_per_step" not in m
-
-
-def test_dp_ranks_over_ffi_counts_no_bridge_call(dp_cell):
-    res = dp_ranks.run(dp_cell, 12345, 2.0, True, platform="cpu")
-    assert not any(name.startswith("dcn_bridge_") and name != "dcn_bridge_s_per_step"
-                   for name in res["metrics"])
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    spec = _models.reference(dp_cell["config"]).param_spec(dp_cell["config"])
+    assert m["dcn_bridge_bytes_per_call"] == 4 * sum(
+        math.prod(shape) for shape, _ in spec.values())
+    assert m["dcn_bridge_chunks_per_call"] == 1.0
+    assert m["dcn_bridge_stage_in_s_per_step"] > 0 and m["dcn_bridge_stage_out_s_per_step"] > 0
+    assert (m["dcn_bridge_stage_in_s_per_step"] + m["dcn_bridge_stage_out_s_per_step"]
+            <= m["dcn_bridge_host_s_per_step"])
+    assert not {"dcn_bridge_s_per_step", "dcn_bridge_d2h_s_per_step",
+                "dcn_bridge_h2d_s_per_step"} & set(m)

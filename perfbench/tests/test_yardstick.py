@@ -108,8 +108,17 @@ def test_manifest_names_files_that_exist():
         assert harness.metric_names(w["name"], traced=True)
     for x in m["per_layer"]:
         spec = harness.load("metrics", x["name"])
-        for key in ("layer", "unit", "better", "source", "moves", "workloads"):
+        # the manifest's list of cells is the only one: a metric's file has none to go stale
+        assert "workloads" not in spec and x["workloads"], x["name"]
+        for key in ("layer", "unit", "better", "source", "moves"):
             assert spec[key] == x[key], (x["name"], key)
         assert os.path.exists(os.path.join(harness.BENCH, "readers", spec["reader"] + ".py"))
         for w in x["workloads"]:  # every cell that reports it reports what it moves
             assert x["moves"] in harness.metric_names(w, traced=False)
+    on_disk = {f[:-len(".json")] for f in os.listdir(os.path.join(harness.BENCH, "metrics"))}
+    assert on_disk == {x["name"] for x in m["per_layer"]}  # no metric file lacks an entry
+    used = {harness.load("metrics", x["name"])["reader"] for x in m["per_layer"]}
+    helpers = {"__init__", "program_spans", "kernel_roofline"}  # shared by readers, no metric's own
+    readers = {f[:-len(".py")] for f in os.listdir(os.path.join(harness.BENCH, "readers"))
+               if f.endswith(".py")}
+    assert readers - helpers == used

@@ -109,6 +109,13 @@ def seconds_by_name(events, pattern: str) -> float:
     return sum(d for name, _, d in events if rx.search(name))
 
 
+def count_by_name(events, pattern: str) -> int:
+    """Events whose name matches: how often a kernel ran, where
+    seconds_by_name says for how long."""
+    rx = re.compile(pattern)
+    return sum(1 for name, _, _ in events if rx.search(name))
+
+
 def all_ops(t: Trace, lo: float, hi: float):
     for ev in t.ops.values():
         yield from clip(ev, lo, hi)
