@@ -49,9 +49,10 @@ def as_on_chip(monkeypatch):
     import tpunet.interop  # noqa: F401
     import tpunet.ops  # noqa: F401
 
+    import tpunet.ops.dsa_attention  # noqa: F401
     import tpunet.ops.grouped_matmul  # noqa: F401
 
-    for kernels in ("flash_attention", "grouped_matmul"):
+    for kernels in ("flash_attention", "grouped_matmul", "dsa_attention"):
         monkeypatch.setattr(sys.modules[f"tpunet.ops.{kernels}"],
                             "_auto_interpret", lambda: False)
     monkeypatch.setattr(sys.modules["tpunet.interop"],
@@ -199,6 +200,73 @@ def test_smallthinker_train_step_has_its_kernels_and_fits(one_chip, as_on_chip):
     for name, count in (("moe_gmm_fwd", 6), ("moe_gmm_dx", 3), ("moe_tgmm_dw", 3)):
         assert len([ln for ln in text.splitlines() if KERNEL in ln
                     and name in ln.split(" = ")[0]]) == layers * count, name
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _named_kernels(text: str, name: str) -> int:
+    return len([ln for ln in text.splitlines()
+                if KERNEL in ln and name in ln.split(" = ")[0]])
+
+
+@pytest.mark.parametrize("what", ["index", "attn_fwd", "attn_bwd"])
+def test_dsa_keye_b2_s8192_h32_kv4(one_chip, what):
+    """The selecting attention's kernels at the Keye cell's shapes: a
+    16 x 64 indexer over one key head, 32 query heads on 4 key heads of 128,
+    the mask's (512, 8192) int8 slab beside a head's whole K and V."""
+    from tpunet.ops import dsa_attention as dsa
+
+    b, s = 2, 8192
+    shape = lambda dims, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dt, sharding=one_chip)
+    if what == "index":
+        fn = lambda qi, ki, w: dsa.index_scores(qi, ki, w, interpret=False)  # noqa: E731
+        args = (shape((b, s, 16, 64)), shape((b, s, 64)), shape((b, s, 16), jnp.float32))
+        names = ["dsa_index_fwd"]
+    else:
+        loss = lambda q, k, v, m: jnp.sum(dsa.selected_attention(  # noqa: E731
+            q, k, v, m, interpret=False).astype(jnp.float32))
+        fn = loss if what == "attn_fwd" else jax.grad(loss, (0, 1, 2))
+        args = (shape((b, s, 32, 128)), shape((b, s, 4, 128)), shape((b, s, 4, 128)),
+                shape((b, s, s), jnp.int8))
+        names = ["dsa_attn_fwd"] + (["dsa_attn_dq", "dsa_attn_dkv"] if what == "attn_bwd" else [])
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count(KERNEL) == len(names)
+    for name in names:
+        assert _named_kernels(text, name) == 1, name
+
+
+def test_keye_train_step_has_its_kernels_and_fits(one_chip, as_on_chip):
+    """The benchmark's Keye configuration through make_train_step at the
+    cell's b2 x s8192: a layer holds 2 dsa_index_fwd and 2 dsa_attn_fwd
+    (forward and remat's recompute), dq and dkv once, and 12 grouped kernels;
+    weights, AdamW state and the step's scratch fit one chip."""
+    import optax
+
+    from perfbench import harness
+    from perfbench.models import keye
+    from tpunet.train import TrainState, make_train_step
+
+    cfg = harness.load("configs", "keye-vl2-30b-a3b-ep8-l4")
+    layers = cfg["num_hidden_layers"]
+    model = keye.build(cfg, {"remat": True})
+    tx = optax.adamw(3e-4)
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def state_of(t):
+        params = model.init(jax.random.PRNGKey(0), t)["params"]
+        return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+
+    state = jax.eval_shape(state_of, tokens)
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 465_391_104
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    compiled = make_train_step(model, tx).lower(
+        _on(one_chip, state), tokens, tokens, _on(one_chip, key)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) >= harness.load("workloads", "keye-train-s8192")["kernels"]
+    for name, count in (("dsa_index_fwd", 2), ("dsa_attn_fwd", 2), ("dsa_attn_dq", 1),
+                        ("dsa_attn_dkv", 1), ("moe_gmm_fwd", 6), ("moe_gmm_dx", 3),
+                        ("moe_tgmm_dw", 3)):
+        assert _named_kernels(text, name) == layers * count, name
     assert _device_bytes(compiled) < HBM_BYTES
 
 

@@ -214,6 +214,12 @@ class LayerSpec:
     rotary: bool
     moe_impl: str
     moe_held: tuple[int, int] | None
+    moe_activation: str
+    moe_router_input: str
+    qk_norm: bool
+    attn_select_top_k: int | None
+    attn_index_heads: int
+    attn_index_head_dim: int
 
 
 class SelfAttention(nn.Module):
@@ -239,6 +245,19 @@ class SelfAttention(nn.Module):
     kv-head tensors natively (in-kernel GQA: K/V stream at 1/group
     bandwidth); every other impl receives a post-rotary broadcast to
     ordinary MHA shapes.
+
+    attn_select_top_k = k makes the DATA decide which keys a query sees
+    (tpunet.ops.dsa_attention): an indexer of `attn_index_heads` heads of
+    `attn_index_head_dim` over one key head scores every earlier key for
+    every query from the DETACHED input, the k best are kept (all of them
+    while a query has no more than k), and the softmax runs over those
+    alone. The selection passes no gradient; the indexer learns from a loss
+    of its own, the KL divergence from the main attention's probabilities
+    (mean over heads, detached) to the softmax of its scores over the
+    selection, sown under `intermediates/dsa_index_loss` for the trainer to
+    add, beside `dsa_selected_pairs`. attn_impl "flash" runs the kernels,
+    "reference" the plain forms. qk_norm: an RMSNorm a head, of its own
+    scale, on q and on k before the rotary.
 
     decode=True switches to autoregressive inference: a "cache" collection
     holds cached_key/cached_value buffers sized by the INIT input's
@@ -274,11 +293,29 @@ class SelfAttention(nn.Module):
                 f"attn_window is only supported by attn_impl 'reference'/"
                 f"'flash', not {spec.attn_impl!r}"
             )
+        select = spec.attn_select_top_k is not None
+        if select and spec.decode:
+            raise ValueError(
+                "decode=True with attn_select_top_k is not supported yet: a "
+                "step would have to score the cached keys with the indexer, "
+                "which needs a cache of the indexer's keys beside the KV "
+                "cache and a selection inside the cached attention (ROADMAP "
+                "Reach A11); score the full sequence with decode=False")
+        if select and (spec.attn_impl not in ("reference", "flash")
+                       or spec.attn_window is not None
+                       or spec.attn_index_heads < 1 or spec.attn_index_head_dim < 1):
+            raise ValueError(
+                "attn_select_top_k needs attn_impl 'reference' or 'flash', no "
+                "attn_window, and the indexer's attn_index_heads and "
+                "attn_index_head_dim")
         dt = spec.compute_dtype
         proj = lambda nh, name: _dense(nh * dh, dt, name, spec.weight_quant, spec.lora_rank, spec.lora_alpha)
         q = proj(h, "q")(x).reshape(b, s, h, dh)
         k = proj(kv, "k")(x).reshape(b, s, kv, dh)
         v = proj(kv, "v")(x).reshape(b, s, kv, dh)
+        if spec.qk_norm:
+            q = RMSNorm(spec.norm_eps, spec.norm_unit_offset, dt, name="q_norm")(q)
+            k = RMSNorm(spec.norm_eps, spec.norm_unit_offset, dt, name="k_norm")(k)
 
         if spec.decode:
             # The cached step below is dense local attention — correct for
@@ -483,7 +520,9 @@ class SelfAttention(nn.Module):
             k = jnp.repeat(k, h // kv, axis=2)
             v = jnp.repeat(v, h // kv, axis=2)
 
-        if spec.attn_impl == "eva":
+        if select:
+            o = self._selected(x, q, k, v)
+        elif spec.attn_impl == "eva":
             from tpunet.ops.eva_attention import eva_attention
 
             def vec(key, shape):  # the released model's initialisation
@@ -526,6 +565,45 @@ class SelfAttention(nn.Module):
         o = o.reshape(b, s, h * dh)
         return _dense(x.shape[-1], dt, "out", spec.weight_quant,
                       spec.lora_rank, spec.lora_alpha)(o)
+
+    @nn.nowrap
+    def _selected(self, x, q, k, v):
+        """Attention over the keys the indexer selects. x: the block's
+        normalised input; q: (b, s, h, dh), k, v: (b, s, kv, dh), after norm
+        and rotary, k and v at their own (grouped) width."""
+        from tpunet.ops import dsa_attention as dsa
+
+        spec = self.spec
+        b, s, _ = x.shape
+        hi, di, dt = spec.attn_index_heads, spec.attn_index_head_dim, spec.compute_dtype
+        kernels = spec.attn_impl == "flash"
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)  # noqa: E731
+        with jax.named_scope("dsa.index"):
+            # the indexer reads the input DETACHED: its loss moves its own
+            # weights and nothing before them
+            xi = jax.lax.stop_gradient(x)
+            qi = dense(hi * di, "index_q")(xi).reshape(b, s, hi, di)
+            ki = nn.LayerNorm(epsilon=1e-6, dtype=dt, name="index_k_norm")(
+                dense(di, "index_k")(xi)).reshape(b, s, 1, di)
+            if spec.rotary:
+                qi = rotary_embed(qi, spec.rope_theta)
+                ki = rotary_embed(ki, spec.rope_theta)
+            ki = ki[:, :, 0]
+            w = dense(hi, "index_w")(xi).astype(jnp.float32) * (hi * di) ** -0.5
+            scores = jax.lax.stop_gradient(
+                dsa.index_scores(qi, ki, w) if kernels
+                else dsa.index_scores_reference(qi, ki, w))
+        with jax.named_scope("dsa.select"):
+            mask, pairs = dsa.select(scores, spec.attn_select_top_k, kernel=kernels)
+        self.sow("intermediates", "dsa_selected_pairs", pairs)
+        if kernels:
+            o, lse = dsa.selected_attention(q, k, v, mask, with_lse=True)
+        else:
+            o, lse = dsa.selected_attention_reference(q, k, v, mask), None
+        with jax.named_scope("dsa.kl"):
+            self.sow("intermediates", "dsa_index_loss",
+                     dsa.index_loss(q, k, mask, scores, qi, ki, w, lse=lse))
+        return o
 
 
 class Mlp(nn.Module):
@@ -708,13 +786,16 @@ class GroupedExperts(nn.Module):
     """Top-k mixture of gated experts that drops nothing, and that is told
     which experts it holds.
 
-    The router is `n_experts` wide and reads `h`, the input of the block's
-    ATTENTION (so the choice is known before attention has run); every
+    The router is `n_experts` wide and reads `h`, which `Block` hands over:
+    the input of the block's ATTENTION (so the choice is known before
+    attention has run; `moe_router_input="attn_input"`) or the expert
+    layer's own input `u` ("mlp_input"); every
     token takes its `top_k` best logits and weighs them by a softmax over
     those k. Of the experts this module holds `held` = (first, count),
     default all: it routes over all `n_experts`, computes its own experts'
-    part of the result, down(relu(gate u) * (up u)) of the block's second
-    norm `u`, and adds NOTHING for the others — what the chips that hold
+    part of the result, down(act(gate u) * (up u)) of the block's second
+    norm `u` with `activation` "relu" (ReGLU) or "silu" (SwiGLU), and adds
+    NOTHING for the others — what the chips that hold
     them would add is theirs to send (expert parallelism; on one chip the
     layer runs without an exchange, and the partial result is the layer's
     output). With all held it is the whole layer.
@@ -742,6 +823,7 @@ class GroupedExperts(nn.Module):
     d_ff: int
     held: tuple[int, int] | None = None
     compute_dtype: jnp.dtype = jnp.bfloat16
+    activation: str = "relu"
 
     @nn.compact
     def __call__(self, u, h):
@@ -754,6 +836,9 @@ class GroupedExperts(nn.Module):
         if not 1 <= k <= e or first < 0 or count < 1 or first + count > e:
             raise ValueError(f"top_k {k}, held {(first, count)} outside "
                              f"n_experts={e}")
+        if self.activation not in ("relu", "silu"):
+            raise ValueError(f"unknown moe_activation {self.activation!r}")
+        gated = nn.relu if self.activation == "relu" else nn.silu
         t = b * s
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (d, e))
@@ -796,7 +881,7 @@ class GroupedExperts(nn.Module):
 
         mm = functools.partial(grouped_matmul, tile_group=tile_group,
                                n_tiles=n_tiles, tile_m=tile_m)
-        act = nn.relu(mm(xs, gate)) * mm(xs, up)
+        act = gated(mm(xs, gate)) * mm(xs, up)
         y = mm(act, down)
         with jax.named_scope("moe.combine"):
             out = _combine(y, gates, plan)
@@ -814,11 +899,15 @@ class Block(nn.Module):
         h = norm("norm1")(x)
         x = x + SelfAttention(spec, name="attn")(h)
         if spec.n_experts > 0 and spec.moe_impl == "grouped":
-            # the router reads the ATTENTION's input: its choice does not
-            # wait for attention
+            if spec.moe_router_input not in ("attn_input", "mlp_input"):
+                raise ValueError(f"unknown moe_router_input {spec.moe_router_input!r}")
+            u = norm("norm2")(x)
+            # "attn_input": the router reads the ATTENTION's input, so its
+            # choice does not wait for attention
             return x + GroupedExperts(
                 spec.n_experts, spec.moe_top_k, spec.d_ff, spec.moe_held,
-                spec.compute_dtype, name="moe")(norm("norm2")(x), h)
+                spec.compute_dtype, spec.moe_activation, name="moe")(
+                    u, h if spec.moe_router_input == "attn_input" else u)
         if spec.n_experts > 0:
             mlp = MoeMlp(spec.n_experts, spec.d_ff, spec.capacity_factor,
                          spec.compute_dtype, top_k=spec.moe_top_k, name="moe")
@@ -904,6 +993,23 @@ class Transformer(nn.Module):
     moe_held: tuple[int, int] | None = None  # moe_impl="grouped": (first,
     #   count), the experts this model holds of the n_experts it routes
     #   over (expert parallelism's share); None = all
+    moe_activation: str = "relu"   # moe_impl="grouped": the gate's function,
+    #   "relu" (ReGLU) or "silu" (SwiGLU)
+    moe_router_input: str = "attn_input"  # moe_impl="grouped": what the
+    #   router reads, "attn_input" (the attention's normalised input) or
+    #   "mlp_input" (the expert layer's own, norm2 of the residual)
+    qk_norm: bool = False          # an RMSNorm a head on q and on k, before
+    #   the rotary, each with a scale of its own (head_dim wide)
+    attn_select_top_k: int | None = None  # attention that SELECTS its keys
+    #   (tpunet.ops.dsa_attention): an indexer scores every earlier key for
+    #   every query, the k best are kept and the softmax runs over those
+    #   alone; None = every key the positions allow. attn_impl "flash" (the
+    #   kernels) or "reference"; training and full-sequence scoring, decode
+    #   raises
+    attn_index_heads: int = 0      # the indexer's query heads, over ONE key head
+    attn_index_head_dim: int = 0   # and their size
+    index_loss_weight: float = 1.0  # what the train step's loss adds of the
+    #   indexer's own loss (the mean over the layers of `dsa_index_loss`)
 
     @nn.nowrap
     def layer_specs(self) -> tuple[LayerSpec, ...]:

@@ -155,6 +155,13 @@ def multi_head_targets(labels, n_heads: int):
     return jnp.take(labels, jnp.minimum(at, s - 1), axis=-1), at < s
 
 
+def _sown(mut, name: str) -> list:
+    """The values sown under `name`, one a layer that sowed it."""
+    return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(
+        mut.get("intermediates", {}))
+        if any(getattr(k, "key", None) == name for k in path)]
+
+
 def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
                   fused_xent_block: int | None = None,
                   z_loss: float = 0.0):
@@ -173,6 +180,9 @@ def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
     but the head's TP speedup is lost; prefer the default path when the lm
     head is tensor-parallel."""
     has_moe = getattr(model, "n_experts", 0) > 0
+    # a model whose attention selects its keys sows its indexer's loss
+    has_index = getattr(model, "attn_select_top_k", None) is not None
+    sows = has_moe or has_index
     if fused_xent_block is not None and getattr(model, "tp_axis", None):
         import warnings
 
@@ -198,10 +208,10 @@ def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
     def loss_fn(p):
         out = model.apply(
             {"params": p}, images, train=True, rngs={"dropout": dropout_rng},
-            mutable=["intermediates"] if has_moe else False,
+            mutable=["intermediates"] if sows else False,
             **({"features_only": True} if fused else {}),
         )
-        out, mut = out if has_moe else (out, None)
+        out, mut = out if sows else (out, None)
         if fused:
             from tpunet.ops import blockwise_cross_entropy
 
@@ -230,15 +240,15 @@ def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
         if has_moe:
             # flax wraps sown values in tuples: sum leaves on matching paths
             # and average over MoE blocks.
-            aux = [
-                leaf
-                for path, leaf in jax.tree_util.tree_leaves_with_path(
-                    mut.get("intermediates", {})
-                )
-                if any(getattr(k, "key", None) == "moe_aux_loss" for k in path)
-            ]
+            aux = _sown(mut, "moe_aux_loss")
             if aux:
                 loss = loss + moe_aux_weight * (sum(aux) / len(aux)).astype(loss.dtype)
+        if has_index:
+            # the indexer's own loss, the mean over the layers that sow one,
+            # at the model's weight; its gradient reaches the indexers alone
+            index = _sown(mut, "dsa_index_loss")
+            loss = loss + model.index_loss_weight * (
+                sum(index) / len(index)).astype(loss.dtype)
         return loss
 
     return loss_fn
