@@ -402,6 +402,105 @@ def test_the_programs_hand_over_a_tuple_of_chunks(world_of_one, chunk_bytes):
                     (step._apply(state, back), []))
 
 
+class _DotModel:
+    """Stands for a model: its logits are (<params, x>, 0), x a tree like the
+    parameters, so at zero parameters every gradient is -x/2 exactly (the
+    -1/2 made at run time), whichever program computes it. Integer leaves
+    take no part: their gradients are float0."""
+
+    def apply(self, variables, x, train=False, rngs=None, mutable=False):
+        import jax.numpy as jnp
+
+        z = sum(jnp.vdot(w, v) for w, v in zip(jax.tree.leaves(variables["params"]),
+                                               jax.tree.leaves(x), strict=True)
+                if jnp.issubdtype(w.dtype, jnp.inexact))
+        return jnp.stack([z, jnp.zeros_like(z)])[None]
+
+
+# name: (shape, dtype) of each parameter, the grad_compression, and the chunks'
+# sizes at CHUNKED bytes a chunk
+_MIXED = {"a": ((1000,), "float32"), "b": ((300,), "float32"), "c": ((10, 10), "float32"),
+          "d": ((50,), "float32"), "e": ((4, 50), "float32"), "f": ((1500,), "float32")}
+CUT_CASES = {
+    # a spans chunks 0 to 2
+    "leaf-over-three-chunks": ({"a": ((2500,), "float32"), "b": ((40, 30), "float32")},
+                               None, (1024, 1024, 1024, 628)),
+    # chunk 1: the end of b, all of c, d and e, the start of f
+    "whole-leaves-and-two-parts": (_MIXED, None, (1024, 1024, 1024, 78)),
+    # QLoRA's frozen int8 base: a float0 gradient between two that are cut
+    "float0-leaf": ({"a": ((1000,), "float32"), "b": ((64,), "int8"),
+                     "c": ((600,), "float32")}, None, (1024, 576)),
+    "bf16": (_MIXED, "bf16", (2048, 1102)),
+    # ravel_pytree promotes: the bfloat16 leaf crosses as float32
+    "mixed-dtypes": ({"a": ((1000,), "bfloat16"), "b": ((1500,), "float32")},
+                     None, (1024, 1024, 452)),
+    "under-one-chunk": ({"a": ((100,), "float32"), "b": ((20, 3), "float32")},
+                        None, (160,)),
+}
+
+
+@pytest.mark.parametrize("case", list(CUT_CASES))
+def test_the_chunks_are_the_raveled_vectors_split(world_of_one, monkeypatch, case):
+    """The grad program cuts each chunk from slices of the leaves (PR 34):
+    what it hands over is, element for element, the split of the vector
+    ravel_pytree makes of them."""
+    import jax.numpy as jnp
+    import optax
+    from jax.flatten_util import ravel_pytree
+
+    from tpunet import interop
+    from tpunet.interop import boundary_chunks
+    from tpunet.train import TrainState, make_train_step
+    from tpunet.train.trainer import _value_and_grads
+
+    layout, compression, sizes = CUT_CASES[case]
+    monkeypatch.setattr(interop, "_CHUNK_BYTES", CHUNKED)
+    params = {k: jnp.zeros(shape, dtype) for k, (shape, dtype) in layout.items()}
+    x = {k: jax.random.normal(jax.random.PRNGKey(i), shape).astype(dtype)
+         for i, (k, (shape, dtype)) in enumerate(layout.items())}
+    labels, key = jnp.zeros((1,), jnp.int32), jax.random.PRNGKey(0)
+    step = make_train_step(_DotModel(), optax.sgd(0.1), cross_host=True,
+                           grad_compression=compression)
+    _, chunks = step._grad(TrainState(params, (), jnp.zeros((), jnp.int32)),
+                           x, labels, key)
+
+    _, grads = _value_and_grads(_DotModel(), params, x, labels, key, 0.01, None, None)
+    flat, _ = ravel_pytree([g for g in jax.tree.leaves(grads)
+                            if g.dtype != jax.dtypes.float0])
+    if compression:
+        flat = flat.astype(jnp.bfloat16)
+    assert boundary_chunks(flat.size, flat.dtype.itemsize, 1) == sizes
+    want = jnp.split(flat, np.cumsum(sizes)[:-1])
+    assert [c.shape for c in chunks] == [(n,) for n in sizes]
+    for got, w in zip(chunks, want, strict=True):
+        assert got.dtype == w.dtype and np.asarray(got).tobytes() == np.asarray(w).tobytes()
+    assert np.any(np.asarray(flat) != 0)
+
+
+def test_the_cut_makes_no_vector_sized_temporaries(monkeypatch):
+    """VGG16's 32 leaves (553 MB of float32) over two ranks at the shipped
+    chunk size, the gradient scaled at run time. Joined into one vector and
+    split, the grad program needed 0.89 GB of temporaries (PERF.md, PR 31):
+    a fresh mapping every step. Cut from the leaves, 58 MB (PR 34)."""
+    import jax.numpy as jnp
+    import optax
+
+    from tpunet import distributed
+    from tpunet.models import vgg16
+    from tpunet.train import TrainState, make_train_step
+
+    monkeypatch.setattr(distributed, "world_size", lambda: 2)
+    params = jax.eval_shape(lambda: vgg16().init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 224, 224, 3)))["params"])
+    assert sum(x.size for x in jax.tree.leaves(params)) == 138_357_544
+    step = make_train_step(_DotModel(), optax.sgd(0.1), cross_host=True)
+    state = TrainState(params, (), jax.ShapeDtypeStruct((), jnp.int32))
+    grad = step._grad.lower(state, params, jax.ShapeDtypeStruct((1,), jnp.int32),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
+    assert len(grad.output_shardings[1]) == 17  # the cells' cut
+    assert grad.memory_analysis().temp_size_in_bytes < 128 * 10**6
+
+
 def test_lowered_and_compiled_is_callable_and_holds_both_programs(world_of_one,
                                                                   chunk_bytes):
     from tpunet.train import make_train_step

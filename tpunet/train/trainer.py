@@ -6,8 +6,8 @@ Design (TPU-first):
     — XLA fuses elementwise ops into the matmuls and inserts ICI collectives
     from the array shardings (batch over `dp`, Megatron-split classifier
     over `mdl`).
-  * Cross-host gradient sync flattens the whole gradient pytree into ONE
-    contiguous vector before the DCN all-reduce (`ravel_pytree`), so the
+  * Cross-host gradient sync all-reduces the whole gradient pytree as ONE
+    contiguous vector (the leaves raveled in order), so the
     multi-stream transport stripes a single large message instead of
     dribbling per-layer buffers — the same bucketing insight behind the
     reference's fairness design (large chunked messages saturate parallel
@@ -349,11 +349,12 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     the exchange between them (`_BoundaryStep`, called like the jitted step;
     `.lower(*args).compile()` compiles both ahead of time, `.as_text()` of
     the result is both programs' text):
-      1. grad: forward and backward, the gradient raveled into one flat
-         vector and cut into contiguous chunks (static shapes, from the
-         vector's bytes and the world size: interop.boundary_chunks; a
-         vector under one chunk stays whole); returns (loss, chunks).
-         Nothing is donated: the apply program still needs `state`.
+      1. grad: forward and backward, then the contiguous chunks of the
+         gradient's flat vector (static shapes, from its bytes and the world
+         size: interop.boundary_chunks; a vector under one chunk stays
+         whole), each joined from slices of the leaves it overlaps: the
+         vector itself is never formed (_boundary_cut). Returns (loss,
+         chunks); nothing is donated: the apply program still needs `state`.
       2. on the host, tpunet.interop.host_all_reduce(chunks): the chunks'
          copies to the host run a few ahead, a
          Communicator.all_reduce(sum) a chunk over the
@@ -373,7 +374,7 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     runs, and outside a program its bytes move by the runtime's plain array
     transfers instead of an `io_callback`'s host-transfer operations.
 
-    grad_compression="bf16" casts the flattened gradient vector to bfloat16
+    grad_compression="bf16" casts the gradient's chunks to bfloat16
     before the cross-host all-reduce and back after — halving DCN bytes for
     ~1 ulp of bf16 noise on already-noisy SGD gradients (the reference has
     no compression; its parent project's QAdam/bytegrad live a layer above —
@@ -397,7 +398,6 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     if cross_host:
         # Import here so single-host training never touches the transport.
         from tpunet import distributed
-        from tpunet.interop import boundary_chunks
 
         world = distributed.world_size()  # raises early if initialize() was skipped
         # One cast path: when the wire already compresses to bf16, ship f32
@@ -429,14 +429,11 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
 
     def grad_program(state: TrainState, images, labels, dropout_rng):
         loss, grads = value_and_grads(state, images, labels, dropout_rng)
-        # ravel_pytree cannot flatten float0 leaves (QLoRA's frozen int8
-        # base under allow_int): they carry no gradient and stay behind.
-        flat, _ = ravel_pytree([g for g in jax.tree.leaves(grads)
-                                if g.dtype != jax.dtypes.float0])
-        if grad_compression == "bf16":
-            flat = flat.astype(jnp.bfloat16)
-        sizes = boundary_chunks(flat.size, flat.dtype.itemsize, world)
-        return loss, tuple(jnp.split(flat, np.cumsum(sizes)[:-1]))
+        # float0 leaves (QLoRA's frozen int8 base under allow_int) carry no
+        # gradient and stay behind.
+        return loss, _boundary_cut([g for g in jax.tree.leaves(grads)
+                                    if g.dtype != jax.dtypes.float0],
+                                   grad_compression, world)
 
     def apply_program(state: TrainState, reduced):
         # A gradient has its parameter's shape and dtype, and an integer
@@ -459,6 +456,32 @@ def make_train_step(model, tx, cross_host: bool = False, donate: bool = True,
     # could not reuse them, and they are dropped when the call returns anyway.
     return _BoundaryStep(jax.jit(grad_program),
                          jax.jit(apply_program, donate_argnums=donated))
+
+
+def _boundary_cut(leaves, compression: str | None, world: int) -> tuple:
+    """The chunks that jnp.split(ravel_pytree(leaves)[0]) at
+    interop.boundary_chunks gives (the leaves' common dtype, bfloat16 under
+    compression), each joined from slices of the leaves it overlaps: the
+    whole vector is never formed. Formed and split, it cost XLA 0.89 GB of
+    temporaries a VGG16 step wherever a chunk spans the end of one large
+    leaf and the start of the next (PERF.md, PR 31 (2) and PR 34)."""
+    from tpunet.interop import boundary_chunks
+
+    # ravel_pytree's dtype: the leaves' dtypes promoted, weak types aside
+    dtype = jnp.result_type(*(leaf.dtype for leaf in leaves))
+    wire = jnp.dtype(jnp.bfloat16 if compression == "bf16" else dtype)
+    starts = np.cumsum([0] + [leaf.size for leaf in leaves]).tolist()
+    chunks, a = [], 0
+    for size in boundary_chunks(starts[-1], wire.itemsize, world):
+        b, pieces = a + size, []
+        for leaf, start, end in zip(leaves, starts, starts[1:]):
+            lo, hi = max(a, start), min(b, end)
+            if lo < hi:
+                piece = leaf.reshape(-1)[lo - start:hi - start]
+                pieces.append(piece.astype(dtype).astype(wire))
+        chunks.append(pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces))
+        a = b
+    return tuple(chunks)
 
 
 class _BoundaryStep:
