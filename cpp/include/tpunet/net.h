@@ -94,6 +94,11 @@ struct SocketHandle {
 };
 static_assert(sizeof(sockaddr_in6) <= kHandleSize, "handle must fit sockaddr");
 
+// Element types and operators of the reduction kernels (utils.h ReduceInto)
+// and of a receive that reduces as it lands (Net::irecv_reduce).
+enum class WireDType : uint8_t { kF32 = 0, kF64, kBF16, kI32, kI64, kU8 };
+enum class WireRedOp : uint8_t { kSum = 0, kProd, kMin, kMax };
+
 // Abstract transport. All ids are process-local opaque tokens. Thread-safety:
 // all methods may be called concurrently from different threads; `accept`
 // blocks until a peer connects.
@@ -120,6 +125,20 @@ class Net {
   // The posted recv buffer may be larger than the incoming message; the actual
   // size comes from the ctrl-stream length frame and is reported by test().
   virtual Status irecv(uint64_t recv_comm, void* data, size_t nbytes, uint64_t* request) = 0;
+  // A receive that REDUCES as it lands: dst[i] = local[i] op incoming[i]
+  // over the message's elements, in ReduceInto's operand order (`local` may
+  // equal `dst`), so the result is bit for bit a plain receive followed by
+  // ReduceInto(dst, local, received). Waited and tested like any request;
+  // the bytes it reports are the message's. An engine that cannot land a
+  // message this way on `recv_comm` returns kInvalidArgument without
+  // posting anything, and the caller receives and reduces itself.
+  virtual Status irecv_reduce(uint64_t recv_comm, void* dst, const void* local,
+                              size_t nbytes, WireDType dtype, WireRedOp op,
+                              uint64_t* request) {
+    (void)recv_comm, (void)dst, (void)local, (void)nbytes, (void)dtype, (void)op,
+        (void)request;
+    return Status::Invalid("irecv_reduce is not supported on this comm");
+  }
   // Poll a request. On done=true the request id is consumed (freed).
   virtual Status test(uint64_t request, bool* done, size_t* nbytes) = 0;
   // Block until the request settles, then consume it like a done test().

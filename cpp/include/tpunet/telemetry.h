@@ -173,6 +173,9 @@ struct MetricsSnapshot {
   // (bytes/wakeup is the ring's syscalls/MiB analogue).
   uint64_t shm_bytes[2] = {0, 0};  // [tx=0, rx=1]
   uint64_t shm_wakeups = 0;
+  // Bytes the SHM receive path reduced as they landed (Net::irecv_reduce);
+  // a part of reduce_bytes.
+  uint64_t shm_reduce_bytes = 0;
   // Serving-tier SLO accounting (docs/DESIGN.md "Serving tier"): per-request
   // time-to-first-token and time-per-output-token histograms fed by the
   // router/decode workers through tpunet_c_serve_observe, plus instantaneous
@@ -286,6 +289,8 @@ class Telemetry {
   // through a ring segment, and futex wake syscalls the ring issued.
   void OnShmBytes(bool is_send, uint64_t nbytes);
   void OnShmWakeup();
+  // Bytes a reducing receive (Net::irecv_reduce) reduced as they landed.
+  void OnShmReduceBytes(uint64_t nbytes);
   // Stage-latency accounting, called by the engines when a successful request
   // is consumed by test()/wait(). Timestamps are MonotonicUs(); completion
   // time is "now". post_us == 0 (no stamp) is ignored.

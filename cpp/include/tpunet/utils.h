@@ -117,8 +117,7 @@ uint32_t Crc32c(const void* data, size_t n, uint32_t crc = 0);
 // (TPUNET_REDUCE_THREADS total shards incl. the caller; 0 = auto), so the
 // reduce of ring chunk k keeps pace with the wire moving chunk k+1.
 // Every call adds n * element-size to the tpunet_reduce_bytes_total counter.
-enum class WireDType : uint8_t { kF32 = 0, kF64, kBF16, kI32, kI64, kU8 };
-enum class WireRedOp : uint8_t { kSum = 0, kProd, kMin, kMax };
+// WireDType and WireRedOp are declared in net.h.
 size_t WireDTypeSize(WireDType d);
 void ReduceInto(void* dst, const void* a, const void* b, size_t n,
                 WireDType dtype, WireRedOp op);

@@ -242,6 +242,9 @@ class ScheduledCommunicator : public Communicator {
     uint64_t send_comm = 0;
     uint64_t recv_comm = 0;
     ScratchBuf scratch;  // chunk landing slots; aligned, never zero-filled
+    // recv_comm said once that it cannot reduce as it lands
+    // (Net::irecv_reduce): ExchangeReduce no longer asks.
+    bool recv_copies_only = false;
   };
 
   ScheduledCommunicator(int rank, int world, WireCodec codec, CollAlgo algo,
@@ -304,6 +307,8 @@ class ScheduledCommunicator : public Communicator {
   // One ring step: recv from prev into recvbuf while sending sendbuf to next.
   Status Exchange(const void* sendbuf, size_t send_nbytes, void* recvbuf,
                   size_t recv_nbytes, size_t* got, RingChannel& ch);
+  Status ExchangePosted(uint64_t rreq, const void* sendbuf, size_t send_nbytes,
+                        size_t recv_nbytes, size_t* got, RingChannel& ch);
   Status DrainSends(std::vector<uint64_t>& reqs, Status primary);
   size_t CodecChunkElems() const;
 
@@ -429,6 +434,15 @@ class ScheduledCommunicator : public Communicator {
   Status PostSend(uint64_t comm, const void* buf, size_t nbytes, uint64_t* req) {
     return InPart("coll.wait_wire", "send",
                   [&] { return net_->isend(comm, buf, nbytes, req); });
+  }
+  // A recv that reduces as it lands (accum = local op incoming); an error,
+  // with nothing posted, where the comm cannot (Net::irecv_reduce).
+  Status PostRecvReduce(uint64_t comm, uint8_t* accum, const uint8_t* local,
+                        size_t nbytes, DType dtype, RedOp op, uint64_t* req) {
+    return InPart("coll.wait_wire", "recv", [&] {
+      return net_->irecv_reduce(comm, accum, local, nbytes, ToWireDType(dtype),
+                                ToWireRedOp(op), req);
+    });
   }
   Status WaitRecv(uint64_t req, size_t* nbytes) { return Wait(req, nbytes, true); }
   Status WaitSend(uint64_t req) { return Wait(req, nullptr, false); }

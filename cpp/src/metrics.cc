@@ -272,6 +272,7 @@ struct Telemetry::Impl {
   // memory").
   std::atomic<uint64_t> shm_bytes[2] = {};
   std::atomic<uint64_t> shm_wakeups{0};
+  std::atomic<uint64_t> shm_reduce_bytes{0};
 
   // Fairness window (win_mu): Jain's index over per-stream byte deltas
   // between rolls. Rolled lazily from Snapshot() at most once per
@@ -747,6 +748,10 @@ void Telemetry::OnShmWakeup() {
   impl_->shm_wakeups.fetch_add(1, std::memory_order_relaxed);
 }
 
+void Telemetry::OnShmReduceBytes(uint64_t nbytes) {
+  impl_->shm_reduce_bytes.fetch_add(nbytes, std::memory_order_relaxed);
+}
+
 namespace {
 thread_local uint64_t t_consumed_first_wire_us = 0;
 }  // namespace
@@ -972,6 +977,7 @@ void Telemetry::Reset() {
   im->shm_bytes[0].store(0, std::memory_order_relaxed);
   im->shm_bytes[1].store(0, std::memory_order_relaxed);
   im->shm_wakeups.store(0, std::memory_order_relaxed);
+  im->shm_reduce_bytes.store(0, std::memory_order_relaxed);
   for (int i = 0; i < kFaultActionSlots; ++i) {
     im->faults_injected[i].store(0, std::memory_order_relaxed);
   }
@@ -1104,6 +1110,7 @@ MetricsSnapshot Telemetry::Snapshot() const {
   s.shm_bytes[0] = im->shm_bytes[0].load(std::memory_order_relaxed);
   s.shm_bytes[1] = im->shm_bytes[1].load(std::memory_order_relaxed);
   s.shm_wakeups = im->shm_wakeups.load(std::memory_order_relaxed);
+  s.shm_reduce_bytes = im->shm_reduce_bytes.load(std::memory_order_relaxed);
   s.straggler_events = im->straggler_events.load(std::memory_order_relaxed);
   s.isend_count = im->isend_count.load(std::memory_order_relaxed);
   s.irecv_count = im->irecv_count.load(std::memory_order_relaxed);
@@ -1400,6 +1407,12 @@ std::string Telemetry::PrometheusText() const {
          "should wake rarely).");
   emit("tpunet_shm_wakeups_total{rank=\"%lld\"} %llu\n", (long long)rank,
        (unsigned long long)s.shm_wakeups);
+  family("tpunet_shm_reduce_bytes_total", "counter",
+         "Bytes the SHM receive path reduced as they landed (Net::irecv_reduce: "
+         "straight out of the ring, or from its bounce buffer); also counted "
+         "in tpunet_reduce_bytes_total.");
+  emit("tpunet_shm_reduce_bytes_total{rank=\"%lld\"} %llu\n", (long long)rank,
+       (unsigned long long)s.shm_reduce_bytes);
   // Request stage-latency histograms: queueing delay separable from wire time.
   auto stage_hist = [&](const char* name, const char* help, const StageHist& h) {
     family(name, "histogram", help);
@@ -1844,6 +1857,12 @@ class TelemetryNet : public Net {
   }
   Status irecv(uint64_t comm, void* data, size_t n, uint64_t* req) override {
     Status s = inner_->irecv(comm, data, n, req);
+    if (s.ok()) Telemetry::Get().OnRequestStart(Owner(), false, comm, *req, n);
+    return s;
+  }
+  Status irecv_reduce(uint64_t comm, void* dst, const void* local, size_t n,
+                      WireDType dtype, WireRedOp op, uint64_t* req) override {
+    Status s = inner_->irecv_reduce(comm, dst, local, n, dtype, op, req);
     if (s.ok()) Telemetry::Get().OnRequestStart(Owner(), false, comm, *req, n);
     return s;
   }

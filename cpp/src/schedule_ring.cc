@@ -252,6 +252,12 @@ Status ScheduledCommunicator::DoBroadcastRing(void* buf, size_t nbytes, int root
 // nullptr = accum itself (the classic in-place accumulate). A distinct
 // local lets out-of-place collectives read the caller's sendbuf directly
 // and write partials straight into recvbuf — no staging copy anywhere.
+//
+// Where the recv comm reduces as it lands (Net::irecv_reduce: the SHM
+// engine's own comms), every recv is posted that way straight into
+// `accum`: no scratch landing and no Reduce here, and the reduce's time
+// lies inside the recv wait (coll.wait_wire). Sends and chunk sizes are the
+// same either way, so the peer cannot tell which path this rank took.
 Status ScheduledCommunicator::ExchangeReduce(const uint8_t* sendbuf, size_t send_nbytes,
                                              uint8_t* accum, size_t recv_nbytes,
                                              DType dtype, RedOp op, RingChannel& ch,
@@ -264,6 +270,13 @@ Status ScheduledCommunicator::ExchangeReduce(const uint8_t* sendbuf, size_t send
   size_t esize = DTypeSize(dtype);
   size_t chunk = RingChunkBytes() / esize * esize;
   if (chunk == 0 || (send_nbytes <= chunk && recv_nbytes <= chunk)) {
+    if (!ch.recv_copies_only) {
+      uint64_t rreq = 0;
+      if (PostRecvReduce(ch.recv_comm, accum, local, recv_nbytes, dtype, op, &rreq).ok()) {
+        return ExchangePosted(rreq, sendbuf, send_nbytes, recv_nbytes, nullptr, ch);
+      }
+      ch.recv_copies_only = true;
+    }
     ch.scratch.reserve(recv_nbytes);
     Status st = Exchange(sendbuf, send_nbytes, ch.scratch.data(), recv_nbytes, nullptr, ch);
     if (!st.ok()) return st;
@@ -277,17 +290,27 @@ Status ScheduledCommunicator::ExchangeReduce(const uint8_t* sendbuf, size_t send
   size_t ns = (send_nbytes + chunk - 1) / chunk;
   size_t nr = (recv_nbytes + chunk - 1) / chunk;
   size_t n = std::max(ns, nr);
-  ch.scratch.reserve(2 * chunk);
   auto slen = [&](size_t i) { return std::min(chunk, send_nbytes - i * chunk); };
   auto rlen = [&](size_t i) { return std::min(chunk, recv_nbytes - i * chunk); };
 
   uint64_t rreq[2] = {0, 0}, sreq[2] = {0, 0};
   bool rlive[2] = {false, false}, slive[2] = {false, false};
+  bool landed = false;  // the first recv post decides: the comm reduces as it lands
   auto post = [&](size_t i) -> Status {
     int slot = i & 1;
     if (i < nr) {
-      Status st =
-          PostRecv(ch.recv_comm, ch.scratch.data() + slot * chunk, rlen(i), &rreq[slot]);
+      Status st;
+      if (i == 0 && !ch.recv_copies_only) {
+        landed = PostRecvReduce(ch.recv_comm, accum, local, rlen(0), dtype, op, &rreq[slot]).ok();
+        ch.recv_copies_only = !landed;
+      } else if (landed) {
+        st = PostRecvReduce(ch.recv_comm, accum + i * chunk, local + i * chunk, rlen(i),
+                            dtype, op, &rreq[slot]);
+      }
+      if (!landed) {
+        ch.scratch.reserve(2 * chunk);
+        st = PostRecv(ch.recv_comm, ch.scratch.data() + slot * chunk, rlen(i), &rreq[slot]);
+      }
       if (!st.ok()) return st;
       rlive[slot] = true;
     }
@@ -327,7 +350,7 @@ Status ScheduledCommunicator::ExchangeReduce(const uint8_t* sendbuf, size_t send
       st = post(i + 1);  // keep the wire busy while we reduce chunk i
       if (!st.ok()) return quiesce(st);
     }
-    if (has_r) {
+    if (has_r && !landed) {
       Reduce(accum + i * chunk, local + i * chunk,
              ch.scratch.data() + slot * chunk, rlen(i) / esize, dtype, op);
     }
@@ -552,10 +575,19 @@ Status ScheduledCommunicator::AgPhaseCodec(float* data, size_t count, RingChanne
 Status ScheduledCommunicator::Exchange(const void* sendbuf, size_t send_nbytes,
                                        void* recvbuf, size_t recv_nbytes,
                                        size_t* got, RingChannel& ch) {
-  uint64_t rreq = 0, sreq = 0;
+  uint64_t rreq = 0;
   Status st = PostRecv(ch.recv_comm, recvbuf, recv_nbytes, &rreq);
   if (!st.ok()) return st;
-  st = PostSend(ch.send_comm, sendbuf, send_nbytes, &sreq);
+  return ExchangePosted(rreq, sendbuf, send_nbytes, recv_nbytes, got, ch);
+}
+
+// The rest of a ring step whose recv `rreq` (of recv_nbytes) is posted: the
+// send, then both waits, as Exchange.
+Status ScheduledCommunicator::ExchangePosted(uint64_t rreq, const void* sendbuf,
+                                             size_t send_nbytes, size_t recv_nbytes,
+                                             size_t* got, RingChannel& ch) {
+  uint64_t sreq = 0;
+  Status st = PostSend(ch.send_comm, sendbuf, send_nbytes, &sreq);
   if (!st.ok()) {
     WaitRecv(rreq, nullptr);  // quiesce the posted recv before unwinding
     return st;

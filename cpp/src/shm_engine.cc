@@ -37,6 +37,11 @@
 //     counters, which is what lets tests prove "intra-host stage moved zero
 //     TCP bytes" straight off the counters.
 //
+//   * A receive may REDUCE as it lands (Net::irecv_reduce, posted by the
+//     ring's reduce-scatter steps): the receive thread folds each chunk
+//     straight out of the ring into the caller's accumulator (RingReducer),
+//     so the bytes are never copied into a scratch buffer first.
+//
 //   * Failure containment composes unchanged: fault injection acts on the
 //     segment (fault.h FaultPreMem — corrupt flips a ring byte under the
 //     original-bytes CRC, stall parks against the abort flag, delay
@@ -65,6 +70,7 @@
 #include <chrono>
 #include <deque>
 #include <memory>
+#include <optional>
 #include <set>
 #include <string>
 #include <thread>
@@ -194,6 +200,11 @@ struct ShmMsg {
   uint8_t* data = nullptr;
   size_t len = 0;
   RequestPtr state;
+  // A receive that reduces as it lands (ShmEngine::irecv_reduce): data =
+  // local op incoming. Null for every other message.
+  const uint8_t* local = nullptr;
+  WireDType dtype = WireDType::kU8;
+  WireRedOp op = WireRedOp::kSum;
 };
 
 // Blocking FIFO identical in spirit to the BASIC engine's Queue.
@@ -278,6 +289,9 @@ struct ShmComm {
     uint64_t ring_end = 0;
   };
   std::vector<Deferred> deferred;  // scheduler-thread-private
+  // Recv side: where a reducing receive's chunk lands when it cannot be
+  // reduced straight out of the ring (RingReducer). Scheduler-thread-private.
+  ScratchBuf bounce;
   const uint64_t fork_gen = ForkGeneration();
 
   const std::atomic<bool>* aborted_flag() const { return &aborted; }
@@ -758,6 +772,70 @@ Status RecvChunkCtrl(ShmComm* c, uint8_t* data, size_t n, uint32_t* wire_crc) {
   return s;
 }
 
+// Lands the chunks of a reducing receive (ShmEngine::irecv_reduce) in
+// m.data = m.local op incoming, in ReduceInto's operand order, so the result
+// is bit for bit a copy out of the ring and a separate reduce. Whole elements
+// are reduced straight out of the ring where its bytes lie on element
+// boundaries; a chunk that cannot be (CRC on, a fault armed, the ctrl path)
+// lands in the comm's bounce buffer and is reduced from there. The bytes of
+// an element that a chunk boundary cuts wait at the bounce's head.
+class RingReducer {
+ public:
+  RingReducer(ShmComm* c, const ShmMsg& m)
+      : dst_(m.data), local_(m.local), dtype_(m.dtype), op_(m.op),
+        esize_(WireDTypeSize(m.dtype)) {
+    c->bounce.reserve(c->chunk + esize_);
+    bounce_ = c->bounce.data();
+  }
+
+  // Reduces the n ring bytes at cursor `at`; false, with nothing touched,
+  // where an element would be read off its boundary or across the wrap.
+  bool FromRing(const ShmSeg& seg, uint64_t at, size_t n) {
+    const size_t pos = static_cast<size_t>(at % seg.ring_bytes);
+    const size_t first = std::min(n, seg.ring_bytes - pos);
+    if (pend_ != 0 || pos % esize_ != 0 || (first < n && first % esize_ != 0)) {
+      return false;
+    }
+    Take(seg.ring + pos, first);
+    if (first < n) Take(seg.ring, n - first);
+    return true;
+  }
+
+  // Where the next chunk lands when FromRing declined it; Landed(n) then
+  // reduces its n bytes.
+  uint8_t* Slot() { return bounce_ + pend_; }
+  void Landed(size_t n) {
+    const size_t total = pend_ + n;
+    const size_t whole = total - total % esize_;
+    Reduce(bounce_, whole);
+    memmove(bounce_, bounce_ + whole, total - whole);
+    pend_ = total - whole;
+  }
+
+ private:
+  void Take(const uint8_t* src, size_t n) {
+    const size_t whole = n - n % esize_;
+    Reduce(src, whole);
+    memcpy(bounce_, src + whole, n - whole);
+    pend_ = n - whole;
+  }
+  void Reduce(const uint8_t* src, size_t nbytes) {
+    if (nbytes == 0) return;
+    ReduceInto(dst_ + done_, local_ + done_, src, nbytes / esize_, dtype_, op_);
+    Telemetry::Get().OnShmReduceBytes(nbytes);
+    done_ += nbytes;
+  }
+
+  uint8_t* dst_;
+  const uint8_t* local_;
+  WireDType dtype_;
+  WireRedOp op_;
+  size_t esize_;
+  uint8_t* bounce_;
+  size_t done_ = 0;  // message bytes reduced
+  size_t pend_ = 0;  // bytes of a cut element at the bounce's head
+};
+
 Status RecvOneShmMsg(ShmComm* c, const ShmMsg& m) {
   uint8_t hdr8[8];
   Status s = ReadExact(c->ctrl_fd, hdr8, sizeof(hdr8));
@@ -773,6 +851,8 @@ Status RecvOneShmMsg(ShmComm* c, const ShmMsg& m) {
   }
   size_t len = static_cast<size_t>(target);
   size_t nchunks = ChunkCount(len, c->chunk);
+  std::optional<RingReducer> reducer;
+  if (m.local != nullptr) reducer.emplace(c, m);
   size_t off = 0;
   for (size_t i = 0; i < nchunks; ++i) {
     size_t n = std::min(c->chunk, len - off);
@@ -817,10 +897,16 @@ Status RecvOneShmMsg(ShmComm* c, const ShmMsg& m) {
         from_ring = false;
       }
     }
+    // A copied chunk lands in the caller's buffer, or in the bounce buffer
+    // of a reducing receive.
+    uint8_t* land = reducer ? reducer->Slot() : m.data + off;
+    bool reduced = false;
     m.state->MarkWireStart(MonotonicUs());
     if (from_ring) {
       uint64_t tail = c->seg.hdr->tail.load(std::memory_order_relaxed);
-      c->seg.CopyOut(tail, m.data + off, n);
+      reduced = reducer && !c->crc && fa == FaultAction::kNone &&
+                reducer->FromRing(c->seg, tail, n);
+      if (!reduced) c->seg.CopyOut(tail, land, n);
       if (c->crc) {
         uint8_t crcb[4];
         c->seg.CopyOut(tail + n, crcb, 4);
@@ -828,13 +914,13 @@ Status RecvOneShmMsg(ShmComm* c, const ShmMsg& m) {
       }
       c->seg.Consume(tail + wire_len);
     } else {
-      s = RecvChunkCtrl(c, m.data + off, n, &wire_crc);
+      s = RecvChunkCtrl(c, land, n, &wire_crc);
       if (!s.ok()) return s;
     }
     if (fa == FaultAction::kCorrupt && n > 0) {
-      m.data[off + n / 2] ^= 0x01;  // wire damage before verification
+      land[n / 2] ^= 0x01;  // wire damage before verification
     }
-    if (c->crc && wire_crc != Crc32c(m.data + off, n)) {
+    if (c->crc && wire_crc != Crc32c(land, n)) {
       // Integrity failure is a REQUEST error, not a disconnect: the chunk
       // framing is intact (exactly chunk+trailer was consumed), so the
       // comm keeps working for subsequent messages — the socket engines'
@@ -843,10 +929,16 @@ Status RecvOneShmMsg(ShmComm* c, const ShmMsg& m) {
       m.state->SetError(ErrorKind::kCorruption,
                         "CRC32C mismatch on shm segment: payload corrupted "
                         "in transit");
-    } else if (from_ring) {
-      Telemetry::Get().OnShmBytes(false, n);
     } else {
-      Telemetry::Get().OnStreamBytes(false, 0, n, static_cast<int>(c->cls));
+      // A failed request reduces nothing more: its chunks keep the framing.
+      if (reducer && !reduced && !m.state->failed.load(std::memory_order_acquire)) {
+        reducer->Landed(n);
+      }
+      if (from_ring) {
+        Telemetry::Get().OnShmBytes(false, n);
+      } else {
+        Telemetry::Get().OnStreamBytes(false, 0, n, static_cast<int>(c->cls));
+      }
     }
     m.state->nbytes.fetch_add(n, std::memory_order_relaxed);
     m.state->MarkWireEnd(MonotonicUs());
@@ -1097,24 +1189,21 @@ class ShmEngine : public EngineBase {
       if (s.ok()) *request |= kInnerIdBit;
       return s;
     }
-    ShmCommPtr c;
-    if (!recv_comms_.Get(recv_comm, &c)) {
-      return Status::Invalid("unknown recv comm " + std::to_string(recv_comm));
+    return PostRecv(recv_comm, ShmMsg{static_cast<uint8_t*>(data), nbytes, nullptr}, request);
+  }
+
+  // The receive thread reduces each chunk as it lands (RingReducer); the
+  // inner engine's comms cannot.
+  Status irecv_reduce(uint64_t recv_comm, void* dst, const void* local, size_t nbytes,
+                      WireDType dtype, WireRedOp op, uint64_t* request) override {
+    if ((recv_comm & kInnerIdBit) || WireDTypeSize(dtype) == 0) {
+      return Net::irecv_reduce(recv_comm, dst, local, nbytes, dtype, op, request);
     }
-    if (ForkGeneration() != c->fork_gen) {
-      return Status::Inner("recv comm created before fork(); its threads do not exist here");
-    }
-    auto state = std::make_shared<RequestState>();
-    state->t_post_us = MonotonicUs();
-    state->total.store(1, std::memory_order_release);
-    ArmWatchdog(state, c);
-    uint64_t id = next_id_.fetch_add(1);
-    requests_.Put(id, state);
-    if (!c->msgs.Push(ShmMsg{static_cast<uint8_t*>(data), nbytes, state})) {
-      FailShmMsg(c.get(), state, ErrorKind::kInnerError, "recv comm is poisoned");
-    }
-    *request = id;
-    return Status::Ok();
+    ShmMsg m{static_cast<uint8_t*>(dst), nbytes, nullptr};
+    m.local = static_cast<const uint8_t*>(local != nullptr ? local : dst);
+    m.dtype = dtype;
+    m.op = op;
+    return PostRecv(recv_comm, std::move(m), request);
   }
 
   Status test(uint64_t request, bool* done, size_t* nbytes) override {
@@ -1168,6 +1257,28 @@ class ShmEngine : public EngineBase {
   }
 
  private:
+  Status PostRecv(uint64_t recv_comm, ShmMsg m, uint64_t* request) {
+    ShmCommPtr c;
+    if (!recv_comms_.Get(recv_comm, &c)) {
+      return Status::Invalid("unknown recv comm " + std::to_string(recv_comm));
+    }
+    if (ForkGeneration() != c->fork_gen) {
+      return Status::Inner("recv comm created before fork(); its threads do not exist here");
+    }
+    auto state = std::make_shared<RequestState>();
+    state->t_post_us = MonotonicUs();
+    state->total.store(1, std::memory_order_release);
+    ArmWatchdog(state, c);
+    uint64_t id = next_id_.fetch_add(1);
+    requests_.Put(id, state);
+    m.state = state;
+    if (!c->msgs.Push(std::move(m))) {
+      FailShmMsg(c.get(), state, ErrorKind::kInnerError, "recv comm is poisoned");
+    }
+    *request = id;
+    return Status::Ok();
+  }
+
   Status InnerConnect(int32_t dev, const SocketHandle& handle, uint64_t* send_comm) {
     uint64_t inner_id = 0;
     Status s = inner_->connect(dev, handle, &inner_id);

@@ -6,7 +6,8 @@
 // Coverage: all_reduce (sum, with TPUNET_REDUCE_THREADS>1), reduce_scatter,
 // all_gather, broadcast, all_to_all, neighbor_exchange, barrier, and
 // overlapping iall_reduce tickets waited out of order, then teardown while
-// a ticket is still in flight on one rank (wait-then-destroy on the other).
+// a ticket is still in flight on one rank (wait-then-destroy on the other),
+// and an all_reduce over the SHM engine, equal to the TCP engine's bytes.
 
 #include <atomic>
 #include <chrono>
@@ -193,6 +194,33 @@ void schedule_rank_main(int rank, int base_port) {
   }
 }
 
+// The value of a label-less-but-rank counter series in the exposition.
+uint64_t Counter(const char* series) {
+  std::string text(1 << 20, '\0');
+  int32_t len = tpunet_c_metrics_text(&text[0], text.size());
+  if (len < 0) return 0;
+  size_t at = text.find(series);
+  if (at == std::string::npos) return 0;
+  at = text.find("} ", at);
+  return at == std::string::npos ? 0 : std::strtoull(text.c_str() + at + 2, nullptr, 10);
+}
+
+// SHM lane: the same f32 all_reduce over the TCP engine and then over the
+// shared-memory engine, whose receive thread reduces each chunk straight
+// into the caller's accumulator as it lands (Net::irecv_reduce) while the
+// collective thread sends from a disjoint slice. The results must be equal
+// to the byte: the ring's reduction order is the same on both engines.
+void shm_lane_rank_main(int rank, int port, std::vector<float>* out) {
+  std::string coord = "127.0.0.1:" + std::to_string(port);
+  uintptr_t comm = 0;
+  CHECK_OK(tpunet_comm_create_ex(coord.c_str(), rank, kWorld, "f32", "ring", nullptr, &comm));
+  std::vector<float> send(kCount);
+  for (uint64_t i = 0; i < kCount; ++i) send[i] = 0.1f * float(rank + 1) + 0.37f * float(i % 13);
+  out->assign(kCount, 0.0f);
+  CHECK_OK(tpunet_comm_all_reduce(comm, send.data(), out->data(), kCount, 0, 0));
+  CHECK_OK(tpunet_comm_destroy(&comm));
+}
+
 void rank_main(int rank, const std::string& coordinator) {
   uintptr_t comm = 0;
   CHECK_OK(tpunet_comm_create(coordinator.c_str(), rank, kWorld, &comm));
@@ -366,6 +394,38 @@ int main() {
   for (int r = 0; r < kWorld; ++r)
     ranks.emplace_back(schedule_rank_main, r, base_port);
   for (auto& th : ranks) th.join();
+
+  // SHM lane (fresh comms on base_port+9 over TCP, +10 over SHM on a small
+  // ring whose chunks wrap).
+  std::vector<float> by_engine[2][kWorld];
+  for (int shm = 0; shm < 2; ++shm) {
+    if (shm == 1) {
+      setenv("TPUNET_SHM", "1", 1);
+      setenv("TPUNET_SHM_RING_BYTES", "65536", 1);
+    }
+    tpunet_c_metrics_reset();
+    ranks.clear();
+    for (int r = 0; r < kWorld; ++r)
+      ranks.emplace_back(shm_lane_rank_main, r, base_port + 9 + shm, &by_engine[shm][r]);
+    for (auto& th : ranks) th.join();
+    // Every byte the ring reduced landed reduced on SHM, and none on TCP.
+    uint64_t landed = Counter("tpunet_shm_reduce_bytes_total{");
+    uint64_t reduced = Counter("tpunet_reduce_bytes_total{");
+    if (reduced == 0 || landed != (shm == 1 ? reduced : 0)) {
+      std::fprintf(stderr, "FAIL: shm=%d reduced %" PRIu64 " bytes, %" PRIu64 " as they landed\n",
+                   shm, reduced, landed);
+      g_failures.fetch_add(1);
+    }
+  }
+  unsetenv("TPUNET_SHM");
+  unsetenv("TPUNET_SHM_RING_BYTES");
+  for (int r = 0; r < kWorld; ++r) {
+    if (by_engine[0][r] != by_engine[1][r] ||
+        memcmp(by_engine[0][r].data(), by_engine[1][r].data(), kCount * 4) != 0) {
+      std::fprintf(stderr, "FAIL: rank %d SHM all_reduce differs from TCP\n", r);
+      g_failures.fetch_add(1);
+    }
+  }
 
   finished.store(true);
   watchdog.join();

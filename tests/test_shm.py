@@ -276,3 +276,118 @@ def test_shm_close_before_accept_still_delivers(recv_host):
             if p.is_alive():
                 p.kill()
     assert recv_res == "OK", recv_res
+
+
+# ---------------------------------------------------------------------------
+# Ring reduces on the SHM comms: the receive thread folds each chunk into the
+# accumulator as it lands (Net::irecv_reduce), and the result must be the TCP
+# ring's to the byte.
+
+MIB = 1 << 20
+
+
+def _ring_cases(world: int) -> list:
+    """(collective, dtype, op, count, in place): every dtype x op on odd
+    counts, then slices under, at and over the ring's 8 MiB pipeline chunk.
+    `count` is the all_reduce's element count, or reduce_scatter's per rank."""
+    import ml_dtypes
+
+    bf16 = np.dtype(ml_dtypes.bfloat16)
+    cases = []
+    for i, (dt, op) in enumerate((dt, op) for dt in (np.float32, bf16, np.int32)
+                                 for op in ("sum", "max", "prod")):
+        cases.append(("all_reduce", dt, op, world * 5003 + 3, i % 2 == 0))
+        cases.append(("reduce_scatter", dt, op, 4099, False))
+    cases += [
+        ("all_reduce", np.float32, "sum", world * (MIB // 4 + 1) + 1, False),
+        ("all_reduce", np.float32, "prod", world * (2 * MIB), True),
+        ("all_reduce", bf16, "max", world * (4 * MIB), False),
+        ("all_reduce", np.int32, "sum", world * (3 * MIB) + 5, True),
+        ("reduce_scatter", np.float32, "sum", 2 * MIB, False),
+        ("reduce_scatter", bf16, "prod", 5 * MIB + 1, False),
+    ]
+    return cases
+
+
+def _ring_input(rank: int, case_idx: int, dt, op: str, n: int) -> np.ndarray:
+    rng = np.random.default_rng(1000 * case_idx + rank)
+    if np.dtype(dt) == np.int32:
+        lo, hi = (-3, 4) if op == "prod" else (-1000, 1000)
+        return rng.integers(lo, hi, n, dtype=np.int32)
+    x = rng.standard_normal(n, dtype=np.float32)
+    return (1 + x / 64 if op == "prod" else x).astype(dt)
+
+
+def _ring_reduce_bytes(coll: str, count: int, world: int, rank: int, esize: int) -> int:
+    """Bytes this rank's ring reduces: the slices it receives in the
+    reduce-scatter half (cpp/src/schedule_ring.cc)."""
+    if coll == "reduce_scatter":
+        return (world - 1) * count * esize
+    vr = (rank + world - 1) % world
+    ridx = [(vr - s - 1) % world for s in range(world - 1)]
+    return sum((count * (r + 1) // world - count * r // world) * esize for r in ridx)
+
+
+def _ring_worker(rank: int, world: int, port: int, q, mode: str) -> None:
+    try:
+        import hashlib
+
+        os.environ.update(TPUNET_SHM_RING_BYTES="65536", TPUNET_RANK=str(rank),
+                          TPUNET_CRC="1" if mode == "crc" else "0")
+        if mode == "oddchunk":
+            # 10001-byte chunks cut elements: their bytes wait in the bounce.
+            os.environ["TPUNET_MIN_CHUNKSIZE"] = "10001"
+        from tpunet import telemetry, transport
+        from tpunet.collectives import Communicator
+
+        comms = {}
+        for shm, p in (("1", port), ("0", port + 1)):
+            os.environ["TPUNET_SHM"] = shm
+            comms[shm] = Communicator(f"127.0.0.1:{p}", rank, world, algo="ring")
+        if mode == "close" and rank == 1:
+            # The segment to rank 2 fails over to ctrl TCP mid-message.
+            transport.fault_inject("stream=0:side=send:after_bytes=3M:action=close")
+        digests, failovers = {}, 0
+        for shm in ("1", "0"):
+            for i, (coll, dt, op, count, inplace) in enumerate(_ring_cases(world)):
+                n = count * world if coll == "reduce_scatter" else count
+                x = _ring_input(rank, i, dt, op, n)
+                telemetry.reset()
+                if coll == "reduce_scatter":
+                    out = comms[shm].reduce_scatter(x, op=op)
+                else:
+                    out = comms[shm].all_reduce(x, op=op, inplace=inplace)
+                m = telemetry.metrics()
+                landed = sum(m["tpunet_shm_reduce_bytes_total"].values())
+                reduced = sum(m["tpunet_reduce_bytes_total"].values())
+                failovers += sum(m["tpunet_stream_failovers_total"].values())
+                want = _ring_reduce_bytes(coll, count, world, rank, x.itemsize)
+                assert reduced == want, (shm, i, reduced, want)
+                assert landed == (want if shm == "1" else 0), (shm, i, landed, want)
+                digests.setdefault(i, []).append(hashlib.sha256(out.tobytes()).hexdigest())
+            transport.fault_clear()
+        differ = [i for i, (a, b) in digests.items() if a != b]
+        assert not differ, f"SHM and TCP rings differ in cases {differ}"
+        assert failovers == (mode == "close" and rank == 1), failovers
+        for c in comms.values():
+            c.close()
+        q.put((rank, "OK"))
+    except Exception as e:  # noqa: BLE001
+        q.put((rank, f"FAIL: {type(e).__name__}: {e}"))
+
+
+@pytest.mark.parametrize("world,mode", [(2, "plain"), (3, "plain"), (4, "plain"),
+                                        (3, "crc"), (3, "close"), (3, "oddchunk")])
+def test_shm_ring_reduce_lands_as_tcp_does(world, mode):
+    """all_reduce and reduce_scatter over the SHM ring, whose receive thread
+    reduces each chunk as it lands, are byte-identical to the same calls over
+    TCP: f32, bf16 and i32 under sum, max and prod, in place and out of place,
+    odd counts and slices under, at and over the 8 MiB pipeline chunk, on a
+    64 KiB ring whose extents wrap. tpunet_shm_reduce_bytes_total counts
+    every byte the reduce-scatter half reduced on SHM, and none on TCP. The
+    same holds with CRC trailers (every chunk through the bounce buffer),
+    with the segment failed over to ctrl TCP mid-message, and with a chunk
+    size that cuts elements in two."""
+    from conftest import run_spawn_workers
+
+    run_spawn_workers(_ring_worker, world, timeout=240, extra_args=(mode,))
