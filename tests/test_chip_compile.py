@@ -51,8 +51,9 @@ def as_on_chip(monkeypatch):
 
     import tpunet.ops.dsa_attention  # noqa: F401
     import tpunet.ops.grouped_matmul  # noqa: F401
+    import tpunet.ops.ssd_scan  # noqa: F401
 
-    for kernels in ("flash_attention", "grouped_matmul", "dsa_attention"):
+    for kernels in ("flash_attention", "grouped_matmul", "dsa_attention", "ssd_scan"):
         monkeypatch.setattr(sys.modules[f"tpunet.ops.{kernels}"],
                             "_auto_interpret", lambda: False)
     monkeypatch.setattr(sys.modules["tpunet.interop"],
@@ -267,6 +268,42 @@ def test_keye_train_step_has_its_kernels_and_fits(one_chip, as_on_chip):
                         ("dsa_attn_dkv", 1), ("moe_gmm_fwd", 6), ("moe_gmm_dx", 3),
                         ("moe_tgmm_dw", 3)):
         assert _named_kernels(text, name) == layers * count, name
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_nemotron_train_step_has_its_kernels_and_fits(one_chip, as_on_chip):
+    """The benchmark's Nemotron-H configuration through make_train_step at
+    the cell's b2 x s8192: a Mamba block holds ssd_fwd twice (forward and
+    remat's recompute) and ssd_bwd once, an expert block 4 grouped forward
+    kernels and 4 backward ones, an attention block flash's; weights, AdamW
+    state and the step's scratch fit one chip."""
+    import optax
+
+    from perfbench import harness
+    from perfbench.models import nemotron_h
+    from tpunet.train import TrainState, make_train_step
+
+    cfg = harness.load("configs", "nemotron3-super-120b-a12b-tp8-l11")
+    model = nemotron_h.build(cfg, {"remat": True})
+    tx = optax.adamw(3e-4)
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def state_of(t):
+        params = model.init(jax.random.PRNGKey(0), t)["params"]
+        return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+
+    state = jax.eval_shape(state_of, tokens)
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == nemotron_h.params(cfg)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    compiled = make_train_step(model, tx).lower(
+        _on(one_chip, state), tokens, tokens, _on(one_chip, key)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) >= harness.load("workloads", "nemotron-train-s8192")["kernels"]
+    mamba, experts = (nemotron_h.layers_of(cfg, k) for k in "ME")
+    for name, count in (("ssd_fwd", 2 * mamba), ("ssd_bwd", mamba),
+                        ("moe_gmm_fwd", 4 * experts), ("moe_gmm_dx", 2 * experts),
+                        ("moe_tgmm_dw", 2 * experts)):
+        assert _named_kernels(text, name) == count, name
     assert _device_bytes(compiled) < HBM_BYTES
 
 

@@ -10,7 +10,7 @@ import pytest
 from flax.traverse_util import flatten_dict
 
 from tpunet.models import Transformer, transformer_partition_rules
-from tpunet.models.transformer import Block, LayerSpec, SelfAttention
+from tpunet.models.transformer import Block, LayerSpec, Mamba2, SelfAttention
 from tpunet.parallel import batch_sharding, make_named_mesh, replicated, shard_params
 from tpunet.train import TrainState, create_train_state, make_train_step
 
@@ -213,24 +213,26 @@ def test_layer_spec_fields_are_transformer_fields():
     """A block-level field is declared on `Transformer` (default, comment)
     and on `LayerSpec` (name, type), and `layer_specs()` copies it by name:
     every spec field but the derived ones (`head_dim`: the model's or
-    d_model / n_heads; `rotary`: the layer's place in `attn_pattern`) is a
-    model field of the same name and type, and has no default of its own to
-    drift from the model's. The flash tile is `ops.flash_attention._plan`'s,
-    not an option of the model."""
+    d_model / n_heads; `rotary`: the layer's place in `attn_pattern`;
+    `kind`: its place in `layer_pattern` or `mtp_pattern`) is a model field
+    of the same name and type, and has no default of its own to drift from
+    the model's. The flash tile is `ops.flash_attention._plan`'s, not an
+    option of the model."""
     model = _fields(Transformer)
     for f in dataclasses.fields(LayerSpec):
         assert f.default is dataclasses.MISSING, f.name
         assert f.default_factory is dataclasses.MISSING, f.name
-        if f.name not in ("head_dim", "rotary"):
+        if f.name not in ("head_dim", "rotary", "kind"):
             assert f.name in model, f.name
             assert f.type == model[f.name].type, f.name
     assert not [n for n in model if n.startswith("flash_block")]
     assert list(_fields(Block)) == ["spec"]
     assert list(_fields(SelfAttention)) == ["spec"]
+    assert list(_fields(Mamba2)) == ["spec"]
     spec = Transformer(d_model=96, n_heads=4, rope_theta=5e5).layer_specs()[0]
-    assert (spec.head_dim, spec.rope_theta, spec.rotary) == (24, 5e5, True)
+    assert (spec.head_dim, spec.rope_theta, spec.rotary, spec.kind) == (24, 5e5, True, None)
     for f in dataclasses.fields(LayerSpec):  # the model's defaults, unnamed
-        if f.name not in ("head_dim", "rotary", "n_heads", "rope_theta"):
+        if f.name not in ("head_dim", "rotary", "kind", "n_heads", "rope_theta"):
             assert getattr(spec, f.name) == model[f.name].default, f.name
 
 

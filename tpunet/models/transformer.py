@@ -177,9 +177,10 @@ class LayerSpec:
     """What ONE block is built from: the one field of `Block` and of
     `SelfAttention`, each of which reads `self.spec.<name>` where it uses
     it. `Transformer.layer_specs()` is the only code that makes one, and it
-    fills every field but `head_dim` (the model's, or d_model / n_heads)
-    and `rotary` (by the layer's place in `attn_pattern`) from the model's
-    field of the same name — so a new block-level field is a declaration
+    fills every field but `head_dim` (the model's, or d_model / n_heads),
+    `rotary` (by the layer's place in `attn_pattern`) and `kind` (by its
+    place in `layer_pattern` or `mtp_pattern`) from the model's field of the
+    same name — so a new block-level field is a declaration
     here, one on `Transformer` (which holds the default and the comment
     users read) and its use. No defaults here: a spec never exists apart
     from a model."""
@@ -220,6 +221,17 @@ class LayerSpec:
     attn_select_top_k: int | None
     attn_index_heads: int
     attn_index_head_dim: int
+    kind: str | None
+    mamba_heads: int
+    mamba_head_dim: int
+    mamba_groups: int
+    mamba_state: int
+    mamba_conv: int
+    mamba_chunk: int
+    moe_scoring: str
+    moe_routed_scale: float
+    moe_latent: int
+    moe_shared_d_ff: int
 
 
 class SelfAttention(nn.Module):
@@ -816,7 +828,22 @@ class GroupedExperts(nn.Module):
     d_ff, d); float32 masters, compute_dtype into the products. The router's
     product is float32 at Precision.HIGHEST: under the default an f32 x f32
     product is ONE bf16 pass on a TPU, and its rounding decides which
-    experts a token gets."""
+    experts a token gets.
+
+    The latent form (Nemotron-H's LatentMoE) is four fields, each off by
+    default. `scoring="sigmoid"`: a token's scores are sigmoid(logits), it
+    takes the top_k of scores + `router_bias` (an (n_experts,) leaf that
+    takes no gradient: zero at initialisation, it is what an auxiliary-
+    loss-free balancer moves, and nothing here moves it) and weighs them by
+    score / (sum of the chosen scores). `routed_scale` multiplies every
+    gate. `latent` > 0: the experts work in that width, between
+    `to_latent` (d, latent) and `from_latent` (latent, d), applied once a
+    token around the sum over its experts. `activation="relu2"`: experts
+    of TWO matrices, down(relu(up u)^2). `shared_d_ff` > 0: a shared
+    expert every token takes at weight 1, in the hidden width, of the
+    experts' form (`shared_gate`, `shared_up`, `shared_down`); its columns
+    are separable, so a tensor-parallel share of them is the same module
+    with fewer columns."""
 
     n_experts: int
     top_k: int
@@ -824,6 +851,10 @@ class GroupedExperts(nn.Module):
     held: tuple[int, int] | None = None
     compute_dtype: jnp.dtype = jnp.bfloat16
     activation: str = "relu"
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    latent: int = 0
+    shared_d_ff: int = 0
 
     @nn.compact
     def __call__(self, u, h):
@@ -836,22 +867,40 @@ class GroupedExperts(nn.Module):
         if not 1 <= k <= e or first < 0 or count < 1 or first + count > e:
             raise ValueError(f"top_k {k}, held {(first, count)} outside "
                              f"n_experts={e}")
-        if self.activation not in ("relu", "silu"):
+        if self.activation not in ("relu", "silu", "relu2"):
             raise ValueError(f"unknown moe_activation {self.activation!r}")
-        gated = nn.relu if self.activation == "relu" else nn.silu
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_scoring {self.scoring!r}")
+        expert = _expert_fn(self.activation)
         t = b * s
+        width = self.latent or d
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (d, e))
-        gate = self.param("gate", init, (count, d, f))
-        up = self.param("up", init, (count, d, f))
-        down = self.param("down", init, (count, f, d))
+        if self.scoring == "sigmoid":
+            bias = jax.lax.stop_gradient(
+                self.param("router_bias", nn.initializers.zeros, (e,)))
+        mats = [self.param(name, init, shape) for name, shape in _expert_shapes(
+            self.activation, (count, width, f), (count, f, width))]
 
         with jax.named_scope("moe.route"):
             logits = jnp.dot(h.reshape(t, d).astype(jnp.float32),
                              router.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            top, experts = jax.lax.top_k(logits, k)       # (t, k), best first
-            gates = jax.nn.softmax(top, axis=-1)
+            if self.scoring == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                _, experts = jax.lax.top_k(scores + bias, k)
+                top = jnp.take_along_axis(scores, experts, axis=-1)
+                gates = top / jnp.sum(top, axis=-1, keepdims=True)
+            else:
+                top, experts = jax.lax.top_k(logits, k)   # (t, k), best first
+                gates = jax.nn.softmax(top, axis=-1)
+            if self.routed_scale != 1.0:
+                gates = gates * self.routed_scale
+        if self.latent:
+            to_latent = self.param("to_latent", init, (d, width))
+            from_latent = self.param("from_latent", init, (width, d))
+            with jax.named_scope("moe.latent"):
+                latent = jnp.dot(u.reshape(t, d).astype(dt), to_latent.astype(dt))
 
         with jax.named_scope("moe.dispatch"):
             local = experts - first
@@ -877,18 +926,128 @@ class GroupedExperts(nn.Module):
                     jnp.where(live, pairs, row % (t * k)),
                     jnp.where(here, place, jnp.arange(t, dtype=jnp.int32)[:, None]),
                     here)
-            xs = _dispatch(u.reshape(t, d).astype(dt), plan)
+            xs = _dispatch(latent if self.latent else u.reshape(t, d).astype(dt), plan)
 
         mm = functools.partial(grouped_matmul, tile_group=tile_group,
                                n_tiles=n_tiles, tile_m=tile_m)
-        act = gated(mm(xs, gate)) * mm(xs, up)
-        y = mm(act, down)
+        y = expert(mm, xs, *mats)
         with jax.named_scope("moe.combine"):
             out = _combine(y, gates, plan)
+        if self.latent:
+            with jax.named_scope("moe.latent"):
+                out = jnp.dot(out, from_latent.astype(dt))
+        if self.shared_d_ff:
+            shared = [self.param("shared_" + name, init, shape[1:])
+                      for name, shape in _expert_shapes(
+                          self.activation, (1, d, self.shared_d_ff),
+                          (1, self.shared_d_ff, d))]
+            with jax.named_scope("moe.shared"):
+                dot = lambda a, w: jnp.dot(a, w.astype(dt))  # noqa: E731
+                out = out + expert(dot, u.reshape(t, d).astype(dt), *shared)
         return out.reshape(b, s, d)
 
 
+def _expert_shapes(activation: str, into, out_of):
+    """(name, shape) of an expert's matrices: gate, up, down for a gated
+    activation, up, down for relu2."""
+    named = [("up", into), ("down", out_of)]
+    return named if activation == "relu2" else [("gate", into)] + named
+
+
+def _expert_fn(activation: str):
+    """(product, x, *matrices) -> the experts' output, matrices in the
+    order `_expert_shapes` names them."""
+    if activation == "relu2":
+        return lambda mm, x, up, down: mm(jnp.square(nn.relu(mm(x, up))), down)
+    gated = nn.relu if activation == "relu" else nn.silu
+    return lambda mm, x, gate, up, down: mm(gated(mm(x, gate)) * mm(x, up), down)
+
+
+class Mamba2(nn.Module):
+    """The Mamba-2 mixer (arXiv:2405.21060; Nemotron-H's form): heads of
+    `mamba_head_dim` channels over `mamba_groups` groups of B and C, each
+    `mamba_state` wide, head j reading group j * groups // heads.
+
+        [z | xBC | dt] = x W_in
+        xBC = silu(causal depthwise conv(xBC) + conv_bias)     kernel mamba_conv
+        [x | B | C] = xBC
+        y = ssd(x, softplus(dt + dt_bias), -exp(A_log), B, C) + D x
+        out = (RMSNorm over each group's channels of (y * silu(z))) W_out
+
+    The gate multiplies before the norm normalises. The scan is
+    tpunet.ops.ssd_scan's kernels, in chunks of `mamba_chunk`; the conv and
+    the gated norm are plain XLA. No biases but the conv's. Weights:
+    in_proj/kernel (d, 2 * inner + 2 * groups * state + heads), conv_kernel
+    (mamba_conv, inner + 2 * groups * state), tap i multiplying the position
+    mamba_conv - 1 - i back, conv_bias, dt_bias, A_log and D (heads,),
+    norm_scale (inner,), out_proj/kernel (inner, d)."""
+
+    spec: LayerSpec
+
+    @nn.compact
+    def __call__(self, x):
+        from tpunet.ops.ssd_scan import ssd_scan
+
+        spec = self.spec
+        b, s, d = x.shape
+        heads, p, g, n = (spec.mamba_heads, spec.mamba_head_dim, spec.mamba_groups,
+                          spec.mamba_state)
+        if heads < 1 or heads % g:
+            raise ValueError(f"mamba_heads {heads} must be a positive multiple "
+                             f"of mamba_groups {g}")
+        if spec.decode:
+            raise ValueError(
+                "decode=True with a Mamba layer is not supported yet: a step "
+                "would carry the layer's recurrent state and its conv window "
+                "in a cache of their own (ROADMAP Reach A10); score the full "
+                "sequence with decode=False")
+        dt_, inner, k = spec.compute_dtype, heads * p, spec.mamba_conv
+        conv_dim = inner + 2 * g * n
+        with jax.named_scope("mamba.in_proj"):
+            zxbc = nn.Dense(2 * inner + 2 * g * n + heads, use_bias=False, dtype=dt_,
+                            name="in_proj")(x)
+        z, xbc, dt = jnp.split(zxbc, [inner, inner + conv_dim], axis=-1)
+        log_dt = jnp.linspace(math.log(1e-3), math.log(1e-1), heads)
+        w = self.param("conv_kernel", nn.initializers.normal(k ** -0.5), (k, conv_dim))
+        bias = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
+        dt_bias = self.param(  # softplus(dt_bias) spaced from 1e-3 to 1e-1
+            "dt_bias", lambda *_: jnp.exp(log_dt) + jnp.log(-jnp.expm1(-jnp.exp(log_dt))),
+            (heads,))
+        a_log = self.param("A_log", lambda *_: jnp.log(jnp.linspace(1.0, 16.0, heads)),
+                           (heads,))
+        skip = self.param("D", nn.initializers.ones, (heads,))
+        scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+        with jax.named_scope("mamba.conv"):
+            padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+            conv = sum(padded[:, i:i + s] * w[i] for i in range(k)) + bias
+            xbc = nn.silu(conv).astype(dt_)
+        xs, bm, cm = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+        with jax.named_scope("mamba.ssd"):
+            step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            y = ssd_scan(xs.reshape(b, s, heads, p), step, -jnp.exp(a_log),
+                         bm.reshape(b, s, g, n), cm.reshape(b, s, g, n), spec.mamba_chunk)
+            y = y.astype(jnp.float32) + skip[:, None] * xs.reshape(b, s, heads, p)
+        with jax.named_scope("mamba.gate_norm"):
+            y = (y.reshape(b, s, g, inner // g)
+                 * nn.silu(z.astype(jnp.float32)).reshape(b, s, g, inner // g))
+            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + spec.norm_eps)
+            y = (y.reshape(b, s, inner) * scale).astype(dt_)
+        return nn.Dense(d, use_bias=False, dtype=dt_, name="out_proj")(y)
+
+
+def _grouped_experts(spec: LayerSpec) -> GroupedExperts:
+    return GroupedExperts(
+        spec.n_experts, spec.moe_top_k, spec.d_ff, spec.moe_held,
+        spec.compute_dtype, spec.moe_activation, spec.moe_scoring,
+        spec.moe_routed_scale, spec.moe_latent, spec.moe_shared_d_ff, name="moe")
+
+
 class Block(nn.Module):
+    """A pre-norm residual block. With `spec.kind` None: attention, then
+    the MLP or the experts, each behind its own norm. With a kind, ONE
+    sublayer behind one norm: "M" the Mamba-2 mixer, "*" attention, "E"
+    the grouped experts (router and experts read the same normed input)."""
+
     spec: LayerSpec
 
     @nn.compact
@@ -897,17 +1056,21 @@ class Block(nn.Module):
         norm = lambda name: RMSNorm(  # noqa: E731
             spec.norm_eps, spec.norm_unit_offset, spec.compute_dtype, name=name)
         h = norm("norm1")(x)
+        if spec.kind == "M":
+            return x + Mamba2(spec, name="mamba")(h)
+        if spec.kind == "E":
+            return x + _grouped_experts(spec)(h, h)
         x = x + SelfAttention(spec, name="attn")(h)
+        if spec.kind == "*":
+            return x
         if spec.n_experts > 0 and spec.moe_impl == "grouped":
             if spec.moe_router_input not in ("attn_input", "mlp_input"):
                 raise ValueError(f"unknown moe_router_input {spec.moe_router_input!r}")
             u = norm("norm2")(x)
             # "attn_input": the router reads the ATTENTION's input, so its
             # choice does not wait for attention
-            return x + GroupedExperts(
-                spec.n_experts, spec.moe_top_k, spec.d_ff, spec.moe_held,
-                spec.compute_dtype, spec.moe_activation, name="moe")(
-                    u, h if spec.moe_router_input == "attn_input" else u)
+            return x + _grouped_experts(spec)(
+                u, h if spec.moe_router_input == "attn_input" else u)
         if spec.n_experts > 0:
             mlp = MoeMlp(spec.n_experts, spec.d_ff, spec.capacity_factor,
                          spec.compute_dtype, top_k=spec.moe_top_k, name="moe")
@@ -1010,6 +1173,29 @@ class Transformer(nn.Module):
     attn_index_head_dim: int = 0   # and their size
     index_loss_weight: float = 1.0  # what the train step's loss adds of the
     #   indexer's own loss (the mean over the layers of `dsa_index_loss`)
+    layer_pattern: str | None = None  # one character a block, n_layers of
+    #   them: "M" a Mamba-2 mixer, "*" attention, "E" the grouped experts,
+    #   each the block's ONE sublayer (Nemotron-H's hybrid_override_pattern).
+    #   None = every block is attention then the MLP or the experts
+    mamba_heads: int = 0           # "M" blocks: heads of mamba_head_dim, over
+    mamba_head_dim: int = 64       #   mamba_groups groups of B and C, each
+    mamba_groups: int = 1          #   mamba_state wide; the causal conv's
+    mamba_state: int = 128         #   kernel; the scan's chunk (a multiple of
+    mamba_conv: int = 4            #   128 for the compiled kernels)
+    mamba_chunk: int = 128
+    moe_scoring: str = "softmax"   # moe_impl="grouped" / "E" blocks: "softmax"
+    #   over the chosen logits, or "sigmoid" scores chosen with a bias that
+    #   takes no gradient and normalised over the chosen (GroupedExperts)
+    moe_routed_scale: float = 1.0  # every routed gate times this
+    moe_latent: int = 0            # > 0: the experts work in this width
+    moe_shared_d_ff: int = 0       # > 0: a shared expert of this many columns
+    mtp_pattern: str | None = None  # a multi-token-prediction module of these
+    #   block kinds (DeepSeek-V3's form): W_eh [norm(emb(t+1)); norm(h)] over
+    #   the last block's output h, the blocks, a norm of its own and the
+    #   SHARED head predict the token at t + 2; the mean cross-entropy over
+    #   the positions whose t + 2 lies in the sequence is sown as `mtp_loss`.
+    #   Training and full-sequence scoring; decode raises
+    mtp_loss_weight: float = 0.3   # what the train step's loss adds of mtp_loss
 
     @nn.nowrap
     def layer_specs(self) -> tuple[LayerSpec, ...]:
@@ -1018,11 +1204,9 @@ class Transformer(nn.Module):
         line here — and the only place a layer is made to differ from its
         neighbours: the experts, in every `moe_every`-th block, and the kind
         of attention, by the layer's place in `attn_pattern`."""
-        derived = {"head_dim": self.head_dim or self.d_model // self.n_heads,
-                   "rotary": True}
-        base = LayerSpec(**derived, **{
-            f.name: getattr(self, f.name)
-            for f in dataclasses.fields(LayerSpec) if f.name not in derived})
+        if self.layer_pattern is not None:
+            return self._kind_specs(self.layer_pattern)
+        base = self._base_spec()
         pattern = self.attn_pattern or ((True, True),)
 
         def layer(i):
@@ -1033,6 +1217,51 @@ class Transformer(nn.Module):
                 attn_window=self.attn_window if window else None)
 
         return tuple(layer(i) for i in range(self.n_layers))
+
+    @nn.nowrap
+    def mtp_specs(self) -> tuple[LayerSpec, ...]:
+        """One LayerSpec a block of the multi-token-prediction module."""
+        return self._kind_specs(self.mtp_pattern or "")
+
+    @nn.nowrap
+    def _base_spec(self) -> LayerSpec:
+        derived = {"head_dim": self.head_dim or self.d_model // self.n_heads,
+                   "rotary": True, "kind": None}
+        return LayerSpec(**derived, **{
+            f.name: getattr(self, f.name)
+            for f in dataclasses.fields(LayerSpec) if f.name not in derived})
+
+    @nn.nowrap
+    def _kind_specs(self, kinds: str) -> tuple[LayerSpec, ...]:
+        """Blocks of one sublayer each, by character; attention's kind by
+        the block's place among the attention blocks in `attn_pattern`."""
+        if set(kinds) - set("ME*"):
+            raise ValueError(f"unknown layer kinds in {kinds!r}: 'M', 'E' or '*'")
+        base = self._base_spec()
+        pattern = self.attn_pattern or ((True, True),)
+        out, seen = [], 0
+        for kind in kinds:
+            window, rotary = pattern[seen % len(pattern)]
+            seen += kind == "*"
+            out.append(dataclasses.replace(
+                base, kind=kind, n_experts=self.n_experts if kind == "E" else 0,
+                rotary=rotary, attn_window=self.attn_window if window else None))
+        return tuple(out)
+
+    @nn.nowrap
+    def _mtp(self, emb, tokens, last, head, block_cls):
+        """The multi-token-prediction module over the last block's output;
+        sows its loss."""
+        norm = lambda name: RMSNorm(  # noqa: E731
+            self.norm_eps, self.norm_unit_offset, self.compute_dtype, name=name)
+        following = emb[jnp.roll(tokens, -1, axis=1)].astype(last.dtype)
+        m = _dense(self.d_model, self.compute_dtype, "mtp_proj", None)(
+            jnp.concatenate([norm("mtp_norm_e")(following), norm("mtp_norm_h")(last)],
+                            axis=-1)).astype(last.dtype)
+        for j, spec in enumerate(self.mtp_specs()):
+            m = block_cls(spec, name=f"mtp_block{j}")(m)
+        logits = head(norm("mtp_norm_f")(m)).astype(jnp.float32)
+        self.sow("intermediates", "mtp_loss", _mtp_loss(logits, tokens))
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, features_only: bool = False):
@@ -1073,6 +1302,16 @@ class Transformer(nn.Module):
             raise ValueError(
                 "n_pred_heads > 1 has no fused cross-entropy and no decode "
                 "path: both read one position's logits from the head")
+        if self.layer_pattern is not None and len(self.layer_pattern) != self.n_layers:
+            raise ValueError(f"layer_pattern {self.layer_pattern!r} names "
+                             f"{len(self.layer_pattern)} blocks, n_layers is {self.n_layers}")
+        if self.mtp_pattern and (self.decode or features_only or self.n_pred_heads > 1):
+            raise ValueError(
+                "mtp_pattern is not supported with decode=True, features_only "
+                "or n_pred_heads > 1: the module reads the next token's "
+                "embedding and the last block's output over the whole "
+                "sequence, and gives its own loss; as a draft source for "
+                "speculative_generate it is ROADMAP's")
         emb = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab, self.d_model)
         )
@@ -1097,6 +1336,7 @@ class Transformer(nn.Module):
             block_cls = nn.remat(Block, policy=pol) if pol else nn.remat(Block)
         for i, spec in enumerate(self.layer_specs()):
             x = block_cls(spec, name=f"block{i}")(x)
+        last = x
         x = RMSNorm(self.norm_eps, self.norm_unit_offset, self.compute_dtype,
                     name="norm_f")(x)
         if features_only:
@@ -1107,13 +1347,27 @@ class Transformer(nn.Module):
                 nn.Dense(self.vocab, use_bias=False, dtype=self.compute_dtype,
                          name="lm_head")(x[..., :1, :])
             return x.astype(self.compute_dtype)
-        logits = _dense(self.vocab * self.n_pred_heads, self.compute_dtype,
-                        "lm_head", self.weight_quant, self.lora_rank,
-                        self.lora_alpha)(x).astype(jnp.float32)
+        head = _dense(self.vocab * self.n_pred_heads, self.compute_dtype,
+                      "lm_head", self.weight_quant, self.lora_rank, self.lora_alpha)
+        logits = head(x).astype(jnp.float32)
+        if self.mtp_pattern:
+            with jax.named_scope("mtp"):
+                self._mtp(emb, tokens, last, head, block_cls)
         if self.n_pred_heads > 1:
             logits = logits.reshape(*logits.shape[:-1], self.n_pred_heads,
                                     self.vocab)
         return logits
+
+
+def _mtp_loss(logits, tokens):
+    """Mean cross-entropy of logits (b, s, vocab) at position t against the
+    token at t + 2, over the positions where that lies in the sequence."""
+    s = tokens.shape[1]
+    target = jnp.roll(tokens, -2, axis=1)
+    nll = (jax.nn.logsumexp(logits, axis=-1)
+           - jnp.take_along_axis(logits, target[..., None], axis=-1)[..., 0])
+    inside = jnp.arange(s) < s - 2
+    return jnp.sum(jnp.where(inside, nll, 0.0)) / (tokens.shape[0] * (s - 2))
 
 
 def transformer_partition_rules(

@@ -182,7 +182,9 @@ def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
     has_moe = getattr(model, "n_experts", 0) > 0
     # a model whose attention selects its keys sows its indexer's loss
     has_index = getattr(model, "attn_select_top_k", None) is not None
-    sows = has_moe or has_index
+    # a model with a multi-token-prediction module sows that module's loss
+    has_mtp = bool(getattr(model, "mtp_pattern", None))
+    sows = has_moe or has_index or has_mtp
     if fused_xent_block is not None and getattr(model, "tp_axis", None):
         import warnings
 
@@ -249,6 +251,9 @@ def _make_loss_fn(model, images, labels, dropout_rng, moe_aux_weight: float,
             index = _sown(mut, "dsa_index_loss")
             loss = loss + model.index_loss_weight * (
                 sum(index) / len(index)).astype(loss.dtype)
+        if has_mtp:
+            mtp = _sown(mut, "mtp_loss")
+            loss = loss + model.mtp_loss_weight * (sum(mtp) / len(mtp)).astype(loss.dtype)
         return loss
 
     return loss_fn
