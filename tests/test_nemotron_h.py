@@ -250,10 +250,14 @@ def _mine(params, first, count, shared):
                 shared_down=params["shared_down"][shared])
 
 
+def _latent_layer(held, shared_cols=0):
+    return GroupedExperts(64, 6, 12, held, jnp.float32, "relu2", "sigmoid", 5.0, 8,
+                          shared_cols)
+
+
 @functools.lru_cache(maxsize=None)
 def _layer(held, shared_cols):
-    layer = GroupedExperts(64, 6, 12, held, jnp.float32, "relu2", "sigmoid", 5.0, 8,
-                           shared_cols)
+    layer = _latent_layer(held, shared_cols)
     return jax.jit(lambda p, u: layer.apply({"params": p}, u, u, mutable=["intermediates"]))
 
 
@@ -273,19 +277,30 @@ def _latent_rolled(k, shared, u, params):
     return _latent((0, 1), shared, u, rolled)
 
 
+_LATENT_CFG = dict(CFG, hidden_size=16, n_routed_experts=64, n_routed_experts_held=64,
+                   n_routed_experts_first=0, num_experts_per_tok=6, moe_latent_size=8,
+                   moe_intermediate_size=12, moe_shared_expert_columns_held=16,
+                   initializer_range=0.3)
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_leaves():
+    moe = weights.generate(ref._moe_spec(_LATENT_CFG, "moe"), 4, jnp.float32)["moe"]
+    return dict(moe, router_bias=0.1 * jax.random.normal(jax.random.PRNGKey(3), (64,)))
+
+
+def _latent_case():
+    """(input, the whole layer's leaves) of the 64-expert latent layer."""
+    return jax.random.normal(jax.random.PRNGKey(11), (1, SEQ, 16)), dict(_latent_leaves())
+
+
 def test_the_expert_ranges_and_shared_slices_add_up_to_the_uncut_layer():
     """64 experts held one a share (512 as 8 a chip over 64 chips) and the
     shared expert's 16 columns in 8 slices of 2 (5,376 as 672 a chip over 8):
     the router and the latent projections are every share's, counted once;
     the shares' outputs add up to the whole layer and to the reference's."""
-    u = jax.random.normal(jax.random.PRNGKey(11), (1, SEQ, 16))
-    cfg = dict(CFG, hidden_size=16, n_routed_experts=64, n_routed_experts_held=64,
-               n_routed_experts_first=0, num_experts_per_tok=6, moe_latent_size=8,
-               moe_intermediate_size=12, moe_shared_expert_columns_held=16,
-               initializer_range=0.3)
-    spec = ref._moe_spec(cfg, "moe")
-    moe = weights.generate(spec, 4, jnp.float32)["moe"]
-    moe["router_bias"] = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (64,))
+    cfg = _LATENT_CFG
+    u, moe = _latent_case()
     whole, _ = _latent((0, 64), np.arange(16), u, moe)
     slices = [np.arange(2 * k, 2 * k + 2) if k < 8 else None for k in range(64)]
     shares = [_latent_rolled(k, slices[k], u, moe) for k in range(64)]
@@ -296,6 +311,57 @@ def test_the_expert_ranges_and_shared_slices_add_up_to_the_uncut_layer():
     assert sum(counted) == SEQ * 6
     with jax.default_matmul_precision("highest"):
         _close(whole[0], ref.latent_moe(u[0], moe, cfg, "f32"), 1e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_grad(held):
+    """(leaves, input, r, k) -> d<out, r> / d(leaves, input) of the share
+    `held` of the experts rolled by k, the router's gradient rolled back;
+    no shared expert."""
+    layer = _latent_layer(held)
+
+    def grads(p, u, r, k):
+        rolled = dict(p, router=jnp.roll(p["router"], -k, axis=1),
+                      router_bias=jnp.roll(p["router_bias"], -k))
+        g, du = jax.grad(lambda q, x: jnp.sum(layer.apply({"params": q}, x, x) * r),
+                         argnums=(0, 1))(rolled, u)
+        return dict(g, router=jnp.roll(g["router"], k, axis=1)), du
+    return jax.jit(grads)
+
+
+def test_the_shares_gradients_add_up_to_the_uncut_layers():
+    """A share holding fewer experts than a token chooses (1 of 64 at 6 a
+    token) sums a token over its held experts; the whole layer (64 >= 6)
+    sums over the choices. The 64 shares' gradients of <out, r> add up to
+    the whole layer's: the input's, the router's, both latent projections',
+    and each expert's own."""
+    u, moe = _latent_case()
+    r = jax.random.normal(jax.random.PRNGKey(12), u.shape)
+    want, want_u = _layer_grad((0, 64))(_mine(moe, 0, 64, None), u, r, 0)
+    shares = [_layer_grad((0, 1))(_mine(moe, k, 1, None), u, r, k) for k in range(64)]
+    _close(sum(du for _, du in shares), want_u, 1e-5)
+    for name in ("router", "to_latent", "from_latent"):
+        _close(sum(g[name] for g, _ in shares), want[name], 1e-5)
+    for name in ("up", "down"):
+        _close(jnp.concatenate([g[name] for g, _ in shares]), want[name], 1e-5)
+
+
+def test_a_token_that_chose_every_held_expert_fills_every_slot():
+    """The router's bias sends every token to experts 0..3, all four held
+    by the share (0, 4) at 6 a token: each token fills its 4 slots, which
+    `moe_choices_held_max` reads, and the share is its four one-expert
+    shares' sum. The whole layer sums over choices and sows no such count."""
+    u, moe = _latent_case()
+    moe["router_bias"] = moe["router_bias"].at[:4].add(10.0)
+    four, mut = _latent((0, 4), None, u, moe)
+    inter = mut["intermediates"]
+    assert int(inter["moe_choices_held_max"][0]) == 4
+    assert int(inter["moe_rows_held"][0]) == SEQ * 4
+    ones = [_latent_rolled(k, None, u, moe) for k in range(4)]
+    assert {int(m["intermediates"]["moe_choices_held_max"][0]) for _, m in ones} == {1}
+    _close(sum(out for out, _ in ones), four, 1e-5)
+    _, whole = _latent((0, 64), np.arange(16), u, moe)
+    assert "moe_choices_held_max" not in whole["intermediates"]
 
 
 def test_the_latent_fields_default_to_the_parents_experts():
@@ -327,16 +393,23 @@ def test_decode_with_a_mamba_layer_or_mtp_is_refused_and_says_why():
 # that came before, none using a field this family added, hashed at the
 # PARENT commit fdf7ba6: flash with a window, EVA, ReGLU grouped experts
 # (tests/test_keye.py's three), SwiGLU grouped experts under the selecting
-# attention, and the plain dense decoder.
+# attention, and the plain dense decoder. "swiglu_dsa" holds 2 experts at 3
+# a token, so its sums run over the held experts and its text is that of
+# the commit that made them so; "swiglu_dsa_held3" (3 held at 3 a token,
+# sums over the choices) is hashed at that commit's parent b9afae5.
+_SWIGLU_DSA = dict(vocab=64, d_model=48, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=16,
+                   d_ff=24, n_experts=8, moe_every=1, moe_top_k=3, moe_impl="grouped",
+                   moe_held=(2, 2), moe_activation="silu", moe_router_input="mlp_input",
+                   qk_norm=True, attn_select_top_k=6, attn_index_heads=3,
+                   attn_index_head_dim=8, attn_impl="flash", rope_theta=1e7,
+                   compute_dtype=jnp.float32, remat=True)
 UNTOUCHED = {
     "swiglu_dsa": (
-        dict(vocab=64, d_model=48, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=16,
-             d_ff=24, n_experts=8, moe_every=1, moe_top_k=3, moe_impl="grouped",
-             moe_held=(2, 2), moe_activation="silu", moe_router_input="mlp_input",
-             qk_norm=True, attn_select_top_k=6, attn_index_heads=3,
-             attn_index_head_dim=8, attn_impl="flash", rope_theta=1e7,
-             compute_dtype=jnp.float32, remat=True),
-        "82ccbbcca029da83fa97ed66a727ccf3da2c90411d4870e3cfa62095004c1e69"),
+        _SWIGLU_DSA,
+        "d704728a8e6c72ac802b3de3fcd564127308a4ec663f2917d63db4a0d1128c4a"),
+    "swiglu_dsa_held3": (
+        dict(_SWIGLU_DSA, moe_held=(2, 3)),
+        "dee48b4057b3812c0a089e020855fa4d3c765a77fd172731c1018f032f7945eb"),
     "dense": (dict(vocab=64, d_model=32, n_layers=2, n_heads=4, d_ff=64),
               "243585445f75ee82777e4c0b270ca0ad565bb3c4d86322e0903e9308c84233ae"),
 }

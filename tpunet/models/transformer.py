@@ -733,10 +733,11 @@ def _gather_rows(src, plan):
     return jnp.concatenate([src, spare])[rows_token]
 
 
-def _sum_choices(buf, weight, place):
+def _sum_slots(buf, weight, place):
     """(rows, d) -> (tokens, d): token t takes sum_j weight[t, j] *
-    buf[place[t, j]], accumulated in float32 a choice at a time (k gathers
-    of (tokens, d), never a (tokens, k, d) copy)."""
+    buf[place[t, j]] over its slots j, accumulated in float32 a slot at a
+    time (one gather of (tokens, d) a slot, never a (tokens, slots, d)
+    copy)."""
     out = jnp.zeros((place.shape[0], buf.shape[1]), jnp.float32)
     for j in range(place.shape[1]):
         out = out + weight[:, j, None] * buf[place[:, j]].astype(jnp.float32)
@@ -745,13 +746,16 @@ def _sum_choices(buf, weight, place):
 
 # The two permutations of an expert layer, each the other's transpose, so
 # that forward AND backward are gathers: a row scatter-add, which is what
-# autodiff makes of a gather, serialises on a TPU. `plan` = (rows_token,
-# rows_pair, place, here): per buffer row its token (for a padding row one
-# of `_SPARE_ROWS` indices past the tokens) and the (token, choice) pair that
-# sits in it (as token * k + choice; for a padding row any pair); per pair
-# its row (for a pair that fell elsewhere any row: its weight is 0) and
-# whether it fell on an expert held here. The indices that stand for
-# nothing are SPREAD, never one index many times: see `_gather_rows`.
+# autodiff makes of a gather, serialises on a TPU. A token's sums run over
+# m = min(k, count) SLOTS: its k choices where k <= count, else the `count`
+# held experts (a token's choices are distinct experts, so it has at most
+# one pair in each). `plan` = (rows_token, rows_pair, place, here): per
+# buffer row its token (for a padding row one of `_SPARE_ROWS` indices past
+# the tokens) and the (token, slot) pair that sits in it (as token * m +
+# slot; for a padding row any pair); per slot its row (for a slot that
+# holds nothing its own token's row: its weight is 0) and whether it holds
+# a pair. The indices that stand for nothing are SPREAD, never one index
+# many times: see `_gather_rows`.
 
 @jax.custom_vjp
 def _dispatch(u, plan):
@@ -764,7 +768,7 @@ def _dispatch_fwd(u, plan):
 
 def _dispatch_bwd(plan, dxs):
     _, _, place, here = plan
-    return _sum_choices(dxs, here.astype(jnp.float32), place).astype(dxs.dtype), None
+    return _sum_slots(dxs, here.astype(jnp.float32), place).astype(dxs.dtype), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -773,7 +777,7 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 @jax.custom_vjp
 def _combine(y, gates, plan):
     _, _, place, here = plan
-    return _sum_choices(y, jnp.where(here, gates, 0.0), place).astype(y.dtype)
+    return _sum_slots(y, jnp.where(here, gates, 0.0), place).astype(y.dtype)
 
 
 def _combine_fwd(y, gates, plan):
@@ -822,7 +826,10 @@ class GroupedExperts(nn.Module):
     imbalance; tiles past the true count cost no product. Nothing is sown
     under `moe_aux_loss`: the loss is the cross-entropy alone. Sown under
     `intermediates`: `moe_rows_held`, the (token, choice) pairs that fell on
-    held experts, and `moe_rows_max`, the fullest held expert's.
+    held experts, and `moe_rows_max`, the fullest held expert's. A token's
+    sum runs over min(top_k, count) slots: its choices, or where count <
+    top_k its held experts, and then `moe_choices_held_max` is sown too, the
+    most held experts any token chose.
 
     Weights: router (d, n_experts); gate, up (count, d, d_ff); down (count,
     d_ff, d); float32 masters, compute_dtype into the products. The router's
@@ -912,18 +919,28 @@ class GroupedExperts(nn.Module):
             self.sow("intermediates", "moe_rows_max", jnp.max(sizes))
             # rank of each pair inside its expert, token-major
             rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=-1)
-            tile_m = tile_rows(t * min(k, count), dt)
-            rows = buffer_rows(t * min(k, count), count, tile_m)
+            m = min(k, count)                              # slots a token
+            tile_m = tile_rows(t * m, dt)
+            rows = buffer_rows(t * m, count, tile_m)
             starts, tile_group, n_tiles = group_tiles(sizes, tile_m, rows)
             place = (jnp.append(starts, 0)[local.reshape(-1)] + rank).reshape(t, k)
-            pairs = jnp.full((rows,), t * k, jnp.int32).at[
+            if count < k:
+                # a slot is a held expert: place and gate of the choice
+                # that fell on it, if one did (one at most)
+                chose = local[:, :, None] == jnp.arange(count)   # (t, k, count)
+                place = jnp.sum(jnp.where(chose, place[:, :, None], 0), axis=1)
+                gates = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)
+                here = jnp.any(chose, axis=1)
+                self.sow("intermediates", "moe_choices_held_max",
+                         jnp.max(jnp.sum(here, axis=1)))
+            pairs = jnp.full((rows,), t * m, jnp.int32).at[
                 jnp.where(here, place, rows).reshape(-1)].set(
-                    jnp.arange(t * k, dtype=jnp.int32), mode="drop",
+                    jnp.arange(t * m, dtype=jnp.int32), mode="drop",
                     unique_indices=True)
             row = jnp.arange(rows, dtype=jnp.int32)
-            live = pairs < t * k
-            plan = (jnp.where(live, pairs // k, t + row % _SPARE_ROWS),
-                    jnp.where(live, pairs, row % (t * k)),
+            live = pairs < t * m
+            plan = (jnp.where(live, pairs // m, t + row % _SPARE_ROWS),
+                    jnp.where(live, pairs, row % (t * m)),
                     jnp.where(here, place, jnp.arange(t, dtype=jnp.int32)[:, None]),
                     here)
             xs = _dispatch(latent if self.latent else u.reshape(t, d).astype(dt), plan)
